@@ -84,12 +84,6 @@ FAMILIES = {
             ("spec_decode.acceptance_rate", "higher", 0.10),
             ("tier_p99_separation_ok", "true", 0.0),
             ("goodput_ge_fifo", "true", 0.0),
-            # head-major relayout (PR-14): every Pallas serving kernel
-            # must keep Mosaic-lowering on deviceless XLA:TPU — a
-            # layout/BlockSpec regression flips this boolean and can
-            # never land silently (present only on --tpu-check runs;
-            # SKIP elsewhere by design)
-            ("mosaic_lowerable_ok", "true", 0.0),
             # tiered prefix cache (PR-18 fields; SKIP against older
             # artifacts by design), both ABSOLUTE bounds on the
             # 10x-working-set chat trace: the avoided fraction is

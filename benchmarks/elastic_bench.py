@@ -235,4 +235,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     sys.exit(main())

@@ -223,7 +223,6 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--warmup", type=int, default=4)
-    ap.add_argument("--platform", default=None)
     ap.add_argument("--depth", type=int, default=50)
     ap.add_argument("--source", choices=["host", "native"], default="host",
                     help="host: python reader, per-sample feeder assembly; "
@@ -247,10 +246,6 @@ def main(argv=None):
     if args.metrics_out:
         METRICS_OUT = args.metrics_out
 
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
-
     if args.workload == "synthetic":
         if args.compare:
             return run_compare(args)
@@ -262,4 +257,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

@@ -25,12 +25,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
-    ap.add_argument("--platform", default=None)
     args = ap.parse_args()
 
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
     import numpy as np
 
@@ -133,4 +130,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

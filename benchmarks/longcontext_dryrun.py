@@ -107,4 +107,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

@@ -42,12 +42,9 @@ def main():
                     help="sample noise sigma; must be large enough that "
                     "the width-64 net does NOT saturate held-out "
                     "accuracy, or arm differences become invisible")
-    ap.add_argument("--platform", default=None)
     args = ap.parse_args()
 
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
     import numpy as np
 
@@ -176,4 +173,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

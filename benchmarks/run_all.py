@@ -10,7 +10,7 @@ Usage:
   python benchmarks/run_all.py                 # full sweep
   python benchmarks/run_all.py --suite=lstm    # one suite
   python benchmarks/run_all.py --quick         # tiny batches, smoke test
-  BENCH_PLATFORM=cpu python benchmarks/...     # force a JAX platform
+  JAX_PLATFORMS=cpu python benchmarks/...      # force a JAX platform
 
 Each (config, batch) measurement runs in a fresh subprocess so one OOM or
 hang cannot take down the sweep; results stream to benchmarks/results.json
@@ -70,8 +70,6 @@ CHILD = r"""
 import json, os, sys
 sys.path.insert(0, {repo!r})
 import jax
-if os.environ.get("BENCH_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
 from paddle_tpu import cli
 cfg = cli._load_config({config!r})
 print("BENCHDEVICE " + jax.devices()[0].device_kind)
@@ -179,12 +177,12 @@ def main():
     if args.quick:
         timed, warmup, timeout = 3, 1, 600
 
-    results = {"platform": os.environ.get("BENCH_PLATFORM", "default"),
+    results = {"platform": os.environ.get("JAX_PLATFORMS", "default"),
                "device": "?", "points": []}
     if args.merge and os.path.exists(json_path):
         with open(json_path) as f:
             old = json.load(f)
-        cur_platform = os.environ.get("BENCH_PLATFORM", "default")
+        cur_platform = os.environ.get("JAX_PLATFORMS", "default")
         if old.get("platform") != cur_platform:
             # never publish this run's numbers under the OLD platform
             # label — a CPU smoke merged into a TPU table would lie
@@ -216,4 +214,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

@@ -33,24 +33,7 @@ fi
 # directly and takes its exit code).
 echo '== PERF SENTINEL (benchmarks/check_regression.py) =='
 python benchmarks/check_regression.py || true
-# latest --tpu-check verdict: the head-major Mosaic-lowering booleans
-# from the newest serving artifact, next to the sentinel lines (run
-# serving_bench.py --tpu-check to refresh them)
 latest_serving=$(ls benchmarks/runs/*serving_paged*.json 2>/dev/null | sort | tail -1)
-if [ -n "$latest_serving" ]; then
-    echo "== TPU-CHECK ($latest_serving) =="
-    python - "$latest_serving" <<'PYEOF' || true
-import json, sys
-doc = json.load(open(sys.argv[1]))
-tc = doc.get("tpu_check")
-if not tc:
-    print("no tpu_check section — run serving_bench.py --tpu-check")
-else:
-    oks = {k: tc[k] for k in sorted(tc) if k.endswith("_ok")}
-    print(json.dumps({"pool_layout": tc.get("pool_layout"),
-                      "mosaic_ok": tc.get("mosaic_ok"), **oks}))
-PYEOF
-fi
 # latest tiered-prefix-cache figures: cold-prefill blocks the
 # DRAM/disk tiers absorbed + the tiered/baseline TTFT p99 ratio on
 # the 10x-working-set chat trace, from the newest serving artifact

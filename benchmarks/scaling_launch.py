@@ -112,6 +112,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     if "--worker" in sys.argv:
         worker()
     else:

@@ -95,6 +95,8 @@ import numpy as np
 
 from bench_metrics import metrics_write as _metrics_write  # noqa: E402
 from bench_metrics import resolve_metrics_out  # noqa: E402
+from paddle_tpu.serving.blocks import (  # noqa: E402
+    DEFAULT_BLOCK_SIZE, DEFAULT_CHUNK_TOKENS)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -591,148 +593,26 @@ def kv_quality_probe(params, cfg, *, block_size, chunk_tokens, vocab,
     return out
 
 
-def tpu_export_check(params, cfg, *, block_size, chunk_tokens, batch,
-                     cache_len):
-    """Deviceless XLA:TPU export of the paged step programs (decode +
-    one contextful chunk prefill) per KV dtype on the XLA attention
-    path — the quantized pool's scatter writes, int8/int4 gathers and
-    fused dequant all compile for TPU with no chip attached — PLUS
-    direct per-kernel Mosaic lowering probes of all four serving
-    kernels (flash-decode, chunk-prefill attention, span-write, fused
-    sampler) per KV dtype. Since the head-major pool relayout every
-    probe must SUCCEED: ``mosaic_ok`` aggregates them, the caller
-    asserts it, and the regression sentinel
-    (``check_regression.py mosaic_lowerable_ok``) keeps a layout
-    regression from ever landing silently. The artifact also stamps
-    each kernel's legal BlockSpec geometry and VMEM estimate — the
-    evidence a reader needs to see WHY the shapes are tiling-legal."""
-    import jax
-    import jax.export  # noqa: F401
-    import jax.numpy as jnp
-
+def tpu_compile_check(cfg, *, block_size, chunk_tokens, batch,
+                      cache_len):
+    """Deviceless COMPILE (not lowering) of the paged engine's programs
+    for a TPU v5e, every kernel placed, per KV dtype —
+    ``ops/pallas/aot.py``: libtpu runs Mosaic and XLA:TPU for a chip
+    that is not attached. A kernel the compiler refuses raises with the
+    compiler's message; what returns is what compiled, with each
+    program's placement record, compile seconds and memory analysis.
+    The compiled kernels need a block size that is a multiple of 128
+    (``serving.blocks.DEFAULT_BLOCK_SIZE``)."""
     from paddle_tpu.models import transformer
-    from paddle_tpu.ops.pallas import decode as _fd
-    from paddle_tpu.ops.pallas import prefill as _fp
-    from paddle_tpu.serving import sampling
-    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
-    bs = block_size
-    B = batch
-    P = cache_len // bs
-    Hkv, Dh = cfg.kv_heads, cfg.head_dim
-    G = cfg.n_heads // Hkv
-    p_shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a),
-                                       np.asarray(a).dtype), params)
-    i32 = jax.ShapeDtypeStruct((), jnp.int32)
-    out = {"pool_layout": transformer.POOL_LAYOUT,
-           "blockspecs": {}, "vmem_bytes": {}}
-    ok_all = True
-    for kvd in (None, "int8", "int4"):
-        key = "fp32" if kvd is None else kvd
-        # one zero pool per dtype serves both the exported-program
-        # shapes and the probe/blockspec geometry below
-        pool = transformer.init_block_pool(cfg, B * P, bs,
-                                           kv_dtype=kvd)
-        pool_shapes = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), pool)
-        dargs = (p_shapes, pool_shapes,
-                 jax.ShapeDtypeStruct((B,), jnp.int32),
-                 jax.ShapeDtypeStruct((B,), jnp.int32),
-                 jax.ShapeDtypeStruct((B,), jnp.bool_),
-                 jax.ShapeDtypeStruct((B, P), jnp.int32),
-                 jax.ShapeDtypeStruct((B,), jnp.float32),
-                 jax.ShapeDtypeStruct((B,), jnp.int32), i32)
-        ctx_pages = chunk_tokens // bs          # one contextful chunk
-        pargs = (p_shapes, pool_shapes,
-                 jax.ShapeDtypeStruct((1, chunk_tokens), jnp.int32),
-                 i32,
-                 jax.ShapeDtypeStruct((2 * ctx_pages,), jnp.int32),
-                 jax.ShapeDtypeStruct((), jnp.float32), i32, i32)
-        pf, df = sampling.paged_step_fns(cfg, bs, pallas="off")
-        try:
-            nd = len(jax.export.export(
-                jax.jit(df), platforms=["tpu"])(*dargs).serialize())
-            np_ = len(jax.export.export(
-                jax.jit(pf), platforms=["tpu"])(*pargs).serialize())
-            out[f"xla_{key}_ok"] = True
-            out[f"xla_{key}_bytes"] = nd + np_
-        except Exception as e:                  # noqa: BLE001
-            out[f"xla_{key}_ok"] = False
-            out[f"xla_{key}_detail"] = (
-                f"{type(e).__name__}: {str(e)[:300]}")
-        # DIRECT per-kernel Mosaic lowering probes — the head-major
-        # relayout is exactly what makes these succeed, so a refusal
-        # is a regression, not a diagnostic to record and move past.
-        # These are the same cached probes the mode="on" dispatch
-        # consults (decode.decode_lowering_ok & co) plus the fused
-        # sampler, run at the bench geometry AND the bench model's
-        # activation dtype (q_dtype=cfg.dtype — the probe must lower
-        # the very program the engine would dispatch; a bf16-only
-        # tiling regression would otherwise slip past an fp32 probe).
-        kvq = kvd or "none"
-        dt = pool["k"].dtype
-        M = B * P * bs
-        S = ctx_pages * bs
-        probes = {
-            "pallas_decode": lambda: _fd.decode_lowering_ok(
-                M, P, bs, Hkv, G, Dh, dt, kv_dtype=kvq,
-                q_dtype=cfg.dtype),
-            "pallas_prefill": lambda: _fp.prefill_lowering_ok(
-                M, S, chunk_tokens, bs, Hkv, G, Dh, dt, kv_dtype=kvq,
-                q_dtype=cfg.dtype),
-            "pallas_span_write": lambda: _fp.span_write_lowering_ok(
-                M, -(-chunk_tokens // bs), bs, cfg.n_layers, Hkv, Dh,
-                dt, kv_dtype=kvq),
-            "pallas_sample": lambda: _fd.sample_lowering_ok(
-                B, cfg.vocab),
-        }
-        kinds = {"pallas_decode": "decode", "pallas_prefill": "prefill",
-                 "pallas_span_write": "span_write",
-                 "pallas_sample": "sample"}
-        for tag, probe in probes.items():
-            seen = set(_fd.lowering_failures())
-            got = bool(probe())
-            out[f"{tag}_{key}_ok"] = got
-            ok_all &= got
-            if not got:
-                # prefer the diagnostic this very probe just recorded;
-                # a cached refusal recorded no fresh entry, so fall
-                # back to every same-kind diagnostic rather than
-                # guessing one signature's
-                det = {k: v for k, v in _fd.lowering_failures().items()
-                       if k not in seen}
-                det = det or _fd.lowering_failures(kinds[tag])
-                out[f"{tag}_{key}_detail"] = (
-                    "; ".join(sorted(set(det.values())))
-                    if det else "no detail")
-        Dh_st = pool["k"].shape[-1]
-        tile = _fd.select_decode_tile(P, bs, Dh, dt, kvq)
-        ptile = _fp.select_prefill_tile(ctx_pages, bs, chunk_tokens,
-                                        Dh, dt, kvq)
-        out["blockspecs"][key] = {
-            "pool": list(pool["k"].shape),
-            "decode_pool_block": [1, bs, Dh_st],
-            "decode_grid": [B, Hkv, P // tile],
-            "decode_tile": tile,
-            "prefill_pool_block": [1, bs, Dh_st],
-            "prefill_grid": [Hkv, ctx_pages // ptile],
-            "prefill_tile": ptile,
-            "span_write_block": [cfg.n_layers, Hkv, bs, Dh_st],
-            "scalar_prefetch": {
-                "decode": ["pages", "pos"],
-                "prefill": ["pages"], "span_write": ["pages"],
-                "sample": ["seed", "temperature", "top_k"]},
-        }
-        out["vmem_bytes"][key] = {
-            "decode": _fd.decode_vmem_bytes(
-                M, P, bs, G, Dh, jnp.dtype(dt).itemsize, kvq,
-                tile=tile),
-            "prefill": _fp.prefill_vmem_bytes(
-                M, S, chunk_tokens, G, Dh, jnp.dtype(dt).itemsize,
-                kvq),
-        }
-    out["mosaic_ok"] = ok_all
-    return out
+    from paddle_tpu.ops.pallas import aot
+    device = aot.topology_device()
+    return {"device_kind": device.device_kind,
+            "pool_layout": transformer.POOL_LAYOUT,
+            **{kvd or "fp": aot.compile_engine_programs(
+                cfg, device=device, batch=batch, cache_len=cache_len,
+                block_size=block_size, chunk_tokens=chunk_tokens,
+                kv_dtype=kvd)
+               for kvd in (None, "int8", "int4")}}
 
 
 def build_draft_pair(vocab, d_model, layers, heads, max_len, *,
@@ -1865,9 +1745,13 @@ def main(argv=None):
                     help="insert ONE near-cache_len prompt mid-burst "
                          "into the latency trace (the chunked-prefill "
                          "stress)")
-    ap.add_argument("--block-size", type=int, default=16,
-                    help="paged-engine KV block size (tokens)")
-    ap.add_argument("--chunk-tokens", type=int, default=64,
+    ap.add_argument("--block-size", type=int,
+                    default=DEFAULT_BLOCK_SIZE,
+                    help="paged-engine KV block size (tokens; the "
+                         "engine default — the compiled kernels need "
+                         "a multiple of 128)")
+    ap.add_argument("--chunk-tokens", type=int,
+                    default=DEFAULT_CHUNK_TOKENS,
                     help="paged-engine prefill chunk size (tokens)")
     ap.add_argument("--num-blocks", type=int, default=None,
                     help="paged pool size (default: HBM parity with "
@@ -1888,7 +1772,6 @@ def main(argv=None):
                     help="replays per (variant, phase); the best run "
                          "is reported (noise-robust on shared hosts)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--platform", default=None)
     ap.add_argument("--metrics-out", default=None,
                     help="append JSONL records here (bench.py trail "
                          "conventions)")
@@ -1907,15 +1790,12 @@ def main(argv=None):
                          "lifecycles + the kill-and-requeue, one "
                          "connected tree per request)")
     ap.add_argument("--tpu-check", action="store_true",
-                    help="deviceless XLA:TPU export of the paged step "
-                         "programs per KV dtype (fp32/int8/int4, XLA "
-                         "attention path) — proves the quantized-pool "
-                         "writes/gathers compile for TPU without a "
-                         "chip; ASSERTS every Pallas serving kernel "
-                         "(flash-decode, chunk-prefill, span-write, "
-                         "fused sampler) lowers through Mosaic at the "
-                         "head-major pool layout and stamps the legal "
-                         "BlockSpecs + VMEM estimates")
+                    help="deviceless COMPILE of the paged engine's "
+                         "programs for a TPU v5e with every Pallas "
+                         "kernel placed, per KV dtype (ops/pallas/"
+                         "aot.py — the real compiler, no chip); needs "
+                         "--block-size a multiple of 128; a refused "
+                         "kernel raises with the compiler's message")
     ap.add_argument("--fleet", action="store_true",
                     help="run ONLY the serving-fleet phase (router "
                          "goodput + victim TTFT vs one engine at "
@@ -1946,8 +1826,6 @@ def main(argv=None):
         args.repeats = 1
 
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
 
     if args.fleet_chaos:
@@ -2082,16 +1960,9 @@ def main(argv=None):
     # it on (auto = TPU; the interpreter is correctness-speed and gets
     # its own dedicated check under --smoke below)
     pallas_mode = pallas_policy.pallas_mode(args.pallas)
-    # timed only where the kernels would actually be IN the program:
-    # off-TPU the dispatch guard (decode.kernels_dispatchable) routes
-    # "on" to the XLA path, and timing that as "engine_paged_pallas"
-    # would report a fake 1.0x kernel speedup; on TPU the head-major
-    # kernels dispatch for real (per-shape lowering probes + VMEM
-    # budgets permitting)
-    from paddle_tpu.ops.pallas import decode as _pallas_decode_mod
-    pallas_timed = (pallas_mode == "on"
-                    and _pallas_decode_mod.kernels_dispatchable(
-                        pallas_mode))
+    # timed only where the compiled kernels are IN the program ("on";
+    # building that engine raises where they cannot be)
+    pallas_timed = pallas_mode == "on"
     pallas_tr = CompileTracker(storm_threshold=storm)
     mk_pallas = paged_factory(params, cfg, tracker=pallas_tr,
                               pallas=args.pallas, **paged_kw) \
@@ -2317,28 +2188,17 @@ def main(argv=None):
         metrics_write(**line)
 
     if args.tpu_check:
-        results["tpu_check"] = tpu_export_check(
-            params, cfg, block_size=args.block_size,
+        results["tpu_check"] = tpu_compile_check(
+            cfg, block_size=args.block_size,
             chunk_tokens=args.chunk_tokens, batch=args.batch,
             cache_len=args.cache_len)
         line = {"bench": "serving", "phase": "tpu_check",
-                **{k: v for k, v in results["tpu_check"].items()
-                   if not k.endswith("_detail")
-                   and k not in ("blockspecs", "vmem_bytes")}}
+                "device_kind": results["tpu_check"]["device_kind"],
+                "programs_compiled": {
+                    k: sorted(v) for k, v in
+                    results["tpu_check"].items() if isinstance(v, dict)}}
         print(json.dumps(line), flush=True)
         metrics_write(**line)
-        assert all(results["tpu_check"][f"xla_{d}_ok"]
-                   for d in ("fp32", "int8", "int4")), \
-            results["tpu_check"]
-        # head-major relayout contract: every serving kernel lowers
-        # through Mosaic at every KV dtype — a failed probe here is a
-        # layout regression, asserted outright AND exported as a
-        # sentinel boolean so it can never land silently
-        assert results["tpu_check"]["mosaic_ok"], {
-            k: v for k, v in results["tpu_check"].items()
-            if k.startswith("pallas_")}
-        results["mosaic_lowerable_ok"] = \
-            results["tpu_check"]["mosaic_ok"]
 
     # dedicated attribution replay: one more latency-phase run on a
     # fresh paged engine with request-lifecycle tracing captured — the
@@ -2410,4 +2270,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

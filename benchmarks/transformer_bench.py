@@ -33,7 +33,6 @@ def main():
     ap.add_argument("--layers", type=int, default=6)
     ap.add_argument("--vocab", type=int, default=32000)
     ap.add_argument("--iters", type=int, default=10)
-    ap.add_argument("--platform", default=None)
     ap.add_argument("--decode", action="store_true",
                     help="serving decode throughput (MHA vs GQA) instead "
                          "of training")
@@ -55,8 +54,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
 
     from paddle_tpu.models import transformer as tfm
@@ -184,4 +181,6 @@ def _run_decode(args, tfm, jax, jnp, rng):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

@@ -51,6 +51,7 @@ def attention_sweep(args):
 
     candidates = [(64, 64), (64, 128), (128, 64), (128, 128),
                   (128, 256), (256, 128), (256, 256), (128, 512)]
+    budget = fa.planning_budget(interpret=False)
     rng = np.random.RandomState(0)
     results = {}
     for seq, d, dname in itertools.product(
@@ -65,7 +66,7 @@ def attention_sweep(args):
             bq_c, bk_c = min(bq, seq), min(bk, seq)
             tp = fa._pad_to_blocks(seq, bq_c, bk_c)
             if fa._vmem_working_set(tp, d, bq_c, bk_c,
-                                    dtype.itemsize) > fa.VMEM_BYTES:
+                                    dtype.itemsize) > budget:
                 continue
             try:
                 f = jax.jit(lambda q_: fa.flash_attention(
@@ -119,15 +120,11 @@ def decode_sweep(args):
         q = jnp.asarray(rng.randn(B, Hkv, G, d), jnp.float32)
         pos = jnp.full((B,), span - 1, jnp.int32)
         best = None
-        for bs in (8, 16, 32, 64, 128):
+        for bs in (8, 16, 32, 64, 128, 256):
             if span % bs:
                 continue
             P = span // bs
             M = B * span                      # pool at arena parity
-            if not fd.decode_kernel_fits(M, P, bs, G, d, dtype):
-                print(f"  span={span} d={d} {dname} bs={bs}: VMEM "
-                      f"over budget, skipped", flush=True)
-                continue
             k = jnp.asarray(rng.randn(Hkv, M, d), dtype)   # head-major
             v = jnp.asarray(rng.randn(Hkv, M, d), dtype)
             pages = jnp.asarray(
@@ -199,17 +196,11 @@ def prefill_sweep(args):
         kck = jnp.asarray(rng.randn(C, Hkv, d), jnp.float32)
         vck = jnp.asarray(rng.randn(C, Hkv, d), jnp.float32)
         best = None
-        for bs in (8, 16, 32, 64, 128):
+        for bs in (8, 16, 32, 64, 128, 256):
             if span % bs:
                 continue
             P_ctx = span // bs
             M = args.slots * span             # pool at arena parity
-            if not fpf.prefill_kernel_fits(
-                    M, span, C, G, d, dtype,
-                    kv_dtype=dname if quant else "none"):
-                print(f"  span={span} C={C} d={d} {dname} bs={bs}: "
-                      f"VMEM over budget, skipped", flush=True)
-                continue
             d_st = d // 2 if dname == "int4" else d
             if quant:                              # head-major pools
                 k = jnp.asarray(rng.randint(-127, 128, (Hkv, M, d_st)),
@@ -304,4 +295,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils import compile_cache
+    compile_cache.configure()
     main()

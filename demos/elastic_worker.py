@@ -43,17 +43,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 _NDEV = int(os.environ.get("PADDLE_LOCAL_CPU_DEVICES", "4"))
 os.environ.setdefault("PADDLE_TPU_SEED", "42")
 os.environ.setdefault("PADDLE_TPU_COMPUTE_DTYPE", "float32")
-from paddle_tpu.utils.flags import set_xla_host_device_count  # noqa: E402
-
-set_xla_host_device_count(_NDEV)
-
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", _NDEV)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", _NDEV)
 
 import numpy as np  # noqa: E402
 

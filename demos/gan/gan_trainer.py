@@ -24,11 +24,9 @@ def main():
     ap.add_argument("--batch-size", type=int, default=64)
     ap.add_argument("--conv", action="store_true",
                     help="DCGAN-style conv G/D (28x28 images)")
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=99, platform=args.platform)
+    paddle.init(seed=99)
     cfg = gan.GANConfig(conv=args.conv)
     trainer = gan.GANTrainer(cfg, jax.random.PRNGKey(0))
 

@@ -33,11 +33,9 @@ def main():
     ap.add_argument("--passes", type=int, default=2)
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--checkpoint-dir", default=None)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=42, platform=args.platform)
+    paddle.init(seed=42)
     img = paddle.layer.data("pixel", paddle.data_type.dense_vector(784))
     lbl = paddle.layer.data("label", paddle.data_type.integer_value(10))
     out = lenet5(img)

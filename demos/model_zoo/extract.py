@@ -56,12 +56,10 @@ def main():
     ap.add_argument("--passes", type=int, default=1)
     ap.add_argument("--batches", type=int, default=4)
     ap.add_argument("--out-dir", default="/tmp/paddle_tpu_model_zoo")
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
     os.makedirs(args.out_dir, exist_ok=True)
 
-    paddle.init(seed=5, platform=args.platform)
+    paddle.init(seed=5)
     img, out, cost = build()
     if not args.retrain and os.path.exists(PRETRAINED):
         import gzip

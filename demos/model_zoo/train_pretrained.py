@@ -31,7 +31,6 @@ import paddle_tpu as paddle
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--passes", type=int, default=6)
-    ap.add_argument("--platform", default=None)
     ap.add_argument("--recipe", default=False,
                     help="fused_bn recipe: 1/int8/full/q8/defer/q8sr "
                     "(default dense)")
@@ -40,7 +39,7 @@ def main():
                            "pretrained")
     os.makedirs(out_dir, exist_ok=True)
 
-    paddle.init(seed=5, platform=args.platform)
+    paddle.init(seed=5)
     from extract import build                   # same topology as the demo
     img, out, cost = build(recipe=args.recipe)
     params = paddle.parameters.create(cost)

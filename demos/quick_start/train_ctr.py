@@ -23,11 +23,9 @@ def main():
     ap.add_argument("--batch-size", type=int, default=256)
     ap.add_argument("--wide-dim", type=int, default=10000)
     ap.add_argument("--vocab", type=int, default=10000)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=7, platform=args.platform)
+    paddle.init(seed=7)
     out, cost = ctr.ctr_wide_deep(args.wide_dim, args.vocab)
     params = paddle.parameters.create(cost)
     trainer = paddle.trainer.SGD(

@@ -66,10 +66,9 @@ def main():
     ap.add_argument("--net", choices=("bow", "emb", "cnn"), default="bow")
     ap.add_argument("--passes", type=int, default=2)
     ap.add_argument("--batch-size", type=int, default=64)
-    ap.add_argument("--platform", default=None)
     args = ap.parse_args()
 
-    paddle.init(seed=9, platform=args.platform)
+    paddle.init(seed=9)
     word_idx = {f"w{i}": i for i in range(VOCAB - 1)}
     word_idx["<unk>"] = VOCAB - 1
     reader = paddle.dataset.imdb.train(word_idx)

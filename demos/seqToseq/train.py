@@ -21,11 +21,9 @@ def main():
     ap.add_argument("--passes", type=int, default=2)
     ap.add_argument("--batch-size", type=int, default=32)
     ap.add_argument("--dict-size", type=int, default=1000)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=17, platform=args.platform)
+    paddle.init(seed=17)
     cost = seq2seq.seq2seq_train(args.dict_size, args.dict_size)
     params = paddle.parameters.create(cost)
     trainer = paddle.trainer.SGD(

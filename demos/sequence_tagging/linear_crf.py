@@ -24,11 +24,9 @@ def main():
     ap.add_argument("--batch-size", type=int, default=32)
     ap.add_argument("--vocab", type=int, default=500)
     ap.add_argument("--tags", type=int, default=7)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=11, platform=args.platform)
+    paddle.init(seed=11)
     words = layer.data("words", paddle.data_type.integer_value_sequence(
         args.vocab))
     tags = layer.data("tags", paddle.data_type.integer_value_sequence(

@@ -4,7 +4,7 @@ search over the KV cache (reference workflow slot: seqToseq generation +
 trainer/tests/test_recurrent_machine_generation.cpp — the transformer
 flagship's serving loop).
 
-Run: python demos/text_generation/generate.py [--steps N] [--platform cpu]
+Run: python demos/text_generation/generate.py [--steps N]
 """
 
 import argparse
@@ -19,11 +19,10 @@ import numpy as np
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
-    ap.add_argument("--platform", default=None)
     args = ap.parse_args()
 
     import paddle_tpu as paddle
-    paddle.init(seed=3, platform=args.platform)
+    paddle.init(seed=3)
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models import transformer as tfm

@@ -43,11 +43,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--passes", type=int, default=3)
     ap.add_argument("--batch-size", type=int, default=128)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=23, platform=args.platform)
+    paddle.init(seed=23)
     encode = layer.data("link_encode",
                         paddle.data_type.dense_vector(TERM_NUM))
     hidden = layer.fc(encode, 16, act=paddle.activation.Relu(),

@@ -22,11 +22,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=300)
     ap.add_argument("--batch-size", type=int, default=128)
-    ap.add_argument("--platform", default=None,
-                    help="force a JAX platform (e.g. cpu)")
     args = ap.parse_args()
 
-    paddle.init(seed=13, platform=args.platform)
+    paddle.init(seed=13)
     trainer = vae.VAETrainer(vae.VAEConfig(), jax.random.PRNGKey(0))
     reader = paddle.batch(paddle.dataset.mnist.train(), args.batch_size)
     key = jax.random.PRNGKey(1)

@@ -70,27 +70,11 @@ def init(**kwargs):
 
     Accepts the historical flags (use_gpu, trainer_count, ...) for source
     compatibility; aliased names map onto their TPU equivalents, other
-    unknown flags are ignored as the reference's init did.
+    unknown flags are ignored as the reference's init did. The JAX
+    platform is chosen the JAX way: the ``JAX_PLATFORMS`` env var.
     """
     from paddle_tpu.utils import flags as _flags
     from paddle_tpu.utils import rng as _rng
-    if kwargs.get("platform"):
-        # must run before any jax computation; the JAX_PLATFORMS env var
-        # cannot serve here because site hooks may override it
-        import jax
-        try:
-            # best-effort diagnostic only: a private API that any JAX
-            # upgrade may rename; the config update below is what matters
-            from jax._src import xla_bridge
-            already = xla_bridge.backends_are_initialized()
-        except (ImportError, AttributeError):
-            already = False
-        if already:
-            raise RuntimeError(
-                "paddle.init(platform=...) called after the JAX backend "
-                "was already initialized - the setting would be silently "
-                "ignored. Call init() before any jax computation.")
-        jax.config.update("jax_platforms", kwargs["platform"])
     for k, v in kwargs.items():
         _flags.GLOBAL_FLAGS.set_if_known(_LEGACY_FLAG_ALIASES.get(k, k), v)
     if kwargs.get("seed"):

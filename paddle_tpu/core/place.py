@@ -48,25 +48,35 @@ PEAK_FLOPS_TABLE = (
 )
 
 
-def peak_flops(device=None) -> Optional[float]:
+def peak_flops(device=None, *, required: bool = False) -> Optional[float]:
     """Declared peak FLOP/s of ``device`` (default: the default device).
 
     Resolution order: ``PADDLE_TPU_PEAK_TFLOPS`` (in TFLOP/s) →
     longest-matching ``PEAK_FLOPS_TABLE`` entry against the device kind
     → None (unknown hardware; MFU reporting then stays silent rather
-    than inventing a denominator)."""
+    than inventing a denominator).
+
+    ``required=True`` is the benchmark path, where the result divides a
+    printed device metric: only the table counts, the device must be a
+    TPU (the nominal "cpu" entry never reaches a printed number) and an
+    unknown ``device_kind`` raises instead of returning None."""
+    device = device or default_device()
     env = os.environ.get("PADDLE_TPU_PEAK_TFLOPS")
-    if env:
+    if env and not required:
         try:
             return float(env) * 1e12
         except ValueError:
             pass
-    device = device or default_device()
     kind = (getattr(device, "device_kind", "") or device.platform).lower()
     best = None
     for pat, flops in PEAK_FLOPS_TABLE:
         if pat in kind and (best is None or len(pat) > len(best[0])):
             best = (pat, flops)
+    if required and (device.platform != "tpu" or best is None):
+        raise ValueError(
+            f"no declared peak for device {device.platform!r} kind "
+            f"{kind!r}: a benchmark needs a TPU listed in "
+            f"PEAK_FLOPS_TABLE (source: published per-chip bf16 peaks)")
     return best[1] if best else None
 
 

@@ -25,6 +25,7 @@ from paddle_tpu.io.checkpoint import _flatten          # shared pytree walk
 from paddle_tpu.io.merged import _add_member as _add   # shared tar append
 from paddle_tpu.observe import costs as _costs
 from paddle_tpu.observe import metrics as _metrics
+from paddle_tpu.serving import blocks as _blocks
 
 FORMAT_VERSION = 5   # max supported; plain artifacts still save as v1,
 #                      int8-weight ones as v2; v3 adds the continuous-
@@ -114,7 +115,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                      weights_int8: bool = False,
                      engine_buckets: Optional[Sequence[int]] = None,
                      engine_paged: bool = False,
-                     engine_block_size: int = 16,
+                     engine_block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
                      engine_num_blocks: Optional[int] = None,
                      engine_kv_dtype: Optional[str] = None,
                      engine_draft_params=None,
@@ -126,6 +127,12 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     batch/prompt_len/cache_len fix the exported shapes (AOT modules are
     shape-specialized; export several artifacts for several shapes).
     ``platforms`` e.g. ["tpu", "cpu"] widens where the module may run.
+    The engine modules' ``PADDLE_TPU_PALLAS`` policy resolves against
+    that TARGET (``auto`` places the compiled kernels iff every target
+    platform is TPU), never against the exporting process's backend;
+    what each module placed is stamped in ``meta.engine_kernel_paths``.
+    Exporting compiled kernels from a host without the chip needs the
+    target named: ``ops.pallas.policy.compile_target(device_kind)``.
     ``weights_int8`` stores the big matmul weights as per-output-channel
     int8 (see quantize_lm_params) — the exported modules dequantize
     inline, so the loader and LMServer are unchanged.
@@ -229,10 +236,15 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     if engine_buckets:
         from paddle_tpu.ops.pallas import policy as _pallas_policy
         from paddle_tpu.serving import sampling as _sampling
-        # stamp which attention/sampling path the engine modules were
-        # compiled with (the resolved PADDLE_TPU_PALLAS policy at
-        # export time) — a loader cannot re-derive it from the .bin
-        engine_pallas = _pallas_policy.pallas_mode(None)
+        # the policy the engine modules are built under, resolved
+        # against the platform they are exported FOR (a Mosaic kernel
+        # lowers for TPU only, so a mixed target list is the XLA path);
+        # stamped with the per-module placement record — a loader
+        # cannot re-derive either from the .bin
+        targets = set(platforms or [jax.default_backend()])
+        engine_pallas = _pallas_policy.pallas_mode(
+            None, platform=targets.pop() if len(targets) == 1
+            else "mixed")
         buckets = sorted({int(b) for b in engine_buckets})
         bad = [b for b in buckets if b < 1 or b > cache_len]
         if bad:
@@ -281,7 +293,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                                  "pool_layout":
                                      transformer.POOL_LAYOUT}
             eng_prefill, eng_decode = _sampling.paged_step_fns(
-                cfg, bs, dequant=dequant)
+                cfg, bs, dequant=dequant, pallas=engine_pallas)
             pool_shapes = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 transformer.init_block_pool(
@@ -314,8 +326,10 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                 dcfg = engine_draft_config
                 k = int(engine_spec_k)
                 W = k + 1
-                spec = _sampling.paged_spec_fns(cfg, dcfg, bs, k,
-                                                dequant=dequant)
+                spec = _sampling.paged_spec_fns(
+                    cfg, dcfg, bs, k, dequant=dequant,
+                    pallas=engine_pallas,
+                    paths=eng_decode.kernel_paths)
                 dp_shapes = jax.tree_util.tree_map(
                     lambda a: jax.ShapeDtypeStruct(
                         np.shape(a),
@@ -360,7 +374,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                         _vec(jnp.bool_), pages_s).serialize()
         else:
             eng_prefill, eng_decode = _sampling.engine_step_fns(
-                cfg, dequant=dequant)
+                cfg, dequant=dequant, pallas=engine_pallas)
             for b in buckets:
                 ep = jax.export.export(jax.jit(eng_prefill), **kw)(
                     p_shapes, cache_shapes,
@@ -386,7 +400,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         # its MFU numerator is the verify program's model FLOPs
         phases.append(("engine_verify", jit_verify, verify_args))
     for phase, fn, args in phases:
-        ca = _costs.lowered_cost(fn, *args)
+        ca = _costs.lowered_cost(fn, *args, platforms=platforms)
         if ca:
             cost_analysis[phase] = ca
 
@@ -404,6 +418,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     if engine_buckets:
         meta["engine_buckets"] = buckets
         meta["engine_pallas"] = engine_pallas
+        meta["engine_kernel_paths"] = eng_decode.kernel_paths
     if engine_paged_meta:
         meta["engine_paged"] = engine_paged_meta
     draft_blob = None
@@ -599,6 +614,7 @@ class LMServer:
                 decode_flops=self.cost_analysis.get(
                     "engine_decode", {}).get("flops"),
                 pallas_mode=self.meta.get("engine_pallas"),
+                kernel_paths=self.meta.get("engine_kernel_paths"),
                 kv_dtype=kvd, tiers=tiers)
             spec = self.meta.get("engine_spec")
             if spec:
@@ -674,7 +690,8 @@ class LMServer:
             tracker=tracker,
             decode_flops=self.cost_analysis.get(
                 "engine_decode", {}).get("flops"),
-            pallas_mode=self.meta.get("engine_pallas"))
+            pallas_mode=self.meta.get("engine_pallas"),
+            kernel_paths=self.meta.get("engine_kernel_paths"))
 
     def generate(self, prompt: np.ndarray, max_new: int,
                  temperature: float = 0.0,

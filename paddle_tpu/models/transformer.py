@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from paddle_tpu.core import place
 from paddle_tpu.ops import loss as ops_loss
 from paddle_tpu.ops import norm as ops_norm
+from paddle_tpu.ops.pallas import policy as _pallas_policy
 from paddle_tpu.parallel import ring
 
 
@@ -387,6 +388,7 @@ def _forward_impl(params, tokens, cfg, mesh, lengths, return_kv, head,
             from paddle_tpu.ops.pallas import flash_attention
             attn = flash_attention(q, k, v, causal=True)
         else:
+            _pallas_policy.note_path("attention", _pallas_policy.PATH_XLA)
             attn = ring.full_attention(q, k, v, causal=True, lengths=lengths)
         attn = attn.reshape(B, T, cfg.d_model)
         x = x + drop(jnp.einsum("btd,de->bte", attn,
@@ -728,6 +730,7 @@ def decode_step_slots(params, cache, tokens: jax.Array, pos: jax.Array,
     kvd = Hkv * Dh
     max_len = cache["k"].shape[2]
     quantized = _blocks_quantized(params)
+    _pallas_policy.note_path("attention", _pallas_policy.PATH_XLA)
     pos = jnp.asarray(pos, jnp.int32)
     x = _embed_rows(params, tokens, cfg)
     if not cfg.use_rope:
@@ -824,9 +827,10 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
 
     ``pallas`` picks the attention engine through the package-wide
     ``PADDLE_TPU_PALLAS`` policy (explicit arg > env > auto): when it
-    resolves ``on``/``interpret`` (and the working set passes the VMEM
-    budget), the gather + score + softmax + weighted sum above is
-    replaced by ``ops.pallas.decode.flash_decode_attention`` — page
+    resolves ``on``/``interpret``, the gather + score + softmax +
+    weighted sum above is replaced by
+    ``ops.pallas.decode.flash_decode_attention`` (which raises if the
+    chip cannot take the geometry — nothing degrades to XLA) — page
     indices resolved inside the kernel, K/V streamed from the pool, no
     gathered ``[B, T, Hkv, Dh]`` view or ``[B, H, T]`` score tensor in
     HBM, bitwise the XLA path's logits on aligned fp32 shapes (pinned
@@ -847,7 +851,6 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     quantized path (tests/test_kv_quant.py)."""
     from paddle_tpu.ops import q8 as ops_q8
     from paddle_tpu.ops.pallas import decode as _pallas_decode
-    from paddle_tpu.ops.pallas import policy as _pallas_policy
     B = tokens.shape[0]
     P = pages.shape[1]
     bs = int(block_size)
@@ -859,18 +862,11 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     quantized = _blocks_quantized(params)
     kvq = pool_kv_dtype(cache, cfg)       # "none" | "int8" | "int4"
     mode = _pallas_policy.pallas_mode(pallas)
-    # dispatchable (backend), the VMEM budget, AND the per-shape Mosaic
-    # lowering probe: each falls back to the pure-XLA path below rather
-    # than failing the compile
-    use_pallas = _pallas_decode.kernels_dispatchable(mode)
-    if use_pallas and mode == "on" and not (
-            _pallas_decode.decode_kernel_fits(
-                M, P, bs, H // Hkv, Dh, cache["k"].dtype, kv_dtype=kvq)
-            and _pallas_decode.decode_lowering_ok(
-                M, P, bs, Hkv, H // Hkv, Dh, cache["k"].dtype,
-                kv_dtype=kvq, q_dtype=cfg.dtype)):
-        use_pallas = False          # pure-XLA fallback rather than an
-        #                             opaque Mosaic failure
+    # only "off" takes the XLA path: a kernel the chip cannot take
+    # raises inside flash_decode_attention, it does not degrade
+    use_pallas = mode != "off"
+    _pallas_policy.note_path("attention",
+                             _pallas_policy.kernel_path(mode))
     pos = jnp.asarray(pos, jnp.int32)
     pages = jnp.asarray(pages, jnp.int32)
     x = _embed_rows(params, tokens, cfg)
@@ -1059,6 +1055,8 @@ def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     M = cache["k"].shape[2]
     quantized = _blocks_quantized(params)
     kvq = pool_kv_dtype(cache, cfg)
+    # the verify window runs the XLA gather attention under every mode
+    _pallas_policy.note_path("attention", _pallas_policy.PATH_XLA)
     pos = jnp.asarray(pos, jnp.int32)
     valid = jnp.asarray(valid, jnp.int32)
     pages = jnp.asarray(pages, jnp.int32)
@@ -1236,10 +1234,9 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
     with the dequant fused, one exact softmax over the concat — no
     gathered context or [C, S+C] score tensor in HBM) and the span
     writes run the ``paged_span_write`` kernel (block-mapped through
-    the page vector via scalar prefetch). The XLA path above stays the
-    always-available fallback and the numerics reference."""
+    the page vector via scalar prefetch). The XLA path above is what
+    ``off`` selects, and the numerics reference."""
     from paddle_tpu.ops import q8 as ops_q8
-    from paddle_tpu.ops.pallas import policy as _pallas_policy
     if tokens.shape[0] != 1:
         raise ValueError(f"prefill_into_blocks takes one request "
                          f"([1, C] tokens), got {tokens.shape}")
@@ -1255,24 +1252,11 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
     Hkv = cfg.kv_heads
     kvd = Hkv * Dh
     kvq = pool_kv_dtype(cache, cfg)
-    M = cache["k"].shape[2]
     mode = _pallas_policy.pallas_mode(pallas)
-    from paddle_tpu.ops.pallas import decode as _pallas_decode
-    use_pallas = _pallas_decode.kernels_dispatchable(mode)
-    if use_pallas:
-        from paddle_tpu.ops.pallas import prefill as _pallas_prefill
-        if mode == "on" and not (
-                _pallas_prefill.prefill_kernel_fits(
-                    M, S, C, H // Hkv, Dh, cache["k"].dtype,
-                    kv_dtype=kvq, block_size=bs)
-                and _pallas_prefill.prefill_lowering_ok(
-                    M, S, C, bs, Hkv, H // Hkv, Dh, cache["k"].dtype,
-                    kv_dtype=kvq, q_dtype=cfg.dtype)
-                and _pallas_prefill.span_write_lowering_ok(
-                    M, -(-C // bs), bs, cfg.n_layers, Hkv,
-                    Dh, cache["k"].dtype, kv_dtype=kvq)):
-            use_pallas = False      # XLA fallback, not a Mosaic OOM
-            #                         or an opaque tiling rejection
+    # only "off" takes the XLA path (see decode_step_paged)
+    use_pallas = mode != "off"
+    for site in ("attention", "span_write"):
+        _pallas_policy.note_path(site, _pallas_policy.kernel_path(mode))
     length = jnp.asarray(length, jnp.int32)
     pages = jnp.asarray(pages, jnp.int32)
     gpos = S + jnp.arange(C, dtype=jnp.int32)            # [C] global

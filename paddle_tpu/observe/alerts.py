@@ -30,7 +30,7 @@ clock running) → ``firing`` (held for ``for_s``) → back to
 A rule whose metric (or labeled series) does not exist yet evaluates
 as NOT breached — absence of traffic is not an incident.
 
-Stdlib-only (the CLI and bench orchestrator import observe).
+Stdlib-only (the CLI and JAX-free launchers import observe).
 """
 
 import dataclasses

@@ -15,7 +15,7 @@ per process. Timestamps are wall-clock epoch microseconds for the same
 reason — hosts share a clock to NTP precision, which is enough to line
 up multi-second training steps.
 
-Stdlib-only and jax-free at import time (the bench orchestrator and the
+Stdlib-only and jax-free at import time (JAX-free launchers and the
 CLI both import ``observe``).
 """
 

@@ -14,7 +14,7 @@ number the perf program steers by — "15.9% MFU" says exactly how far
 from "as fast as the hardware allows" a run is, where images/sec says
 nothing across models.
 
-jax-free at import time (the CLI and bench orchestrator import
+jax-free at import time (the CLI and JAX-free launchers import
 ``observe``); every jax touch is inside a function and failure-tolerant
 — cost accounting must never take down a training loop.
 """
@@ -55,16 +55,22 @@ def normalize_cost(analysis) -> Optional[dict]:
             "bytes_accessed": float(nbytes or 0.0)}
 
 
-def lowered_cost(fn, *args) -> Optional[dict]:
+def lowered_cost(fn, *args, platforms=None) -> Optional[dict]:
     """FLOPs/bytes of ``fn(*args)`` from the lowered HLO cost model.
 
     ``fn`` is a jitted function; ``args`` may be concrete arrays or
     ShapeDtypeStructs (concrete args are abstracted first — nothing
-    executes). Returns ``{"flops", "bytes_accessed"}`` or None when the
-    lowering or the cost model is unavailable.
+    executes). ``platforms`` lowers for those platforms instead of the
+    default backend (an export for a chip this host lacks). Returns
+    ``{"flops", "bytes_accessed"}`` or None when the cost model is
+    unavailable. A program that does not trace or lower raises: that
+    is the caller's program failing (a kernel refused at trace time,
+    say), not missing accounting.
     """
+    traced = fn.trace(*_abstract(args))
+    lowered = traced.lower(lowering_platforms=tuple(platforms)) \
+        if platforms else traced.lower()
     try:
-        lowered = fn.lower(*_abstract(args))
         return normalize_cost(lowered.cost_analysis())
     except Exception:  # noqa: BLE001 — accounting is best-effort
         return None

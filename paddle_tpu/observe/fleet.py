@@ -39,7 +39,7 @@ gauges, delta-summed counters, and pooled
 ``gang_step_time_window_seconds{q}`` with the identical
 never-average-per-rank-p99s semantics.
 
-Stdlib-only (the CLI and bench orchestrator import observe).
+Stdlib-only (the CLI and JAX-free launchers import observe).
 """
 
 import os

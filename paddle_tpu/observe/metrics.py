@@ -10,8 +10,8 @@ serving/training stack needs typed, labeled, exportable series. Two sinks:
 - ``render_prometheus()`` — Prometheus text exposition format, so a
   scrape endpoint (or a test) can read a snapshot of any registry.
 
-Deliberately stdlib-only: bench.py's orchestrator (which never imports
-jax) and the CLI both import this module.
+Deliberately stdlib-only: launchers that must stay off JAX (the `route`
+parent, chip_smoke.py's parent) and the CLI both import this module.
 """
 
 import json

@@ -37,13 +37,10 @@ def current_scope() -> str:
 
 def _profiler_ctx(kind: str, name: str, **kw):
     """A profiler annotation context, or nullcontext when the profiler
-    is unavailable (jax absent / too old) — never an ImportError."""
+    is unavailable — never an ImportError."""
     try:
         import jax.profiler
-        cls = getattr(jax.profiler, kind, None)
-        if cls is None:
-            return contextlib.nullcontext()
-        return cls(name, **kw)
+        return getattr(jax.profiler, kind)(name, **kw)
     except Exception:  # noqa: BLE001 — observability must not crash the job
         return contextlib.nullcontext()
 

@@ -23,7 +23,7 @@ the error budget (``1 - target``) — 1.0 means the budget is being
 spent exactly as fast as it accrues; the default threshold flags
 anything past that.
 
-Stdlib-only (the CLI and bench orchestrator import observe).
+Stdlib-only (the CLI and JAX-free launchers import observe).
 """
 
 import dataclasses

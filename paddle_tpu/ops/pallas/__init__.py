@@ -2,9 +2,9 @@
 is insufficient (reference slot: the hand-written CUDA in
 paddle/cuda/src/hl_cuda_*.cu; see /opt/skills/guides/pallas_guide.md).
 
-Each kernel ships with a jnp reference implementation and dispatches to it
-off-TPU, so the package runs everywhere; tests exercise the kernels in
-Pallas interpret mode on CPU.
+Each kernel ships with a jnp/XLA reference implementation, which is what
+``auto`` selects off-TPU, so the package runs everywhere; tests exercise
+the kernels in Pallas interpret mode on CPU.
 
 Dispatch policy — ``PADDLE_TPU_PALLAS``
 ---------------------------------------
@@ -13,10 +13,11 @@ every kernel in this package (``attention.flash_attention``,
 ``decode.flash_decode_attention`` / ``decode.fused_sample`` and whatever
 lands next):
 
-- ``auto`` (default) — kernels on TPU, jnp/XLA fallback elsewhere;
-- ``on``        — compile the kernels on the current backend;
-- ``off``       — always the pure-XLA fallback (the path every feature
-  keeps available — correctness never depends on Pallas);
+- ``auto`` (default) — ``on`` on TPU, ``off`` elsewhere;
+- ``on``        — place the compiled kernels, or raise (a geometry the
+  compiler refuses, a working set past the chip's VMEM, a backend that
+  cannot compile Mosaic): nothing degrades to the XLA path;
+- ``off``       — the pure-XLA path (the only way to get it);
 - ``interpret`` — run the kernels through the Pallas interpreter (the
   CPU correctness path tier-1 exercises).
 

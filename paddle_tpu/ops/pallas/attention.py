@@ -15,8 +15,9 @@ streams query blocks, reconstructing p exactly from the saved logsumexp —
 no O(T²) tensor exists in either direction; dq accumulates in an fp32
 output revisited across key-block grid steps.
 
-Off-TPU the public entry falls back to the jnp reference; tests run the
-kernel in interpret mode.
+Under ``PADDLE_TPU_PALLAS=off`` (what ``auto`` resolves to off-TPU) the
+public entry runs the jnp reference; tests run the kernel in interpret
+mode.
 """
 
 import functools
@@ -27,15 +28,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import policy as _policy
+
 NEG_INF = -1e30
 
 # ---------------------------------------------------------------------------
 # block-size selection
 # ---------------------------------------------------------------------------
-
-# usable per-core VMEM on current TPUs (v4/v5 families ship 16 MiB; leave
-# compiler headroom for spills, semaphores and double-buffering)
-VMEM_BYTES = int(16 * 2**20 * 0.85)
 
 # measured-best blocks keyed (seq_bucket, head_dim, dtype_name) — filled
 # from on-chip sweeps (benchmarks/tune_flash_blocks.py); consulted before
@@ -66,10 +65,23 @@ def _vmem_working_set(tp: int, d: int, bq: int, bk: int,
     return max(fwd, bwd)
 
 
-def select_block_sizes(seq: int, head_dim: int, dtype) -> Tuple[int, int]:
+def planning_budget(interpret: bool) -> int:
+    """VMEM bytes block selection plans against: the target chip's
+    budget for a compiled kernel; the interpreter has no VMEM, so it
+    plans against the smallest scoped default and picks the blocks a
+    real chip would."""
+    if interpret:
+        return int(_policy.SCOPED_VMEM_DEFAULT_BYTES
+                   * _policy.VMEM_PLANNING_SHARE)
+    return _policy.vmem_budget_bytes()
+
+
+def select_block_sizes(seq: int, head_dim: int, dtype,
+                       vmem_budget: int) -> Tuple[int, int]:
     """(block_q, block_k) for the flash kernels, keyed on the problem
     shape: a measured table first, then the analytic default (128, 128 —
-    the MXU-native tile), always validated against the VMEM budget.
+    the MXU-native tile), always validated against ``vmem_budget``
+    bytes (``policy.vmem_budget_bytes()`` of the target chip).
     Raises with a actionable message when no block choice can fit —
     the caller should shard the sequence (ring attention) instead of
     letting Mosaic fail opaquely."""
@@ -84,11 +96,11 @@ def select_block_sizes(seq: int, head_dim: int, dtype) -> Tuple[int, int]:
         bq_c, bk_c = min(bq, seq), min(bk, seq)
         tp = _pad_to_blocks(seq, bq_c, bk_c)
         if _vmem_working_set(tp, head_dim, bq_c, bk_c,
-                             itemsize) <= VMEM_BYTES:
+                             itemsize) <= vmem_budget:
             return bq_c, bk_c
     raise ValueError(
         f"flash attention: no block size fits seq={seq} head_dim="
-        f"{head_dim} dtype={name} in ~{VMEM_BYTES >> 20} MiB VMEM — the "
+        f"{head_dim} dtype={name} in ~{vmem_budget >> 20} MiB VMEM — the "
         f"whole K/V must reside per grid program. Shard the sequence "
         f"(use_ring_attention over a seq mesh axis) or reduce head_dim.")
 
@@ -181,6 +193,10 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, nq, block_q), jnp.float32),
         ],
         interpret=interpret,
+        **_policy.compiled_kernel_params(
+            interpret, _vmem_working_set(tp, d, block_q, block_k,
+                                         q.dtype.itemsize),
+            "flash_attention"),
     )(q, k, v)
     return out[:, :t], lse.reshape(bh, tp)[:, :t]
 
@@ -311,6 +327,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, sm_scale, causal, block_q,
             jax.ShapeDtypeStruct((bh, tp, d), v.dtype),
         ],
         interpret=interpret,
+        **_policy.compiled_kernel_params(
+            interpret, _vmem_working_set(tp, d, block_q, block_k,
+                                         q.dtype.itemsize),
+            "flash_attention"),
     )(q, k, v, do, lse, delta)
     return dq[:, :t].astype(q.dtype), dk[:, :t], dv[:, :t]
 
@@ -361,11 +381,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     (parallel/ring.py) keeps collectives at Hkv heads and expands locally
     per ring step. Dispatch resolves through the package-wide
     ``PADDLE_TPU_PALLAS`` policy (``ops/pallas/policy.py``): ``auto``
-    keeps the historical behaviour — kernel on TPU, jnp reference
-    elsewhere — while the env var (or the ``interpret`` arg, which wins
-    over it: True pins the interpreter, False the compiled kernel) can
-    force any path on any backend."""
-    from paddle_tpu.ops.pallas import policy as _policy
+    is the kernel on TPU and the jnp reference elsewhere, while the env
+    var (or the ``interpret`` arg, which wins over it: True pins the
+    interpreter, False the compiled kernel) can force any path on any
+    backend — a compiled kernel the backend or the chip's VMEM cannot
+    take raises, it never degrades to the reference."""
     b, t, h, d = q.shape
     if k.shape[2] != h:
         k = jnp.repeat(k, h // k.shape[2], axis=2)
@@ -377,6 +397,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     mode = _policy.pallas_mode(
         None if interpret is None else
         ("interpret" if interpret else "on"))
+    _policy.note_path("attention", _policy.kernel_path(mode))
     if mode == "off":
         out = _reference(qr, kr, vr, sm_scale, causal)
     else:
@@ -387,7 +408,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         if block_q and block_k:
             bq, bk = min(block_q, t), min(block_k, t)
         else:
-            bq_auto, bk_auto = select_block_sizes(t, d, q.dtype)
+            bq_auto, bk_auto = select_block_sizes(
+                t, d, q.dtype, planning_budget(mode == "interpret"))
             bq = min(block_q, t) if block_q else bq_auto
             bk = min(block_k, t) if block_k else bk_auto
         out = _flash(qr, kr, vr, sm_scale, causal, bq, bk,

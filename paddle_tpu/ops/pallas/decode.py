@@ -31,19 +31,26 @@ fp32 shapes (pinned in tests/test_pallas_decode.py): an online softmax
 normalizes ``(p@v)/l`` where XLA computes ``(p/l)@v``, a rounding
 difference the streaming buys nothing for at decode shapes.
 
-Every BlockSpec in this file is **Mosaic-legal** under the TPU tiling
-rule (the last two block dims must each be divisible by the dtype's
-native tile — (8, 128) fp32, (16, 128) bf16, (32, 128) int8 — or equal
-the array dims): the head-major pool makes each program's block
-``(1, block_size, Dh)`` with the singleton on a LEADING dim, quantized
-scale columns ride as ``[Hkv, M, 1]`` views (trailing singleton ==
-array dim), and the page/pos/seed/temperature/top-k vectors live in
-SMEM via scalar prefetch where no tiling rule applies. Whether a given
-shape ACTUALLY lowers is never assumed: dispatch asks
-:func:`decode_lowering_ok` — a cached deviceless XLA:TPU lowering probe
-of the real kernel call — and falls back to the XLA path on a refusal
-(``serving_bench.py --tpu-check`` asserts the probes hold and stamps
-the legal BlockSpecs + VMEM estimates into its artifact).
+Every BlockSpec in this file follows the TPU tiling rule (the last two
+block dims must each be divisible by the dtype's native tile — (8, 128)
+fp32, (16, 128) bf16, (32, 128) int8 — or equal the array dims): the
+head-major pool makes each program's block ``(1, block_size, Dh)`` with
+the singleton on a LEADING dim, quantized scale columns ride as
+``[Hkv, M, 1]`` views (trailing singleton == array dim), and the
+page/pos/seed/temperature/top-k vectors live in SMEM via scalar prefetch
+where no tiling rule applies. The COMPILED kernel additionally needs
+``block_size`` to be a multiple of 128: each page step stores its
+partial scores at lane offset ``page·block_size`` of the score scratch,
+and Mosaic only takes a dynamic lane offset it can prove 128-aligned
+(block 16/32 is refused with "cannot statically prove that index in
+dimension 1 is a multiple of 128"; 128 compiles — v5e, libtpu 0.0.34).
+That is why ``serving.blocks.DEFAULT_BLOCK_SIZE`` is 128; other block
+sizes still run interpreted. Nothing here probes or falls back: a
+compiled kernel whose geometry is illegal or whose working set passes
+the chip's VMEM raises (``policy.vmem_limit_bytes``), and the compiler's
+own refusal propagates with its message. tests/test_aot_tpu_compile.py
+compiles every serving kernel for a v5e with the real compiler,
+devicelessly.
 
 ``fused_sample`` is the epilogue: greedy / temperature / top-k sampling
 (``serving/sampling.sample_tokens`` semantics, per-slot runtime vectors)
@@ -61,13 +68,12 @@ Counting/argmax reductions run over exact small-integer fp32 images
 far above any vocab).
 
 Dispatch resolves through the package-wide ``PADDLE_TPU_PALLAS`` policy
-(``ops/pallas/policy.py``); the pure-XLA gather path in
-``transformer.decode_step_paged`` remains the always-available fallback.
+(``ops/pallas/policy.py``); only ``off`` selects the pure-XLA gather
+path in ``transformer.decode_step_paged``.
 """
 
 import functools
 import math
-import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -75,7 +81,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.attention import VMEM_BYTES
+from paddle_tpu.ops.pallas import policy as _policy
 
 NEG_INF = -1e30
 
@@ -85,77 +91,18 @@ NEG_INF = -1e30
 # would otherwise advise tiles for a pool shape that no longer exists).
 POOL_LAYOUT = "head_major"
 
-_warned_fallback = set()        # modes that already warned (once per mode)
 
-# cached verdicts of the deviceless Mosaic lowering probes, keyed by
-# (kernel kind, shape/dtype signature) — a probe is one tiny XLA:TPU
-# lowering with no chip attached (~a second, paid once per signature).
-# Refusals keep their diagnostic in _LOWERING_DETAIL (surfaced by the
-# once-per-key warning below and serving_bench --tpu-check), so a
-# silent XLA fallback on a real chip is never undiagnosable.
-_LOWERING_CACHE = {}
-_LOWERING_DETAIL = {}
-
-
-def kernels_dispatchable(mode: str) -> bool:
-    """Whether the resolved ``PADDLE_TPU_PALLAS`` mode may place the
-    serving kernels in a compiled program on the current default
-    backend. ``interpret`` always can (the interpreter runs anywhere);
-    ``on`` requires a TPU backend — off-TPU it falls back to the XLA
-    path with a once-per-mode warning instead of failing the first
-    compile. On TPU the per-site guards still apply on top: the VMEM
-    ``*_kernel_fits`` budgets and the :func:`decode_lowering_ok` /
-    ``prefill.prefill_lowering_ok`` Mosaic probes (the head-major pool
-    relayout made the kernels lowerable; the probe — not a constant —
-    is what asserts it for the actual shapes)."""
-    if mode == "interpret":
-        return True
-    if mode != "on":
-        return False
-    if jax.default_backend() != "tpu":
-        if mode not in _warned_fallback:
-            _warned_fallback.add(mode)
-            warnings.warn(
-                "PADDLE_TPU_PALLAS resolved 'on' but the default "
-                "backend is not TPU; serving falls back to the "
-                "pure-XLA path (use 'interpret' to exercise the "
-                "kernels off-TPU).",
-                RuntimeWarning, stacklevel=2)
-        return False
-    return True
-
-
-def mosaic_lowerable(key, build) -> bool:
-    """Cached deviceless XLA:TPU lowering probe: ``build()`` must
-    return (fn, abstract args); the probe lowers ``jit(fn)`` for the
-    TPU platform with no device attached and records whether Mosaic
-    accepts the kernel. This is the real successor of the old
-    ``MOSAIC_LOWERABLE`` constant — per kernel, per shape signature,
-    measured instead of asserted."""
-    if key in _LOWERING_CACHE:
-        return _LOWERING_CACHE[key]
-    try:
-        import jax.export
-        fn, args = build()
-        jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
-        ok = True
-    except Exception as e:                            # noqa: BLE001
-        ok = False
-        _LOWERING_DETAIL[key] = f"{type(e).__name__}: {str(e)[:300]}"
-        warnings.warn(
-            f"Pallas kernel {key[0]!r} failed the Mosaic lowering "
-            f"probe (falls back to the XLA path): "
-            f"{_LOWERING_DETAIL[key]}", RuntimeWarning, stacklevel=2)
-    _LOWERING_CACHE[key] = ok
-    return ok
-
-
-def lowering_failures(kind: Optional[str] = None):
-    """Diagnostics of every probe REFUSAL so far (``{key: detail}``),
-    optionally filtered by kernel kind — what ``serving_bench.py
-    --tpu-check`` surfaces next to a failed ``*_ok`` boolean."""
-    return {k: v for k, v in _LOWERING_DETAIL.items()
-            if kind is None or k[0] == kind}
+def check_compiled_block_size(block_size: int, what: str):
+    """A compiled (non-interpret) kernel's geometry gate: raises for a
+    block size Mosaic refuses instead of letting the first compile die
+    with a vector-store alignment message."""
+    if int(block_size) % 128:
+        raise ValueError(
+            f"{what}: block_size {block_size} is not a multiple of "
+            f"128 — the compiled kernel stores each page's scores at "
+            f"lane offset page*block_size, which Mosaic only accepts "
+            f"128-aligned. Use a block size of 128 (the engine "
+            f"default), PADDLE_TPU_PALLAS=interpret, or =off.")
 
 
 def _kv_store_dims(Dh: int, dtype, kv_dtype: str):
@@ -168,63 +115,6 @@ def _kv_store_dims(Dh: int, dtype, kv_dtype: str):
     if kv_dtype == "int4":
         return Dh // 2, 1, "int4"
     return Dh, 1, "int8"
-
-
-def decode_lowering_ok(M: int, P: int, block_size: int, Hkv: int,
-                       G: int, Dh: int, dtype,
-                       kv_dtype: str = "none",
-                       q_dtype=None) -> bool:
-    """Mosaic lowering probe for :func:`flash_decode_attention` at the
-    given pool geometry (deviceless, cached). ``mode="on"`` dispatch
-    asks this before placing the kernel in a program so an unlowerable
-    shape degrades to the XLA path instead of failing the compile.
-    ``q_dtype`` is the ACTIVATION dtype the caller's q arrives in
-    (tiling is dtype-dependent, so the probe must lower the very
-    program the dispatch would build); it defaults to the pool dtype —
-    right for fp pools, but quantized-pool callers must pass their
-    model dtype explicitly."""
-    if q_dtype is None:
-        q_dtype = dtype if kv_dtype in (None, "none") else jnp.float32
-    Dh_st, _, name = _kv_store_dims(Dh, dtype, kv_dtype)
-    quant = kv_dtype not in (None, "none")
-    key = ("decode", M, P, int(block_size), Hkv, G, Dh, name,
-           jnp.dtype(q_dtype).name)
-
-    def build():
-        kv = jax.ShapeDtypeStruct(
-            (Hkv, M, Dh_st),
-            jnp.int8 if quant else jnp.dtype(dtype))
-        sc = jax.ShapeDtypeStruct((Hkv, M), jnp.float32)
-        args = [jax.ShapeDtypeStruct((2, Hkv, G, Dh),
-                                     jnp.dtype(q_dtype)),
-                kv, kv,
-                jax.ShapeDtypeStruct((2, P), jnp.int32),
-                jax.ShapeDtypeStruct((2,), jnp.int32)]
-        fn = functools.partial(
-            flash_decode_attention, block_size=block_size,
-            kv_dtype=kv_dtype)
-        if quant:
-            return (lambda q, k, v, pg, ps, ks, vs: fn(
-                q, k, v, pg, ps, k_scale=ks, v_scale=vs),
-                args + [sc, sc])
-        return fn, args
-
-    return mosaic_lowerable(key, build)
-
-
-def sample_lowering_ok(B: int, V: int) -> bool:
-    """Mosaic lowering probe for :func:`fused_sample` (cached,
-    deviceless) — the epilogue's dispatch guard on TPU."""
-    key = ("sample", B, V)
-
-    def build():
-        return fused_sample, [
-            jax.ShapeDtypeStruct((B, V), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.int32)]
-
-    return mosaic_lowerable(key, build)
 
 
 # ---------------------------------------------------------------------------
@@ -268,17 +158,6 @@ def decode_vmem_bytes(M: int, P: int, block_size: int, G: int, Dh: int,
             + T * Dh * 4                 # V scratch
             + 2 * G * Dh * 4             # q, out
             + 4 * tile * blk)            # 2x tile in-flight K/V blocks
-
-
-def decode_kernel_fits(M: int, P: int, block_size: int, G: int, Dh: int,
-                       dtype, kv_dtype: str = "none") -> bool:
-    """Whether the flash-decode working set fits the VMEM budget — the
-    dispatch guard: ``mode="on"`` falls back to the XLA gather path when
-    this says no, rather than letting Mosaic fail opaquely."""
-    itemsize = jnp.dtype(dtype).itemsize
-    tile = select_decode_tile(P, block_size, Dh, dtype, kv_dtype)
-    return decode_vmem_bytes(M, P, block_size, G, Dh, itemsize,
-                             kv_dtype, tile=tile) <= VMEM_BYTES
 
 
 def select_decode_tile(P: int, block_size: int, head_dim: int,
@@ -405,10 +284,10 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     or 1/2 (int4) byte/elt.
 
     Grid (slot, kv-head, page-step) with ``pages``/``pos`` scalar-
-    prefetched; the per-program working set must pass
-    ``decode_kernel_fits`` and the shape must pass
-    ``decode_lowering_ok`` (the dispatch in ``decode_step_paged``
-    guards both and falls back to XLA)."""
+    prefetched. Compiled (``interpret=False``) the block size must be
+    a multiple of 128 and the per-program working set
+    (``decode_vmem_bytes``) must fit the target chip's VMEM — both
+    raise here, at trace time, rather than degrade."""
     B, Hkv, G, Dh = q.shape             # Dh is always the LOGICAL dim
     quant = kv_dtype not in (None, "none")
     M = k.shape[1]
@@ -424,6 +303,12 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     tile = int(tile)
     Dh_st = k.shape[-1]                 # stored last dim (packed int4)
     T = P * bs
+    if not interpret:
+        check_compiled_block_size(bs, "flash_decode_attention")
+    params = _policy.compiled_kernel_params(
+        interpret, decode_vmem_bytes(M, P, bs, G, Dh, k.dtype.itemsize,
+                                     kv_dtype, tile=tile),
+        "flash_decode_attention")
     kernel = functools.partial(
         _decode_kernel, block_size=bs, P=P, tile=tile, G=G, Dh=Dh,
         scale=math.sqrt(Dh), kv_dtype=kv_dtype if quant else "none")
@@ -460,6 +345,7 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), jnp.float32),
         interpret=interpret,
+        **params,
     )(pages.astype(jnp.int32), jnp.asarray(pos, jnp.int32).reshape(B),
       *args)
 
@@ -517,7 +403,10 @@ def _hash_uniform(seed: jax.Array, row: jax.Array,
     h = h ^ (h >> 15)
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> 16)
-    return ((h >> 8).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    # 24 random bits as fp32: Mosaic has no uint32 -> float32 cast, and
+    # h >> 8 < 2^24 is the same number read as int32
+    bits = jax.lax.bitcast_convert_type(h >> 8, jnp.int32)
+    return (bits.astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
 
 
 def _first_argmax(x: jax.Array, iota: jax.Array) -> jax.Array:
@@ -540,7 +429,9 @@ def _sample_kernel(seed_ref, temp_ref, topk_ref, logits_ref, o_ref):
     row = pl.program_id(0)
     v = logits_ref[0, 0].astype(jnp.float32)[None, :]     # [1, V]
     V = v.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.float32, (1, V), 1)
+    # Mosaic's iota is integer-only; the fp32 image is exact below 2^24
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, V), 1).astype(
+        jnp.float32)
     greedy = _first_argmax(v, iota)
     k = jnp.clip(topk_ref[row], 0, V)
     keys = _sortable_key(v)

@@ -8,9 +8,11 @@ stages; the chunk's KV then lands in the pool as compiler-emitted
 masked-span writes (the exact pattern CUDA-L2 in PAPERS.md shows
 library-emitted kernels leave margin on). Two hand-scheduled kernels
 replace that, behind the same ``PADDLE_TPU_PALLAS`` knob as the decode
-kernels — both built for the head-major pool ``[Hkv, M, Dh]`` and both
-Mosaic-legal under the TPU tiling rule (see ops/pallas/decode.py for
-the rule and the probe machinery):
+kernels — both built for the head-major pool ``[Hkv, M, Dh]`` under
+the TPU tiling rule, and both raising (never degrading to XLA) when
+compiled at a geometry the chip cannot take; see ops/pallas/decode.py
+for the rule, the 128-multiple block size the compiled score-scratch
+stores need, and the VMEM policy:
 
 - :func:`flash_chunk_prefill` — one chunk's attention against its
   context, straight off the pool: grid ``(kv-head, ctx-page-step)``
@@ -24,17 +26,20 @@ the rule and the probe machinery):
   under the context-visible + chunk-causal mask. No gathered context
   view and no score tensor ever exist in HBM. Exact softmax (not
   online rescaling) for the same reason as ``flash_decode_attention``:
-  it reproduces the XLA fallback's op chain, so the interpret-mode
+  it reproduces the XLA path's op chain, so the interpret-mode
   kernel is BITWISE the XLA path on aligned fp32 shapes (pinned in
   tests/test_pallas_prefill.py).
 
 - :func:`paged_span_write` — the chunk's masked span writes: grid over
-  the chunk's pages, each program's output block mapped THROUGH the
+  (layer, chunk page), each program's output block mapped THROUGH the
   scalar-prefetched page vector, pool buffers aliased in-place. Padded
-  rows keep the span's old bytes (the RMW the XLA fallback expresses
+  rows keep the span's old bytes (the RMW the XLA path expresses
   as slice + where + update-slice), and quantized pools write values
   and scale rows through the same kernel (scale tables ride as
-  trailing-singleton ``[L, Hkv, M, 1]`` views — tiling-legal).
+  trailing-singleton ``[L, Hkv, M, 1]`` views — tiling-legal). One
+  program holds ONE layer's ``(1, Hkv, bs, Dh)`` span: a block over
+  all layers overflowed VMEM at block size 128 (RESOURCE_EXHAUSTED on
+  v5e, libtpu 0.0.34).
 
 Tiling: ``tile`` context pages stream per grid step (each its own
 scalar-prefetch-placed BlockSpec) — measured winners from
@@ -54,10 +59,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.attention import VMEM_BYTES
+from paddle_tpu.ops.pallas import policy as _policy
 from paddle_tpu.ops.pallas.decode import (NEG_INF, POOL_LAYOUT,
                                           _kv_store_dims, _widen_block,
-                                          mosaic_lowerable)
+                                          check_compiled_block_size)
 
 # measured-best (block_size, ctx pages-per-grid-step) keyed (POOL
 # layout, context-span bucket, chunk bucket, head_dim, dtype_name) —
@@ -102,106 +107,6 @@ def prefill_vmem_bytes(M: int, S: int, C: int, G: int, Dh: int,
             + 2 * C * Dh * 4             # chunk k/v tiles
             + 2 * C * G * Dh * 4         # q, out
             + stream)                    # in-flight context blocks
-
-
-def prefill_kernel_fits(M: int, S: int, C: int, G: int, Dh: int,
-                        dtype, kv_dtype: str = "none",
-                        block_size: Optional[int] = None) -> bool:
-    """Dispatch guard for ``mode="on"``: fall back to the XLA chunk
-    path when the working set exceeds the VMEM budget rather than
-    letting Mosaic fail opaquely. Pass ``block_size`` so the in-flight
-    stream is charged at the tile ``select_prefill_tile`` would
-    actually pick (a MEASURED_PREFILL winner can exceed the analytic
-    256-row cap; without it the default cap is charged)."""
-    itemsize = jnp.dtype(dtype).itemsize
-    stream_rows = None
-    if block_size and S:
-        bs = int(block_size)
-        tile = select_prefill_tile(S // bs, bs, C, Dh, dtype, kv_dtype)
-        stream_rows = tile * bs
-    return prefill_vmem_bytes(M, S, C, G, Dh, itemsize, kv_dtype,
-                              stream_rows=stream_rows) <= VMEM_BYTES
-
-
-def prefill_lowering_ok(M: int, S: int, C: int, block_size: int,
-                        Hkv: int, G: int, Dh: int, dtype,
-                        kv_dtype: str = "none",
-                        q_dtype=None) -> bool:
-    """Mosaic lowering probe for the chunk-prefill ATTENTION kernel at
-    the given geometry — deviceless and cached (see
-    ``decode.mosaic_lowerable``). The ``mode="on"`` dispatch consults
-    this together with :func:`span_write_lowering_ok` (the chunk's
-    other kernel). ``q_dtype`` is the caller's ACTIVATION dtype (q and
-    the chunk's own K/V arrive in it; tiling is dtype-dependent, so
-    the probe lowers the very program dispatch would build); defaults
-    to the pool dtype — quantized-pool callers pass their model dtype
-    explicitly."""
-    bs = int(block_size)
-    if q_dtype is None:
-        q_dtype = dtype if kv_dtype in (None, "none") else jnp.float32
-    Dh_st, _, name = _kv_store_dims(Dh, dtype, kv_dtype)
-    quant = kv_dtype not in (None, "none")
-    key = ("prefill", M, S, C, bs, Hkv, G, Dh, name,
-           jnp.dtype(q_dtype).name)
-
-    def build():
-        kvd = jnp.int8 if quant else jnp.dtype(dtype)
-        qd = jnp.dtype(q_dtype)
-        kv = jax.ShapeDtypeStruct((Hkv, M, Dh_st), kvd)
-        sc = jax.ShapeDtypeStruct((Hkv, M), jnp.float32)
-        P_ctx = S // bs
-        args = [jax.ShapeDtypeStruct((C, Hkv, G, Dh), qd),
-                jax.ShapeDtypeStruct((C, Hkv, Dh), qd),
-                jax.ShapeDtypeStruct((C, Hkv, Dh), qd),
-                kv, kv,
-                jax.ShapeDtypeStruct((P_ctx,), jnp.int32)]
-
-        def probe(q, kck, vck, k, v, pages, *scales):
-            ks, vs = (scales[0], scales[1]) if quant else (None, None)
-            return flash_chunk_prefill(
-                q, kck, vck, k, v, pages, block_size=bs,
-                k_scale=ks, v_scale=vs, kv_dtype=kv_dtype)
-
-        extra = [sc, sc] if quant else []
-        return probe, args + extra
-
-    return mosaic_lowerable(key, build)
-
-
-def span_write_lowering_ok(M: int, pc: int, block_size: int, L: int,
-                           Hkv: int, Dh: int, dtype,
-                           kv_dtype: str = "none") -> bool:
-    """Mosaic lowering probe for :func:`paged_span_write` (aliased
-    pool write, scale tables included for quantized pools) — cached,
-    deviceless."""
-    bs = int(block_size)
-    Dh_st, _, name = _kv_store_dims(Dh, dtype, kv_dtype)
-    quant = kv_dtype not in (None, "none")
-    key = ("span_write", M, pc, bs, L, Hkv, Dh, name)
-
-    def build():
-        kvd = jnp.int8 if quant else jnp.dtype(dtype)
-        span = jax.ShapeDtypeStruct((L, Hkv, pc * bs, Dh_st), kvd)
-        sspan = jax.ShapeDtypeStruct((L, Hkv, pc * bs), jnp.float32)
-        pool_kv = jax.ShapeDtypeStruct((L, Hkv, M, Dh_st), kvd)
-        pool_sc = jax.ShapeDtypeStruct((L, Hkv, M), jnp.float32)
-        args = [pool_kv, pool_kv, span, span,
-                jax.ShapeDtypeStruct((pc,), jnp.int32),
-                jax.ShapeDtypeStruct((pc * bs,), jnp.bool_)]
-
-        def probe(pk, pv, sk, sv, pages, valid, *scales):
-            pool_in = {"k": pk, "v": pv}
-            spans = {"k": sk, "v": sv}
-            if quant:
-                pool_in.update(k_scale=scales[0], v_scale=scales[1])
-                spans.update(k_scale=scales[2], v_scale=scales[3])
-            return paged_span_write(pool_in, spans, pages, valid,
-                                    block_size=bs)
-
-        extra = [pool_sc, pool_sc, sspan, sspan] if quant else []
-        return probe, args + extra
-
-    return mosaic_lowerable(key, build)
 
 
 def select_prefill_tile(P_ctx: int, block_size: int, chunk: int,
@@ -351,6 +256,13 @@ def flash_chunk_prefill(q: jax.Array, k_chunk: jax.Array,
     # head singleton second-to-last)
     kck = jnp.swapaxes(k_chunk, 0, 1)
     vck = jnp.swapaxes(v_chunk, 0, 1)
+    if P_ctx and not interpret:
+        check_compiled_block_size(bs, "flash_chunk_prefill")
+    params = _policy.compiled_kernel_params(
+        interpret, prefill_vmem_bytes(0, P_ctx * bs, C, G, Dh,
+                                      k.dtype.itemsize, kv_dtype,
+                                      stream_rows=tile * bs),
+        "flash_chunk_prefill")
     if not P_ctx:
         kernel = functools.partial(_cold_chunk_kernel, C=C, G=G, Dh=Dh,
                                    scale=math.sqrt(Dh))
@@ -367,6 +279,7 @@ def flash_chunk_prefill(q: jax.Array, k_chunk: jax.Array,
             out_shape=jax.ShapeDtypeStruct((C, Hkv, G, Dh),
                                            jnp.float32),
             interpret=interpret,
+            **params,
         )(q, kck, vck)
     M = k.shape[1]
     Dh_st = k.shape[-1]                 # stored last dim (packed int4)
@@ -412,6 +325,7 @@ def flash_chunk_prefill(q: jax.Array, k_chunk: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, Hkv, G, Dh), jnp.float32),
         interpret=interpret,
+        **params,
     )(pages.astype(jnp.int32), *args)
 
 
@@ -422,21 +336,43 @@ def flash_chunk_prefill(q: jax.Array, k_chunk: jax.Array,
 
 def _span_write_kernel(n: int):
     """Kernel over ``n`` (span, pool) array pairs: one grid program per
-    chunk page, output blocks mapped through the scalar-prefetched page
-    vector, pool buffers aliased — so each program touches exactly one
-    ``block_size``-token span per array. Padded rows (mask 0) keep the
-    pool's old bytes: the aliased output ref still HOLDS them, so the
-    masked select is a read-modify-write entirely in VMEM."""
+    (layer, chunk page), output blocks mapped through the scalar-
+    prefetched page vector, pool buffers aliased — so each program
+    touches exactly one layer's ``block_size``-token span per array.
+    Padded rows (mask 0) keep the pool's old bytes, read from the pool
+    INPUT block: aliasing makes input and output one HBM buffer, but
+    only an input block is copied into VMEM before the body runs — on
+    the chip the output block starts as whatever VMEM held (the first
+    v5e run wrote that garbage over every padded row and decode read
+    NaN out of it; the interpreter pre-fills outputs and hid it)."""
 
     def kernel(pages_ref, mask_ref, *refs):
-        spans = refs[:n]
-        outs = refs[2 * n:]
+        spans, pools, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
         m = mask_ref[0, :, 0] != 0                        # [bs]
-        for s_ref, o_ref in zip(spans, outs):
+        for s_ref, p_ref, o_ref in zip(spans, pools, outs):
             mv = m.reshape((1, 1, -1) + (1,) * (o_ref.ndim - 3))
-            o_ref[...] = jnp.where(mv, s_ref[...], o_ref[...])
+            o_ref[...] = jnp.where(mv, s_ref[...], p_ref[...])
 
     return kernel
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """Bytes a VMEM block occupies once its trailing two dims are
+    padded to the dtype's native tile (lanes to 128, sublanes to
+    8/16/32 for 4/2/1-byte elements)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // itemsize)
+    lead = math.prod(shape[:-2])
+    return (lead * -(-shape[-2] // sub) * sub
+            * -(-shape[-1] // 128) * 128 * itemsize)
+
+
+def span_write_vmem_bytes(blocks) -> int:
+    """Upper-bound VMEM residency of one (layer, page) span-write
+    program over ``blocks`` = [(block shape, dtype), ...]: per array a
+    span block, the aliased pool block in and out, each double-
+    buffered by the pipeline."""
+    return sum(6 * _tiled_bytes(shape, dtype) for shape, dtype in blocks)
 
 
 def paged_span_write(pool: Dict[str, jax.Array],
@@ -456,14 +392,15 @@ def paged_span_write(pool: Dict[str, jax.Array],
     the RMW equivalent of the decode scatter's mode="drop"). Returns
     the updated pool arrays.
 
-    Grid (pc,); each program's blocks are one page's span per array,
-    placed by indexing the output BlockSpec through the scalar-
-    prefetched page vector — the hand-scheduled form of the masked
-    contiguous-span writes XLA emits for the fallback path, with the
-    pool aliased in-place instead of round-tripping a pool-sized
-    copy. Every block keeps its trailing two dims tiling-legal: the
-    page axis sits third-from-last (``(L, Hkv, bs, Dh)`` value blocks,
-    ``(L, Hkv, bs, 1)`` scale blocks, ``(1, bs, 1)`` mask blocks)."""
+    Grid (L, pc); each program's blocks are one layer's one-page span
+    per array, placed by indexing the output BlockSpec through the
+    scalar-prefetched page vector — the hand-scheduled form of the
+    masked contiguous-span writes XLA emits on its own path, with the
+    pool aliased in-place instead of round-tripping a pool-sized copy.
+    Every block keeps its trailing two dims tiling-legal: the page
+    axis sits third-from-last (``(1, Hkv, bs, Dh)`` value blocks,
+    ``(1, Hkv, bs, 1)`` scale blocks, ``(1, bs, 1)`` mask blocks), and
+    the per-program VMEM no longer grows with the layer count."""
     names = sorted(spans)
     bs = int(block_size)
     pc = int(pages.shape[0])
@@ -478,29 +415,35 @@ def paged_span_write(pool: Dict[str, jax.Array],
 
     pools4 = {nm: view(pool[nm]) for nm in names}
     spans4 = {nm: view(spans[nm]) for nm in names}
+    L = pools4[names[0]].shape[0]
+
+    def block(a):
+        return (1, a.shape[1], bs) + a.shape[3:]
 
     def span_spec(a):
-        blk = a.shape[:2] + (bs,) + a.shape[3:]
         nd = a.ndim
 
-        def imap(j, pg, nd=nd):
-            return (0, 0, j) + (0,) * (nd - 3)
+        def imap(l, j, pg, nd=nd):
+            return (l, 0, j) + (0,) * (nd - 3)
 
-        return pl.BlockSpec(blk, imap)
+        return pl.BlockSpec(block(a), imap)
 
     def pool_spec(a):
-        blk = a.shape[:2] + (bs,) + a.shape[3:]
         nd = a.ndim
 
-        def imap(j, pg, nd=nd):
-            return (0, 0, pg[j]) + (0,) * (nd - 3)
+        def imap(l, j, pg, nd=nd):
+            return (l, 0, pg[j]) + (0,) * (nd - 3)
 
-        return pl.BlockSpec(blk, imap)
+        return pl.BlockSpec(block(a), imap)
 
+    params = _policy.compiled_kernel_params(
+        interpret, span_write_vmem_bytes(
+            [(block(pools4[nm]), pools4[nm].dtype) for nm in names]),
+        "paged_span_write")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(pc,),
-        in_specs=([pl.BlockSpec((1, bs, 1), lambda j, pg: (j, 0, 0))]
+        grid=(L, pc),
+        in_specs=([pl.BlockSpec((1, bs, 1), lambda l, j, pg: (j, 0, 0))]
                   + [span_spec(spans4[nm]) for nm in names]
                   + [pool_spec(pools4[nm]) for nm in names]),
         out_specs=[pool_spec(pools4[nm]) for nm in names],
@@ -517,6 +460,7 @@ def paged_span_write(pool: Dict[str, jax.Array],
         # prefetch operand, matching pallas_call's flat operand order
         input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
+        **params,
     )(pages.astype(jnp.int32), mask,
       *[spans4[nm] for nm in names], *[pools4[nm] for nm in names])
     return {nm: (o[..., 0] if nm in three_d else o)

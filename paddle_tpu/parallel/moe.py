@@ -165,7 +165,6 @@ def moe_ffn_a2a(params, x: jax.Array, cfg: MoEConfig, mesh: Mesh,
     quantize runs BEFORE the collective, inside the shard, so s8 is what
     crosses the wire (asserted in tests/test_moe_pipeline.py).
     """
-    from paddle_tpu.parallel.compat import shard_map
 
     ax = place.AXIS_EXPERT
     if ax not in mesh.axis_names:
@@ -210,7 +209,7 @@ def moe_ffn_a2a(params, x: jax.Array, cfg: MoEConfig, mesh: Mesh,
         aux = cfg.aux_loss_weight * E * jnp.sum(frac_g * mean_p_g)
         return out.astype(xs.dtype), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(ax, None, None), P(ax, None, None),
                   P(ax, None)),

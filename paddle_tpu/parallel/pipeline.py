@@ -132,7 +132,6 @@ def pipeline_apply_interleaved(stage_params, x: jax.Array,
     gradients; the input/output rings stay full precision so the
     pipeline's own data is untouched.
     """
-    from paddle_tpu.parallel.compat import shard_map
 
     S = mesh.shape[stage_axis]
     v = num_chunks
@@ -251,7 +250,7 @@ def pipeline_apply_interleaved(stage_params, x: jax.Array,
         return outs_local
 
     specs_mb = P(stage_axis)
-    outs = shard_map(run, mesh=mesh,
+    outs = jax.shard_map(run, mesh=mesh,
                      in_specs=(param_specs, specs_mb),
                      out_specs=specs_mb, check_vma=False)(stage_params, xs)
     return outs.reshape((B,) + x.shape[1:])

@@ -171,7 +171,6 @@ def ring_attention_spmd(q, k, v, mesh: Mesh, *, causal: bool = False,
     on the wire; the jnp engine's autodiff backward sends cotangents
     through the same int8 codec per hop (bounded by the grad tolerance
     test — prefer the flash engine for training at scale)."""
-    from paddle_tpu.parallel.compat import shard_map
 
     H, Hkv = q.shape[2], k.shape[2]
     tp = (head_axis if head_axis in mesh.axis_names
@@ -203,13 +202,13 @@ def ring_attention_spmd(q, k, v, mesh: Mesh, *, causal: bool = False,
         else:
             def wrapped(q_, k_, v_):
                 return fn(q_, k_, v_, lengths=None)
-        return shard_map(wrapped, mesh=mesh,
+        return jax.shard_map(wrapped, mesh=mesh,
                          in_specs=(qkv_spec,) * 3,
                          out_specs=qkv_spec, check_vma=False)(q, k, v)
 
     def wrapped(q_, k_, v_, len_):
         return fn(q_, k_, v_, lengths=len_)
-    return shard_map(wrapped, mesh=mesh,
+    return jax.shard_map(wrapped, mesh=mesh,
                      in_specs=(qkv_spec, qkv_spec, qkv_spec, len_spec),
                      out_specs=qkv_spec, check_vma=False)(q, k, v, lengths)
 
@@ -255,7 +254,6 @@ def alltoall_attention_spmd(q, k, v, mesh: Mesh, *, causal: bool = False,
     kernel (packed equal-length only); ragged ``lengths`` use the jnp
     engine.
     """
-    from paddle_tpu.parallel.compat import shard_map
 
     P_ = mesh.shape[seq_axis]
     H, Hkv = q.shape[2], k.shape[2]
@@ -298,11 +296,11 @@ def alltoall_attention_spmd(q, k, v, mesh: Mesh, *, causal: bool = False,
         return gather(out)
 
     if lengths is None:
-        return shard_map(
+        return jax.shard_map(
             lambda a, b, c: local(a, b, c, None), mesh=mesh,
             in_specs=(qkv_spec,) * 3, out_specs=qkv_spec,
             check_vma=False)(q, k, v)
-    return shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                      in_specs=(qkv_spec, qkv_spec, qkv_spec, len_spec),
                      out_specs=qkv_spec, check_vma=False)(q, k, v, lengths)
 
@@ -330,7 +328,8 @@ def ring_flash_attention(q, k, v, *, axis_name: str, causal: bool = False,
     k/v [B, T_local, Hkv, D] with H % Hkv == 0 (GQA: the ring rotates
     Hkv-head K/V and dk/dv; the H-head expansion is local per step).
     """
-    from paddle_tpu.ops.pallas.attention import select_block_sizes
+    from paddle_tpu.ops.pallas.attention import (planning_budget,
+                                                 select_block_sizes)
 
     Tl, D = q.shape[1], q.shape[3]
     scale = scale or (1.0 / math.sqrt(D))
@@ -339,7 +338,8 @@ def ring_flash_attention(q, k, v, *, axis_name: str, causal: bool = False,
     else:
         # block selection keyed on the LOCAL shard length (each ring step
         # runs the kernel on [Tl, D] tiles)
-        bq_auto, bk_auto = select_block_sizes(Tl, D, q.dtype)
+        bq_auto, bk_auto = select_block_sizes(
+            Tl, D, q.dtype, planning_budget(bool(interpret)))
         bq = min(block_q, Tl) if block_q else bq_auto
         bk = min(block_k, Tl) if block_k else bk_auto
     return _ring_flash(q, k, v, axis_name, causal, scale, bq, bk,

@@ -6,16 +6,20 @@ pserver+trainer processes with --trainer_id etc.) and submit_local.sh.in
 
 TPU-native: every process is identical (no pserver role); the launcher
 just sets the PADDLE_* env contract consumed by paddle_tpu.distributed.init
-and execs the worker. Local mode spawns N processes on this machine with
-the CPU platform and K virtual devices each — the no-cluster simulation of
-a K-chip x N-host pod used by the tests (SURVEY §4.6's in-process-pserver
-strategy, one level up).
+and execs the worker. Local mode with ``--devices-per-proc=K`` is the
+no-cluster SIMULATION of a K-chip x N-host pod used by the tests (SURVEY
+§4.6's in-process-pserver strategy, one level up): it — and only it —
+pins the workers to the CPU platform (``JAX_PLATFORMS=cpu``) with K
+virtual devices each. Without it the workers get whatever devices the
+machine gives them; nothing is forced.
 
 Usage:
   python -m paddle_tpu.runtime.launch --nprocs=2 --devices-per-proc=4 \
       worker.py [worker args...]
 On a real pod, run one process per host with PADDLE_COORDINATOR pointing
-at host 0 (or let TPU metadata auto-configure) instead.
+at host 0 (or let TPU metadata auto-configure) instead. One chip belongs
+to one process: local processes that must each own a chip are pinned
+with :func:`chip_pin_env` (the serving fleet does).
 """
 
 import argparse
@@ -34,15 +38,34 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
+def chip_pin_env(chip: int) -> dict:
+    """Environment that gives a child process exactly ONE local TPU
+    chip (index ``chip`` on this host) as a one-chip topology of its
+    own — the libtpu recipe for several independent processes on one
+    multi-chip host. Each needs its own mesh-controller port. Off-TPU
+    (``JAX_PLATFORMS=cpu``) libtpu never loads and these are inert, so
+    launchers set them unconditionally."""
+    chip = int(chip)
+    port = 8476 + chip
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_PORT": str(port)}
+
+
 def spawn_local_procs(nprocs: int, argv: Sequence[str],
-                      devices_per_proc: int = 1,
+                      devices_per_proc: Optional[int] = None,
                       coordinator_port: Optional[int] = None,
                       env_extra: Optional[dict] = None,
                       env_per_rank: Optional[Sequence[dict]] = None,
                       cluster: bool = True) -> List[subprocess.Popen]:
     """Spawn ``nprocs`` local worker processes WITHOUT waiting — the
     restartable-gang primitive the elastic supervisor re-forms on every
-    coordination epoch. ``cluster=False`` omits PADDLE_COORDINATOR so
+    coordination epoch. ``devices_per_proc=K`` makes it the CPU
+    SIMULATION (``JAX_PLATFORMS=cpu`` + K virtual devices per worker);
+    ``None`` forces no platform — a real gang keeps its real devices.
+    ``cluster=False`` omits PADDLE_COORDINATOR so
     workers run independent single-process JAX runtimes (the CPU
     simulation path where jaxlib lacks multi-process collectives —
     ``multiprocess_cpu_supported``); a fresh coordinator port per call
@@ -52,13 +75,14 @@ def spawn_local_procs(nprocs: int, argv: Sequence[str],
     procs = []
     for rank in range(nprocs):
         # update() chain, not dict(**kw): callers may legitimately
-        # override the contract keys (env_extra={"PADDLE_PLATFORM":
-        # ...}) and later layers must win, not TypeError
+        # override the contract keys and later layers must win, not
+        # TypeError
         env = dict(os.environ)
         env.update(PADDLE_NUM_PROCESSES=str(nprocs),
-                   PADDLE_PROCESS_ID=str(rank),
-                   PADDLE_PLATFORM="cpu",
-                   PADDLE_LOCAL_CPU_DEVICES=str(devices_per_proc))
+                   PADDLE_PROCESS_ID=str(rank))
+        if devices_per_proc is not None:
+            env.update(JAX_PLATFORMS="cpu",
+                       PADDLE_LOCAL_CPU_DEVICES=str(devices_per_proc))
         env.update(env_extra or {})
         env.update(env_per_rank[rank] if env_per_rank else {})
         if cluster:
@@ -97,7 +121,7 @@ def terminate_procs(procs: Sequence[subprocess.Popen],
 
 
 def launch_local(nprocs: int, argv: Sequence[str],
-                 devices_per_proc: int = 1,
+                 devices_per_proc: Optional[int] = None,
                  coordinator_port: Optional[int] = None,
                  env_extra: Optional[dict] = None,
                  timeout: float = 600.0) -> List[int]:
@@ -249,9 +273,8 @@ devs = jax.devices()
 assert len(devs) == 2, devs
 mesh = Mesh(np.asarray(devs), ("d",))
 x = jax.device_put(jnp.ones((2,), jnp.float32), NamedSharding(mesh, P("d")))
-from paddle_tpu.parallel.compat import shard_map
 import jax.lax as lax
-total = jax.jit(shard_map(lambda v: lax.psum(jnp.sum(v), "d"), mesh=mesh,
+total = jax.jit(jax.shard_map(lambda v: lax.psum(jnp.sum(v), "d"), mesh=mesh,
                           in_specs=P("d"), out_specs=P()))(x)
 assert float(total) == 2.0, float(total)
 """
@@ -296,7 +319,10 @@ def main(argv=None):
         description="multi-process launcher: local simulation or ssh "
         "fan-out across hosts (docs/howto_distributed.md)")
     ap.add_argument("--nprocs", type=int, default=2)
-    ap.add_argument("--devices-per-proc", type=int, default=1)
+    ap.add_argument("--devices-per-proc", type=int, default=None,
+                    help="CPU simulation: pin workers to JAX_PLATFORMS="
+                    "cpu with this many virtual devices each (default: "
+                    "the machine's real devices, nothing forced)")
     ap.add_argument("--timeout", type=float, default=600.0)
     ap.add_argument("--hosts", default=None,
                     help="comma-separated host list: ssh mode, one "

@@ -1013,9 +1013,21 @@ class ServingFleet:
     training gang supervisor, and the fleet glue the ``route`` CLI and
     the multi-process chaos tests stand on.
 
+    One process for each chip: a chip belongs to one process at a time,
+    so replica ``replica{k}`` is pinned to local chip ``k``
+    (``launch.chip_pin_env`` — a replacement spawned under the same
+    name inherits the chip its predecessor released) and the fleet's
+    own process never touches a JAX backend. Processes rather than
+    several engines in one process because the fleet's whole control
+    plane — SIGKILL chaos, wedge kills, heal-by-respawn, drain-on-TERM —
+    acts on processes, and a wedged chip then takes down one replica,
+    not four. A replica's stderr is the fleet's stderr: a replica that
+    cannot get its chip says why where the operator is looking.
+
     Each replica binds an ephemeral TCP port for the JSONL op wire and
-    an ephemeral HTTP health port, announcing both as one
-    machine-readable ``{"replica_ready": {...}}`` line on stdout;
+    an ephemeral HTTP health port, announcing both — with its device,
+    kernel paths and time-to-ready — as one machine-readable
+    ``{"replica_ready": {...}}`` line on stdout;
     :meth:`start` parses the announcements (with a deadline — a replica
     that dies during model load raises instead of hanging the fleet)
     and :meth:`handles` builds ``serving.replica.SocketReplica`` handles
@@ -1069,9 +1081,21 @@ class ServingFleet:
             self._by_name[f"replica{i}"] = p
         return self
 
+    @staticmethod
+    def chip_of(name: str) -> int:
+        """The local chip replica ``name`` owns: the index its name
+        ends in (``replica3`` -> chip 3)."""
+        digits = name[len(name.rstrip("0123456789")):]
+        if not digits:
+            raise ValueError(f"replica name {name!r} must end in its "
+                             f"index — the index is its chip")
+        return int(digits)
+
     def _launch(self, name: str):
         import subprocess
+        from paddle_tpu.runtime import launch
         env = dict(os.environ)
+        env.update(launch.chip_pin_env(self.chip_of(name)))
         if self.env:
             env.update(self.env)
         # the replicas run `python -m paddle_tpu`: make THIS package
@@ -1093,7 +1117,7 @@ class ServingFleet:
             [self.python, "-m", "paddle_tpu", "serve",
              f"--model={self.model}", "--port=0", "--health_port=0",
              *extra],
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            stdout=subprocess.PIPE, stderr=None,    # = ours
             text=True, env=env)
 
     def _await_ready(self, name: str, proc, deadline: float,
@@ -1126,7 +1150,7 @@ class ServingFleet:
                 f"({'exited rc=' + str(rc) if rc is not None else 'timed out'})")
         doc = json.loads(line)["replica_ready"]
         return {"name": name, "port": int(doc["port"]),
-                "health_port": doc.get("health_port")}
+                "health_port": doc.get("health_port"), "ready": doc}
 
     # -- named lifecycle (the fleet controller's surface) ------------------
     def allocate_name(self) -> str:

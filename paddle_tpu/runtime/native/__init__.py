@@ -1,35 +1,59 @@
 """ctypes loader for the native runtime library.
 
-Builds recordio.cc with g++ on first use (cached beside the source; no
-pybind11 in the image — C ABI + ctypes per the environment constraints),
-falling back to None so pure-Python paths keep working without a toolchain.
+Builds recordio.cc with g++ on first use (no pybind11 in the image — C
+ABI + ctypes per the environment constraints). The built library is
+named after a hash of the source AND the compiler line, so a binary left
+over from another source, another flag set or another checkout is never
+loaded: modification times prove nothing once a tree has been copied. A
+failed build is logged once, loudly, and ``get()`` returns None — the
+pure-Python codec then takes over and the caller keeps working.
 """
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
+
+from paddle_tpu.utils.logger import get_logger
+
+log = get_logger("runtime.native")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
-_SRC = os.path.join(os.path.dirname(__file__), "recordio.cc")
-_SO = os.path.join(os.path.dirname(__file__), "_librecordio.so")
+_DIR = os.path.dirname(__file__)
+_SRC = os.path.join(_DIR, "recordio.cc")
+_CXX = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread"]
+_LIBS = ["-lz"]
 
 
-def _build() -> bool:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return True
-    tmp = f"{_SO}.tmp.{os.getpid()}"       # per-process: concurrent builds
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", tmp, "-lz"]
+def _so_path() -> str:
+    h = hashlib.sha256(" ".join(_CXX + _LIBS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"_librecordio.{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str):
+    """Compile ``_SRC`` into ``so`` (atomic publish; concurrent builders
+    each write their own temporary). Raises on a failed build."""
+    tmp = f"{so}.tmp.{os.getpid()}"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        subprocess.run(_CXX + [_SRC, "-o", tmp] + _LIBS, check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "_librecordio*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)       # a binary of some other source
+            except OSError:
+                pass
 
 
 def _bind(lib):
@@ -60,15 +84,25 @@ def _bind(lib):
 
 
 def get():
-    """The loaded native library, or None when unavailable."""
+    """The loaded native library, or None when it cannot be built or
+    loaded here (logged once; the pure-Python codec is the caller's
+    other path)."""
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if _build():
-            try:
-                _lib = _bind(ctypes.CDLL(_SO))
-            except OSError:
-                _lib = None
+        so = _so_path()
+        try:
+            if not os.path.exists(so):
+                _build(so)
+            _lib = _bind(ctypes.CDLL(so))
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            log.error(
+                "NATIVE RECORDIO UNAVAILABLE — %s: %s %s; the "
+                "pure-Python codec takes over (same bytes, slower "
+                "reads and batch assembly)", type(e).__name__, e,
+                detail.decode(errors="replace")[-2000:])
+            _lib = None
         return _lib

@@ -363,7 +363,10 @@ class Supervisor:
     PADDLE_COORDINATOR (one jax.distributed runtime per epoch, fresh
     port each time), ``cluster=False`` runs independent single-process
     runtimes (the CPU-simulation path; see
-    ``launch.multiprocess_cpu_supported``). ``replacements`` is the
+    ``launch.multiprocess_cpu_supported``). ``devices_per_proc=K`` is
+    what makes the gang a CPU simulation (K virtual devices per
+    worker); left ``None`` nothing forces a platform and the workers
+    train on the machine's real devices. ``replacements`` is the
     spare-host budget: None = unlimited (a local respawn is free), an
     int = that many worker deaths can be replaced before the gang
     starts shrinking instead (graceful degradation), optionally snapped
@@ -384,7 +387,7 @@ class Supervisor:
     """
 
     def __init__(self, argv: Sequence[str], nprocs: int, state_dir: str, *,
-                 devices_per_proc: int = 1,
+                 devices_per_proc: Optional[int] = None,
                  cluster: bool = False,
                  hosts: Optional[Sequence[str]] = None,
                  replacement_hosts: Sequence[str] = (),

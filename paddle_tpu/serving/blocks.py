@@ -31,6 +31,14 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+# The engines' default KV block: the smallest size the compiled decode
+# and chunk-prefill kernels accept (their score-scratch stores need a
+# 128-aligned lane offset — ops/pallas/decode.check_compiled_block_size;
+# block 16/32 is refused by the v5e compiler). The default prefill chunk
+# is one block: chunks must be whole blocks.
+DEFAULT_BLOCK_SIZE = 128
+DEFAULT_CHUNK_TOKENS = 128
+
 # chain root: the hash of "no prefix" (any constant salt works; a named
 # one keeps digests stable across processes for debugging)
 ROOT_HASH = b"paddle-tpu-paged-kv-root"

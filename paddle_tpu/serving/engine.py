@@ -60,6 +60,7 @@ from paddle_tpu.observe import costs as _costs
 from paddle_tpu.observe import metrics as _metrics
 from paddle_tpu.observe import requests as _requests
 from paddle_tpu.observe.window import SloConfig, WindowedQuantiles
+from paddle_tpu.serving import blocks as _blocks
 
 # per-process engine instance counter: bakes into request trace ids
 # (``eng<N>.r<rid>``) so several engines' lifecycle events never
@@ -197,7 +198,8 @@ class DecodeEngine:
                  tracker: Optional[_ct.CompileTracker] = None,
                  slo: Optional[SloConfig] = None,
                  decode_flops: Optional[float] = None,
-                 pallas_mode: Optional[str] = None):
+                 pallas_mode: Optional[str] = None,
+                 kernel_paths: Optional[Dict[str, Dict[str, str]]] = None):
         import jax.numpy as jnp
         self._jnp = jnp
         self._prefill_fn = prefill
@@ -211,9 +213,16 @@ class DecodeEngine:
         # artifact's cost stamp) against the declared chip peak
         self.decode_flops = decode_flops
         self._peak_flops = _costs.device_peak_flops()
-        # which attention/sampling path the decode program compiled
-        # (resolved PADDLE_TPU_PALLAS policy; None = unknown/legacy)
+        # the resolved PADDLE_TPU_PALLAS policy the programs were built
+        # under (None = unknown/legacy artifact)
         self.pallas_mode = pallas_mode
+        # per compiled program, the path each kernel site ACTUALLY
+        # placed ("pallas" | "pallas_interpret" | "xla"): recorded by
+        # the step functions as they trace (``sampling._recorded``) or
+        # stamped into the artifact at export. A live dict — in-process
+        # programs appear as they first trace.
+        self.kernel_paths = kernel_paths if kernel_paths is not None \
+            else {}
         self.buckets = tuple(sorted({int(b) for b in buckets
                                      if int(b) <= cache_len}))
         if not self.buckets:
@@ -326,7 +335,7 @@ class DecodeEngine:
         return cls(jax.jit(prefill_fn), jdf, params, cache,
                    batch=batch, cache_len=cache_len, buckets=buckets,
                    seed=seed, pallas_mode=_pallas_policy.pallas_mode(pallas),
-                   **kw)
+                   kernel_paths=decode_fn.kernel_paths, **kw)
 
     # -- request-scoped observability --------------------------------------
     def configure_slo(self, slo: Optional[SloConfig]):
@@ -688,16 +697,9 @@ class DecodeEngine:
         finished: List[EngineRequest] = []
         self._schedule(finished)
         if self._active.any():
-            jnp = self._jnp
             self._pre_decode()
             t0 = time.perf_counter()
-            nxt, self.cache = self._tracker.track_call(
-                "serving_engine.decode", self._decode_fn,
-                self.params, self.cache, jnp.asarray(self._last),
-                jnp.asarray(self._pos), jnp.asarray(self._active),
-                *self._decode_extra(),
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                self._seed())
+            nxt = self._dispatch_decode(self._seed())
             nxt = np.asarray(nxt)       # the only device->host transfer:
             now = time.perf_counter()   # [B] int32 ids
             self._m_step_s.observe(now - t0)
@@ -717,6 +719,48 @@ class DecodeEngine:
                     finished.append(req)
         self._update_gauges()
         return finished
+
+    def _dispatch_decode(self, seed):
+        """One batched decode program over the current slot state;
+        returns the sampled ids (still on device)."""
+        jnp = self._jnp
+        nxt, self.cache = self._tracker.track_call(
+            "serving_engine.decode", self._decode_fn,
+            self.params, self.cache, jnp.asarray(self._last),
+            jnp.asarray(self._pos), jnp.asarray(self._active),
+            *self._decode_extra(),
+            jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
+        return nxt
+
+    def precompile(self) -> Dict[str, int]:
+        """Run every program this engine can dispatch once, on inputs
+        that change nothing a request will read (no active row; a
+        prompt the first real prefill overwrites), so each is compiled
+        — and has executed on the device — before the engine reports
+        ready. A kernel the compiler refuses raises HERE, with the
+        compiler's message, instead of in the middle of traffic; later
+        dispatches hit the jit cache. Only valid on an idle engine.
+        Returns :meth:`compile_counts`."""
+        if not self.idle:
+            raise RuntimeError("precompile() needs an idle engine")
+        self._precompile_prefill()
+        self._precompile_decode()     # ends in a host read: all done
+        return self.compile_counts()
+
+    def _precompile_prefill(self):
+        jnp = self._jnp
+        for b in self.buckets:
+            # lands in free slot 0's row; the slot's next real prefill
+            # rewrites every position a mask could expose
+            _, self.cache = self._tracker.track_call(
+                "serving_engine.prefill", self._prefill_fn,
+                self.params, self.cache, jnp.zeros((1, b), jnp.int32),
+                np.int32(1), np.int32(0), np.float32(0.0), np.int32(0),
+                np.int32(0))
+
+    def _precompile_decode(self):
+        # no active row: every cache write of the step is dropped
+        np.asarray(self._dispatch_decode(np.int32(0)))
 
     def run_until_idle(self, max_steps: int = 100_000
                        ) -> List[EngineRequest]:
@@ -757,6 +801,7 @@ class DecodeEngine:
                "slots_total": self.batch,
                "cache_len": self.cache_len,
                "pallas": self.pallas_mode,
+               "kernel_paths": self.kernel_paths,
                "prefill_buckets": list(self.buckets)}
         mfu = self.decode_mfu()
         if mfu is not None:
@@ -891,7 +936,8 @@ class PagedDecodeEngine(DecodeEngine):
 
     def __init__(self, prefill: Callable, decode: Callable, params,
                  cache, *, batch: int, cache_len: int, block_size: int,
-                 num_blocks: Optional[int] = None, chunk_tokens: int = 64,
+                 num_blocks: Optional[int] = None,
+                 chunk_tokens: int = _blocks.DEFAULT_CHUNK_TOKENS,
                  chunk_buckets: Optional[Sequence[int]] = None,
                  seed: Optional[int] = None,
                  registry: Optional[_metrics.Registry] = None,
@@ -899,10 +945,10 @@ class PagedDecodeEngine(DecodeEngine):
                  slo: Optional[SloConfig] = None,
                  decode_flops: Optional[float] = None,
                  pallas_mode: Optional[str] = None,
+                 kernel_paths: Optional[Dict[str, Dict[str, str]]] = None,
                  kv_dtype: Optional[str] = None,
                  tenant_budgets: Optional[Dict[str, int]] = None,
                  tiers=None):
-        from paddle_tpu.serving import blocks as _blocks
         bs = int(block_size)
         if bs < 1 or cache_len % bs:
             raise ValueError(f"cache_len {cache_len} must be a positive "
@@ -936,7 +982,8 @@ class PagedDecodeEngine(DecodeEngine):
                          cache_len=cache_len, buckets=chunk_buckets,
                          seed=seed, registry=registry, tracker=tracker,
                          slo=slo, decode_flops=decode_flops,
-                         pallas_mode=pallas_mode)
+                         pallas_mode=pallas_mode,
+                         kernel_paths=kernel_paths)
         self.block_size = bs
         self.pages_per_slot = cache_len // bs
         self.num_blocks = int(num_blocks if num_blocks is not None
@@ -1057,9 +1104,9 @@ class PagedDecodeEngine(DecodeEngine):
     # -- construction ------------------------------------------------------
     @classmethod
     def from_params(cls, params, cfg, *, batch: int, cache_len: int,
-                    block_size: int = 16,
+                    block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
                     num_blocks: Optional[int] = None,
-                    chunk_tokens: int = 64,
+                    chunk_tokens: int = _blocks.DEFAULT_CHUNK_TOKENS,
                     chunk_buckets: Optional[Sequence[int]] = None,
                     seed: Optional[int] = None,
                     pallas: Optional[str] = None,
@@ -1102,7 +1149,8 @@ class PagedDecodeEngine(DecodeEngine):
                    block_size=block_size, num_blocks=nb,
                    chunk_tokens=chunk_tokens, chunk_buckets=chunk_buckets,
                    seed=seed, kv_dtype=kv_dtype,
-                   pallas_mode=_pallas_policy.pallas_mode(pallas), **kw)
+                   pallas_mode=_pallas_policy.pallas_mode(pallas),
+                   kernel_paths=decode_fn.kernel_paths, **kw)
 
     # -- request API -------------------------------------------------------
     def set_tenant_budget(self, tenant: str, tokens: Optional[int]):
@@ -1860,9 +1908,35 @@ class PagedDecodeEngine(DecodeEngine):
                  hit_blocks=len(blocks), tokens=K)
         return True
 
+    def _dispatch_chunk(self, slot: int, padded, c: int, npages: int,
+                        temperature, top_k, seed):
+        """One chunk-prefill program over ``slot``'s first ``npages``
+        pages; returns the sampled first token (still on device)."""
+        jnp = self._jnp
+        tok, self.cache = self._tracker.track_call(
+            "serving_engine.prefill", self._prefill_fn,
+            self.params, self.cache, jnp.asarray(padded),
+            np.int32(c), jnp.asarray(self._pages[slot, :npages]),
+            np.float32(temperature), np.int32(top_k), seed)
+        # the spec engine's draft model prefills the SAME chunk into
+        # its own pool here (same page vector — one block table maps
+        # both pools, so hits/preemption/eviction stay in lockstep)
+        self._draft_chunk_hook(slot, padded, c, npages)
+        return tok
+
+    def _precompile_prefill(self):
+        # every (chunk bucket, context span) program of the chunk grid,
+        # each on a zero-length chunk: its span write is fully masked,
+        # so the pool keeps its bytes
+        bs = self.block_size
+        for ctx in range(0, self.cache_len, self.chunk_tokens):
+            for b in self.buckets:
+                np.asarray(self._dispatch_chunk(
+                    0, np.zeros((1, b), np.int32), 0,
+                    ctx // bs + -(-b // bs), 0.0, 0, np.int32(0)))
+
     def _prefill_chunk(self, finished: List[EngineRequest]):
         from paddle_tpu.core import ragged
-        jnp = self._jnp
         slot = self._prefilling.popleft()
         req = self._slot_req[slot]
         while self._try_adopt(slot):
@@ -1883,17 +1957,9 @@ class PagedDecodeEngine(DecodeEngine):
         npages = off // self.block_size + -(-bucket // self.block_size)
         stalled = bool(self._active.any())
         t0 = time.perf_counter()
-        tok, self.cache = self._tracker.track_call(
-            "serving_engine.prefill", self._prefill_fn,
-            self.params, self.cache, jnp.asarray(padded),
-            np.int32(c), jnp.asarray(self._pages[slot, :npages]),
-            np.float32(req.temperature), np.int32(req.top_k),
-            self._seed())
-        tok = int(np.asarray(tok))
-        # the spec engine's draft model prefills the SAME chunk into
-        # its own pool here (same page vector — one block table maps
-        # both pools, so hits/preemption/eviction stay in lockstep)
-        self._draft_chunk_hook(slot, padded, c, npages)
+        tok = int(np.asarray(self._dispatch_chunk(
+            slot, padded, c, npages, req.temperature, req.top_k,
+            self._seed())))
         now = time.perf_counter()
         # accumulate per-chunk device time; the histogram observes one
         # per-request total at the final chunk so its semantics match
@@ -2113,7 +2179,8 @@ class SpecDecodeEngine(PagedDecodeEngine):
             # paged chunk-grid set (target + draft prefill programs)
             # plus propose/verify/draft_verify — keep the default
             # tracker's storm threshold above that
-            chunk = min(int(kw.get("chunk_tokens", 64)),
+            chunk = min(int(kw.get("chunk_tokens",
+                                   _blocks.DEFAULT_CHUNK_TOKENS)),
                         int(kw["cache_len"]))
             spans = max(1, int(kw["cache_len"]) // max(chunk, 1))
             cb = kw.get("chunk_buckets")
@@ -2150,9 +2217,9 @@ class SpecDecodeEngine(PagedDecodeEngine):
     @classmethod
     def from_params(cls, params, cfg, draft_params, draft_cfg, *,
                     spec_k: int = 4, batch: int, cache_len: int,
-                    block_size: int = 16,
+                    block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
                     num_blocks: Optional[int] = None,
-                    chunk_tokens: int = 64,
+                    chunk_tokens: int = _blocks.DEFAULT_CHUNK_TOKENS,
                     chunk_buckets: Optional[Sequence[int]] = None,
                     seed: Optional[int] = None,
                     pallas: Optional[str] = None,
@@ -2179,7 +2246,8 @@ class SpecDecodeEngine(PagedDecodeEngine):
         prefill_fn, decode_fn = sampling.paged_step_fns(
             cfg, block_size, pallas=pallas)
         spec = sampling.paged_spec_fns(cfg, draft_cfg, block_size,
-                                       spec_k, pallas=pallas)
+                                       spec_k, pallas=pallas,
+                                       paths=decode_fn.kernel_paths)
         pool = transformer.init_block_pool(cfg, nb, block_size,
                                            kv_dtype=kv_dtype)
         draft_pool = transformer.init_block_pool(draft_cfg, nb,
@@ -2208,7 +2276,8 @@ class SpecDecodeEngine(PagedDecodeEngine):
                    chunk_tokens=chunk_tokens,
                    chunk_buckets=chunk_buckets, seed=seed,
                    kv_dtype=kv_dtype,
-                   pallas_mode=_pallas_policy.pallas_mode(pallas), **kw)
+                   pallas_mode=_pallas_policy.pallas_mode(pallas),
+                   kernel_paths=decode_fn.kernel_paths, **kw)
 
     # -- scheduler ---------------------------------------------------------
     def _draft_chunk_hook(self, slot: int, padded, c: int, npages: int):
@@ -2226,6 +2295,31 @@ class SpecDecodeEngine(PagedDecodeEngine):
             end = int(self._pos[slot]) + int(self._valid[slot]) - 1
             while end // self.block_size >= self._nalloc[slot]:
                 self._alloc_page(slot)
+
+    def _precompile_decode(self):
+        # the spec round's three programs (this engine never
+        # dispatches the plain decode), none with an active row: every
+        # write drops
+        jnp = self._jnp
+        B, W = self.batch, self.spec_k + 1
+        none = jnp.zeros(B, bool)
+        pos, valid = jnp.asarray(self._pos), jnp.ones(B, jnp.int32)
+        pages_dev = self._decode_extra()[0]
+        win_dev = jnp.zeros((B, W), jnp.int32)
+        _, self.draft_cache = self._tracker.track_call(
+            "serving_engine.propose", self._propose_fn,
+            self.draft_params, self.draft_cache,
+            jnp.asarray(self._last), pos, none, valid, pages_dev)
+        self.draft_cache = self._tracker.track_call(
+            "serving_engine.draft_verify", self._draft_verify_fn,
+            self.draft_params, self.draft_cache, win_dev, pos, valid,
+            none, pages_dev)
+        X, _, self.cache = self._tracker.track_call(
+            "serving_engine.verify", self._verify_fn,
+            self.params, self.cache, win_dev, pos, valid, none,
+            pages_dev, jnp.asarray(self._temp),
+            jnp.asarray(self._topk), np.int32(0))
+        np.asarray(X)
 
     def step(self) -> List[EngineRequest]:
         """One scheduler iteration: admission + chunk prefill as the

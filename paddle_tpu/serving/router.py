@@ -99,8 +99,10 @@ class AdmissionError(RuntimeError):
         self.reason = reason
 
 
-def fleet_keying(handles, default_block_size: int = 16,
-                 default_chunk_tokens: int = 64) -> Tuple[int, int]:
+def fleet_keying(handles,
+                 default_block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
+                 default_chunk_tokens: int = _blocks.DEFAULT_CHUNK_TOKENS
+                 ) -> Tuple[int, int]:
     """Placement keying (block size / chunk grid) read off the first
     replica ``/healthz`` that reports it — the one way the router's
     digest notion is derived from the engines' own prefix caches
@@ -287,8 +289,10 @@ class Router:
     they derive the placement digests and the transferable-prefix cap
     exactly as engine admission does."""
 
-    def __init__(self, replicas: Sequence, *, block_size: int = 16,
-                 chunk_tokens: int = 64, prefill: Sequence[str] = (),
+    def __init__(self, replicas: Sequence, *,
+                 block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
+                 chunk_tokens: int = _blocks.DEFAULT_CHUNK_TOKENS,
+                 prefill: Sequence[str] = (),
                  max_in_flight: int = 8, health_poll_s: float = 0.25,
                  hot_digests: int = 4096,
                  registry: Optional[_metrics.Registry] = None,

@@ -121,30 +121,42 @@ def _decode_live(dequant):
 
 
 def _epilogue(mode):
-    """The sampling tail of a decode program under the resolved
-    ``PADDLE_TPU_PALLAS`` mode: the Pallas ``fused_sample`` kernel
-    (greedy/top-k set exact, categorical matching in distribution) when
-    the kernels are dispatchable on this backend
-    (``decode.kernels_dispatchable`` — "on" off-TPU falls back to
-    ``sample_tokens`` with a once-per-mode warning) AND, for ``on``,
-    when the cached Mosaic lowering probe
-    (``decode.sample_lowering_ok``) accepts the logits shape;
-    ``sample_tokens`` otherwise."""
+    """The sampling tail of a step program under the resolved
+    ``PADDLE_TPU_PALLAS`` mode: ``sample_tokens`` for ``off``, the
+    Pallas ``fused_sample`` kernel otherwise (greedy/top-k set exact,
+    categorical matching in distribution). Nothing falls back: a
+    backend that cannot compile the kernel fails the compile."""
     from paddle_tpu.ops.pallas import decode as _pallas_decode
-    if not _pallas_decode.kernels_dispatchable(mode):
-        def tail(logits, seed, temperature, top_k):
+    from paddle_tpu.ops.pallas import policy as _pallas_policy
+    path = _pallas_policy.kernel_path(mode)
+
+    def tail(logits, seed, temperature, top_k):
+        _pallas_policy.note_path("sampler", path)
+        if mode == "off":
             key = jax.random.PRNGKey(seed)
             return sample_tokens(logits, key, temperature, top_k)
-    else:
-        def tail(logits, seed, temperature, top_k):
-            if mode == "on" and not _pallas_decode.sample_lowering_ok(
-                    logits.shape[0], logits.shape[1]):
-                key = jax.random.PRNGKey(seed)
-                return sample_tokens(logits, key, temperature, top_k)
-            return _pallas_decode.fused_sample(
-                logits, seed, temperature, top_k,
-                interpret=(mode == "interpret"))
+        return _pallas_decode.fused_sample(
+            logits, seed, temperature, top_k,
+            interpret=(mode == "interpret"))
+
     return tail
+
+
+def _recorded(fn, paths, name):
+    """``fn`` with every kernel site it traces recorded into
+    ``paths[name(*args)]`` — the per-compiled-program record of what
+    was actually placed (``policy.record_paths``). ``name`` maps the
+    call's arguments to the program's name, so shape-specialized
+    programs (one per chunk bucket and context span) each get their
+    own entry."""
+    from paddle_tpu.ops.pallas import policy as _pallas_policy
+
+    def wrapped(*args):
+        with _pallas_policy.record_paths(
+                paths.setdefault(name(*args), {})):
+            return fn(*args)
+
+    return wrapped
 
 
 def engine_step_fns(cfg, dequant=None, pallas=None):
@@ -184,9 +196,8 @@ def engine_step_fns(cfg, dequant=None, pallas=None):
                    top_k, seed):
         logits, cache = transformer.prefill_into_slot(
             _live(params), cache, tokens, length, slot, cfg)
-        key = jax.random.PRNGKey(seed)
-        tok = sample_tokens(logits, key, jnp.reshape(temperature, (1,)),
-                            jnp.reshape(top_k, (1,)))
+        tok = tail(logits, seed, jnp.reshape(temperature, (1,)),
+                   jnp.reshape(top_k, (1,)))
         return tok[0], cache
 
     def decode_fn(params, cache, tokens, pos, active, temperature,
@@ -195,6 +206,11 @@ def engine_step_fns(cfg, dequant=None, pallas=None):
             _live_d(params), cache, tokens, pos, active, cfg)
         return tail(logits, seed, temperature, top_k), cache
 
+    paths = {}
+    prefill_fn = _recorded(
+        prefill_fn, paths, lambda p, c, tokens, *_: f"prefill_{tokens.shape[1]}")
+    decode_fn = _recorded(decode_fn, paths, lambda *_: "decode")
+    prefill_fn.kernel_paths = decode_fn.kernel_paths = paths
     return prefill_fn, decode_fn
 
 
@@ -217,11 +233,14 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
     exported signature uniform.
 
     ``pallas`` resolves the ``PADDLE_TPU_PALLAS`` policy (explicit arg
-    > env > auto): when on, the decode step's attention runs the
-    flash-decode kernel over the pool, the chunk prefill runs the
-    ``ops/pallas/prefill.py`` pair (chunk attention off the pool +
-    span-write kernel), and the sampling tail the fused epilogue; the
-    pure-XLA path stays the always-available fallback. ``dequant``
+    > env > auto): unless it resolves ``off`` (the pure-XLA path), the
+    decode step's attention runs the flash-decode kernel over the pool,
+    the chunk prefill runs the ``ops/pallas/prefill.py`` pair (chunk
+    attention off the pool + span-write kernel), and both sampling
+    tails the fused epilogue — placed or raising, never degrading. Both
+    closures carry ``.kernel_paths``: per compiled program (``decode``,
+    ``prefill_<C>_<P>``), the path each kernel site placed, filled in
+    as the program is traced. ``dequant``
     applies to PREFILL only — decode consumes {"q8","scale"} trees
     natively (in-scan dequant, 1-byte weight reads per token). The
     pool may be QUANTIZED (``init_block_pool(kv_dtype=...)``): both
@@ -241,9 +260,8 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
         logits, pool = transformer.prefill_into_blocks(
             _live(params), pool, tokens, length, pages, cfg,
             block_size=block_size, pallas=mode)
-        key = jax.random.PRNGKey(seed)
-        tok = sample_tokens(logits, key, jnp.reshape(temperature, (1,)),
-                            jnp.reshape(top_k, (1,)))
+        tok = tail(logits, seed, jnp.reshape(temperature, (1,)),
+                   jnp.reshape(top_k, (1,)))
         return tok[0], pool
 
     def decode_fn(params, pool, tokens, pos, active, pages, temperature,
@@ -253,39 +271,42 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
             block_size=block_size, pallas=mode)
         return tail(logits, seed, temperature, top_k), pool
 
+    paths = {}
+    prefill_fn = _recorded(
+        prefill_fn, paths, lambda p, c, tokens, length, pages, *_:
+        f"prefill_{tokens.shape[1]}_{pages.shape[0]}")
+    decode_fn = _recorded(decode_fn, paths, lambda *_: "decode")
+    prefill_fn.kernel_paths = decode_fn.kernel_paths = paths
     return prefill_fn, decode_fn
 
 
 def _spec_epilogue(mode):
     """The accept/reject sampling tail of a verify program under the
-    resolved ``PADDLE_TPU_PALLAS`` mode: the Pallas ``fused_sample``
-    kernel per window row + the accept fold
-    (``ops.pallas.decode.fused_spec_verify``) when the kernels are
-    dispatchable, :func:`spec_verify_tokens` otherwise. Both emit the
-    same greedy tokens exactly (the PR-9 fused_sample contract), so the
-    spec engine's bitwise-greedy promise holds on either path."""
+    resolved ``PADDLE_TPU_PALLAS`` mode: :func:`spec_verify_tokens` for
+    ``off``, the Pallas ``fused_sample`` kernel per window row + the
+    accept fold (``ops.pallas.decode.fused_spec_verify``) otherwise.
+    Both emit the same greedy tokens exactly (the PR-9 fused_sample
+    contract), so the spec engine's bitwise-greedy promise holds on
+    either path."""
     from paddle_tpu.ops.pallas import decode as _pallas_decode
-    if not _pallas_decode.kernels_dispatchable(mode):
-        def tail(logits, draft, seed, temperature, top_k, valid):
+    from paddle_tpu.ops.pallas import policy as _pallas_policy
+    path = _pallas_policy.kernel_path(mode)
+
+    def tail(logits, draft, seed, temperature, top_k, valid):
+        _pallas_policy.note_path("sampler", path)
+        if mode == "off":
             key = jax.random.PRNGKey(seed)
             return spec_verify_tokens(logits, draft, key, temperature,
                                       top_k, valid)
-    else:
-        def tail(logits, draft, seed, temperature, top_k, valid):
-            B, W, V = logits.shape
-            if mode == "on" and not _pallas_decode.sample_lowering_ok(
-                    B * W, V):
-                key = jax.random.PRNGKey(seed)
-                return spec_verify_tokens(logits, draft, key,
-                                          temperature, top_k, valid)
-            return _pallas_decode.fused_spec_verify(
-                logits, draft, seed, temperature, top_k, valid,
-                interpret=(mode == "interpret"))
+        return _pallas_decode.fused_spec_verify(
+            logits, draft, seed, temperature, top_k, valid,
+            interpret=(mode == "interpret"))
+
     return tail
 
 
 def paged_spec_fns(cfg, draft_cfg, block_size: int, spec_k: int,
-                   dequant=None, pallas=None):
+                   dequant=None, pallas=None, paths=None):
     """The speculative-decoding program set for the paged spec engine —
     the three DRAFT-side programs plus the target VERIFY, compiled next
     to (never instead of) the ``paged_step_fns`` pair. ``spec_k`` fixes
@@ -327,7 +348,10 @@ def paged_spec_fns(cfg, draft_cfg, block_size: int, spec_k: int,
     ``dequant``/``pallas`` follow ``paged_step_fns`` semantics and
     apply to the TARGET side; the draft runs its params as given (pass
     a quantized draft tree for int8 draft weights — decode-side
-    consumption is native)."""
+    consumption is native). ``paths`` is the kernel-path record to fill
+    (pass the target pair's ``kernel_paths`` so one dict covers the
+    engine's whole program set); each closure carries it as
+    ``.kernel_paths``."""
     from paddle_tpu.models import transformer
     from paddle_tpu.ops.pallas import policy as _pallas_policy
 
@@ -379,6 +403,14 @@ def paged_spec_fns(cfg, draft_cfg, block_size: int, spec_k: int,
             block_size=block_size, pallas=mode)
         return draft_pool
 
-    return {"propose": propose_fn, "verify": verify_fn,
-            "draft_verify": draft_verify_fn,
-            "draft_prefill": draft_prefill_fn}
+    paths = {} if paths is None else paths
+    fns = {"propose": propose_fn, "verify": verify_fn,
+           "draft_verify": draft_verify_fn}
+    fns = {n: _recorded(f, paths, lambda *_, n=n: n)
+           for n, f in fns.items()}
+    fns["draft_prefill"] = _recorded(
+        draft_prefill_fn, paths, lambda p, c, tokens, length, pages:
+        f"draft_prefill_{tokens.shape[1]}_{pages.shape[0]}")
+    for f in fns.values():
+        f.kernel_paths = paths
+    return fns

@@ -79,19 +79,6 @@ class FlagRegistry:
         return {n: (self._values[n], s.help) for n, s in self._specs.items()}
 
 
-def set_xla_host_device_count(n: int) -> None:
-    """Force ``--xla_force_host_platform_device_count=n`` into XLA_FLAGS,
-    replacing any existing setting of that flag (token-level — a naive
-    substring check would treat '...count=80' as already containing
-    '...count=8' and silently skip). Must run before the CPU backend
-    initialises; newer JAX also accepts jax_num_cpu_devices at runtime."""
-    prefix = "--xla_force_host_platform_device_count="
-    toks = [t for t in os.environ.get("XLA_FLAGS", "").split()
-            if not t.startswith(prefix)]
-    toks.append(f"{prefix}{int(n)}")
-    os.environ["XLA_FLAGS"] = " ".join(toks)
-
-
 GLOBAL_FLAGS = FlagRegistry()
 
 # Mirrors of the reference's core flags (paddle/utils/Flags.cpp) that still

@@ -58,14 +58,8 @@ def step_memory(fn: Callable, *args, static_argnums=()) -> Dict[str, int]:
     compiled = jax.jit(fn, static_argnums=static_argnums).lower(
         *args).compile()
     ma = compiled.memory_analysis()
-    # older jaxlib lacks peak_memory_in_bytes; args+outputs+temps is the
-    # upper bound the budgeting decisions need (aliasing makes it safe)
-    peak = getattr(ma, "peak_memory_in_bytes", None)
-    if peak is None:
-        peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-                + ma.temp_size_in_bytes)
     return {
-        "peak": int(peak),
+        "peak": int(ma.peak_memory_in_bytes),
         "arguments": int(ma.argument_size_in_bytes),
         "outputs": int(ma.output_size_in_bytes),
         "temps": int(ma.temp_size_in_bytes),

@@ -250,6 +250,14 @@ class TestCliServe:
             cwd=os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__))))
         try:
+            # the first stdout line announces readiness: the device,
+            # and the kernel path every compiled program placed
+            ready = json.loads(p.stdout.readline())["replica_ready"]
+            assert ready["device"]["platform"] == "cpu"
+            assert ready["pallas"] == "off"
+            assert ready["kernel_paths"]["decode"] == {
+                "attention": "xla", "sampler": "xla"}
+            assert ready["compile_cache"]["dir"]
             p.stdin.write(json.dumps(
                 {"prompt": [1, 2, 3], "max_new": 4}) + "\n")
             p.stdin.flush()
@@ -322,6 +330,7 @@ class TestCliServe:
                 time.sleep(0.05)
             assert doc.get("requests", 0) >= 1, doc
             p.send_signal(signal.SIGTERM)
+            assert "replica_ready" in json.loads(p.stdout.readline())
             out = json.loads(p.stdout.readline())
             assert p.wait(timeout=120) == 0
             assert out["finish_reason"] == "max_tokens"

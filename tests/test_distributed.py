@@ -49,7 +49,6 @@ WORKER = textwrap.dedent("""
     assert dict(mesh.shape) == {{"dcn": 2, "data": 4}}, mesh.shape
 
     # a cross-host psum over both axes: every device contributes 1
-    from paddle_tpu.parallel.compat import shard_map
     ones = jnp.ones((8,), jnp.float32)
     sharded = jax.device_put(
         ones, NamedSharding(mesh, P(("dcn", "data"))))
@@ -57,7 +56,7 @@ WORKER = textwrap.dedent("""
     def f(x):
         return jax.lax.psum(jnp.sum(x), ("dcn", "data"))
 
-    total = jax.jit(shard_map(f, mesh=mesh,
+    total = jax.jit(jax.shard_map(f, mesh=mesh,
                               in_specs=P(("dcn", "data")), out_specs=P()
                               ))(sharded)
     # the psum result is replicated; every process sees 8.0

@@ -728,3 +728,110 @@ class TestRouterEngines:
         assert hits >= 2 * len(prompts)
         assert dc.eng.metrics.get(
             "engine_kv_blocks_imported_total").value() >= 2
+
+
+class TestOneProcessPerChip:
+    """A chip belongs to one process: the fleet pins replica k to local
+    chip k, keeps its own process off every JAX backend, and lets a
+    replica's stderr reach the operator."""
+
+    def test_launch_pins_distinct_chips_and_inherits_stderr(
+            self, monkeypatch, tmp_path):
+        import subprocess
+
+        from paddle_tpu.runtime.master import ServingFleet
+        seen = []
+
+        def fake_popen(argv, **kw):
+            seen.append((argv, kw))
+            return object()
+
+        monkeypatch.setattr(subprocess, "Popen", fake_popen)
+        fleet = ServingFleet(str(tmp_path / "lm.tar"), replicas=4,
+                             env={"JAX_PLATFORMS": "cpu"})
+        for i in range(4):
+            fleet._launch(f"replica{i}")
+        pins = [kw["env"]["TPU_VISIBLE_CHIPS"] for _, kw in seen]
+        ports = [kw["env"]["TPU_MESH_CONTROLLER_PORT"] for _, kw in seen]
+        assert pins == ["0", "1", "2", "3"]
+        assert len(set(ports)) == 4
+        for _, kw in seen:
+            assert kw["env"]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert kw["env"]["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert kw["env"]["JAX_PLATFORMS"] == "cpu"   # env= wins last
+            assert kw["stderr"] is None                  # = the fleet's
+            assert kw["stdout"] is subprocess.PIPE
+        # a healed replica keeps its predecessor's chip; any name that
+        # ends in its index names its chip; one that does not is refused
+        assert ServingFleet.chip_of("replica2") == 2
+        assert ServingFleet.chip_of("r11") == 11
+        with pytest.raises(ValueError, match="index"):
+            ServingFleet.chip_of("spare")
+
+    def test_ready_line_rides_the_endpoint(self, tmp_path):
+        """What a replica says about itself (device, kernel paths,
+        time-to-ready) is kept with its endpoint for the launcher to
+        show — `route` prints it per replica."""
+        import io
+
+        from paddle_tpu.runtime.master import ServingFleet
+        fleet = ServingFleet(str(tmp_path / "lm.tar"), replicas=1)
+        doc = {"port": 7, "health_port": 8,
+               "device": {"platform": "cpu", "kind": "cpu", "id": 0,
+                          "count": 1, "visible_chips": "0"},
+               "kernel_paths": {"decode": {"attention": "xla"}}}
+        proc = type("P", (), {"stdout": io.StringIO(
+            json.dumps({"replica_ready": doc}) + "\n"),
+            "poll": lambda self: None})()
+        ep = fleet._await_ready("replica0", proc, time.time() + 5)
+        assert ep["port"] == 7 and ep["health_port"] == 8
+        assert ep["ready"]["device"]["visible_chips"] == "0"
+
+    def test_router_side_never_initialises_a_backend(self):
+        """Everything the `route` parent runs — the CLI module, the
+        router, fleet keying, the compile-cache helper — under a JAX
+        platform that does not exist: any backend initialisation would
+        raise. (On a TPU host a parent that touched JAX would hold the
+        chips its replicas need.)"""
+        import os
+        import subprocess
+        import sys
+        code = """
+import numpy as np
+from paddle_tpu import cli
+from paddle_tpu.runtime.master import ServingFleet
+from paddle_tpu.serving.router import Router, fleet_keying
+from paddle_tpu.utils import compile_cache
+compile_cache.configure()
+
+class Fake:
+    name = "replica0"
+    def __init__(self): self.sent = []
+    def health(self): return {"block_size": 128, "chunk_tokens": 128}
+    def submit(self, spec): self.sent.append(spec)
+    def pump(self): pass
+    def poll(self): return []
+    def alive(self): return True
+    def metrics_snapshot(self): return None
+    def close(self): pass
+
+h = Fake()
+bs, chunk = fleet_keying([h])
+router = Router([h], block_size=bs, chunk_tokens=chunk)
+router.submit(np.arange(5, dtype=np.int32), 4)
+router.step()
+assert h.sent, "router never placed the request"
+import jax
+try:
+    jax.devices()
+except RuntimeError as e:
+    print("BACKEND_UNTOUCHED", type(e).__name__)
+"""
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, cwd=repo,
+            env=dict(os.environ, JAX_PLATFORMS="no_such_platform",
+                     PYTHONPATH=repo))
+        assert r.returncode == 0, r.stderr[-3000:]
+        assert "BACKEND_UNTOUCHED" in r.stdout

@@ -276,6 +276,13 @@ def test_engine_artifact_v4_paged_roundtrip(tmp_path, rng):
         "kv_dtype": "none",
         "pool_layout": transformer.POOL_LAYOUT}
     assert srv.meta["engine_pallas"] == pallas_policy.pallas_mode(None)
+    # what every exported module placed, recorded as it was traced:
+    # one decode + (2 buckets x 2 context spans) chunk-prefill programs
+    xla = {"attention": "xla", "sampler": "xla"}
+    assert srv.meta["engine_kernel_paths"] == {
+        "decode": xla,
+        **{f"prefill_{b}_{pv}": dict(xla, span_write="xla")
+           for b, pv in ((8, 1), (16, 2), (8, 3), (16, 4))}}
     assert srv.cost_analysis["engine_decode"]["flops"] > 0
     # legacy lockstep path unchanged on a v4 artifact
     got = srv.generate(prompt, max_new=new)
@@ -286,6 +293,7 @@ def test_engine_artifact_v4_paged_roundtrip(tmp_path, rng):
     tracker = CompileTracker()
     eng = srv.engine(seed=0, tracker=tracker)
     assert isinstance(eng, PagedDecodeEngine)
+    assert eng.health()["kernel_paths"] == srv.meta["engine_kernel_paths"]
     reqs = [eng.submit(prompt[i], max_new=new) for i in range(B)]
     long_p = rng.randint(0, 40, 24).astype(np.int32)   # > max bucket 16
     reqs.append(eng.submit(long_p, max_new=4))
@@ -469,3 +477,38 @@ def test_moe_artifact_roundtrip_matches_generate(tmp_path, rng):
     want = np.asarray(transformer.generate(
         params, jnp.asarray(prompt), cfg, max_new=new))
     np.testing.assert_array_equal(got, want)
+
+
+def test_engine_pallas_resolves_from_the_export_target(tmp_path,
+                                                       monkeypatch):
+    """The engine modules' kernel policy follows the platform they are
+    exported FOR, not the exporting process's backend: a TPU-only
+    export from this CPU host places the compiled kernels (it used to
+    stamp the exporter's "off" and serve XLA on the chip without a
+    word); a mixed target list cannot carry Mosaic kernels and
+    resolves — and is stamped — "off"."""
+    import dataclasses
+
+    import pytest
+    from paddle_tpu.ops.pallas import policy
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    cfg = dataclasses.replace(CFG, max_len=128)
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    kw = dict(batch=2, prompt_len=4, cache_len=128,
+              engine_buckets=(128,), engine_paged=True)
+    tpu = str(tmp_path / "tpu.tar")
+    with policy.compile_target("TPU v5 lite"):
+        lm_serving.save_lm_artifact(tpu, params, cfg,
+                                    platforms=["tpu"], **kw)
+    meta = lm_serving.load_lm_artifact(tpu).meta
+    assert meta["engine_pallas"] == "on"
+    assert meta["engine_paged"]["block_size"] == 128     # the default
+    assert {v for rec in meta["engine_kernel_paths"].values()
+            for v in rec.values()} == {"pallas"}
+    # without a named chip the compiled kernels have no VMEM figure
+    with pytest.raises(ValueError, match="no VMEM figure"):
+        lm_serving.save_lm_artifact(tpu, params, cfg,
+                                    platforms=["tpu"], **kw)
+    # a mixed target list cannot carry Mosaic kernels: XLA path, stamped
+    assert policy.pallas_mode(None, platform="mixed") == "off"
+    assert policy.pallas_mode(None, platform="tpu") == "on"

@@ -126,31 +126,47 @@ class TestBlockSelection:
     """Shape-keyed block-size selection with VMEM-fit validation (no
     hand-tuned constants in the public API path)."""
 
+    # what the interpreter plans against: the smallest scoped default
+    BUDGET = fa.planning_budget(interpret=True)
+
+    def test_budget_is_the_target_chips(self):
+        from paddle_tpu.ops.pallas import policy
+        assert self.BUDGET == int(16 * 2**20 * 0.85)
+        with policy.compile_target("TPU v5 lite"):
+            assert fa.planning_budget(False) == int(128 * 2**20 * 0.85)
+        with policy.compile_target("TPU v99"):
+            with pytest.raises(ValueError, match="TPU v99"):
+                fa.planning_budget(False)
+
     def test_measured_table_hit(self):
-        bq, bk = fa.select_block_sizes(2048, 64, jnp.float32)
+        bq, bk = fa.select_block_sizes(2048, 64, jnp.float32,
+                                       self.BUDGET)
         assert (bq, bk) == fa.MEASURED_BLOCKS[(2048, 64, "float32")]
 
     def test_default_fits_and_divides(self):
         for seq in (7, 128, 1000, 4096, 8192):
-            bq, bk = fa.select_block_sizes(seq, 64, jnp.bfloat16)
+            bq, bk = fa.select_block_sizes(seq, 64, jnp.bfloat16,
+                                           self.BUDGET)
             assert bq <= max(seq, 64) and bk <= max(seq, 64)
             tp = fa._pad_to_blocks(seq, bq, bk)
             assert tp % bq == 0 and tp % bk == 0
-            assert fa._vmem_working_set(tp, 64, bq, bk, 2) <= fa.VMEM_BYTES
+            assert fa._vmem_working_set(tp, 64, bq, bk, 2) <= self.BUDGET
 
     def test_long_seq_fp32_prefers_fit(self):
         """seq 16k, D=64: whole-K/V residency must still yield a fitting
         choice in BOTH dtypes, not a crash (fp32 is the stressful one:
         K/V alone are 2·16k·64·4 = 8 MiB)."""
         for dtype, isz in ((jnp.bfloat16, 2), (jnp.float32, 4)):
-            bq, bk = fa.select_block_sizes(16384, 64, dtype)
+            bq, bk = fa.select_block_sizes(16384, 64, dtype,
+                                           self.BUDGET)
             tp = fa._pad_to_blocks(16384, bq, bk)
             assert fa._vmem_working_set(tp, 64, bq, bk,
-                                        isz) <= fa.VMEM_BYTES, dtype
+                                        isz) <= self.BUDGET, dtype
 
     def test_unfittable_raises_actionable(self):
         with pytest.raises(ValueError, match="ring_attention"):
-            fa.select_block_sizes(1 << 17, 256, jnp.float32)
+            fa.select_block_sizes(1 << 17, 256, jnp.float32,
+                                  self.BUDGET)
 
     def test_auto_selection_matches_reference(self, rng):
         """flash_attention with no block args (auto path) stays exact."""

@@ -250,12 +250,20 @@ class TestFlashDecodeKernel:
         # independent of the pool size M (only the slot's own span
         # lives in scratch) — a huge pool behind a serving-sized span
         # fits; a span whose V scratch alone exceeds VMEM does not
-        assert fd.decode_kernel_fits(8 * 2048, 128, 16, 4, 128,
-                                     jnp.bfloat16)
-        assert fd.decode_kernel_fits(512 * 8192, 512, 16, 8, 256,
-                                     jnp.float32)
-        assert not fd.decode_kernel_fits(512 * 8192, 2048, 16, 8, 512,
-                                         jnp.float32)
+        from paddle_tpu.ops.pallas import policy
+        with policy.compile_target("TPU v5 lite"):
+            small = fd.decode_vmem_bytes(8 * 2048, 16, 128, 4, 128, 2)
+            # a serving-sized span asks for no more than the scoped
+            # default; the limit is never set below it
+            assert policy.vmem_limit_bytes(small, "t") == \
+                policy.SCOPED_VMEM_DEFAULT_BYTES
+            big = fd.decode_vmem_bytes(512 * 8192, 64, 128, 8, 256, 4)
+            assert big > policy.SCOPED_VMEM_DEFAULT_BYTES // 2
+            assert policy.vmem_limit_bytes(big, "t") == 2 * big
+            huge = fd.decode_vmem_bytes(1 << 30, 1 << 12, 128, 8, 512,
+                                        4)
+            with pytest.raises(ValueError, match="planning budget"):
+                policy.vmem_limit_bytes(huge, "t")
 
 
 class TestFusedSample:
@@ -360,131 +368,109 @@ class TestEnginePallas:
         assert "engine_decode_mfu" in eng.metrics_text()
 
 
-class TestOnModeFallback:
-    def test_on_mode_serves_via_xla_off_tpu(self, rng):
-        """``pallas="on"`` on a non-TPU backend must fall back to the
-        XLA path with a once-per-mode warning, not fail the first
-        compile — the dispatch gate the head-major relayout flipped
-        from a constant veto (``MOSAIC_LOWERABLE``) to backend check +
-        per-shape lowering probes. On a TPU backend the same gate
-        returns True and the probes decide per shape."""
-        import warnings
-        assert fd.kernels_dispatchable("interpret") is True
-        assert fd.kernels_dispatchable("off") is False
-        on_tpu = jax.default_backend() == "tpu"
-        fd._warned_fallback = set()
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            assert fd.kernels_dispatchable("on") is on_tpu
-            # second and third resolutions must NOT warn again — the
-            # engine resolves the mode once per program build, and a
-            # warning per build would spam every chunk-bucket compile
-            assert fd.kernels_dispatchable("on") is on_tpu
-            assert fd.kernels_dispatchable("on") is on_tpu
-        if not on_tpu:
-            warned = [w for w in rec
-                      if "falls back" in str(w.message)]
-            assert len(warned) == 1, [str(w.message) for w in rec]
+class TestNoSilentFallback:
+    """``on`` — and ``auto`` on a TPU — place the compiled kernels or
+    raise; only ``off`` selects the XLA path. Nothing degrades."""
+
+    def test_on_mode_raises_off_tpu(self):
+        """Off-TPU there is no chip to take a compiled kernel: the
+        engine refuses at construction (its decode program traces
+        there), naming the way out — it used to serve XLA silently."""
+        if jax.default_backend() == "tpu":
+            pytest.skip("off-TPU refusal")
+        with pytest.raises(ValueError, match="block_size 8 is not a "
+                                             "multiple of 128"):
+            _paged(pallas="on")
+        # at a block size the compiler takes, the missing chip is the
+        # refusal
+        kv = jnp.zeros((1, 128, 8), jnp.float32)
+        with pytest.raises(ValueError, match="no VMEM figure for "
+                                             "device kind"):
+            fd.flash_decode_attention(
+                jnp.zeros((1, 1, 2, 8), jnp.float32), kv, kv,
+                jnp.zeros((1, 1), jnp.int32), jnp.zeros(1, jnp.int32),
+                block_size=128)
+
+    def test_auto_on_tpu_raises_instead_of_degrading(self, monkeypatch):
+        """``auto`` resolves against the backend; with a TPU reported
+        it is ``on`` — same refusal, no quiet XLA engine."""
+        monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert policy.pallas_mode(None) == "on"
+        with pytest.raises(ValueError, match="multiple of 128"):
+            _paged(pallas=None)
+
+    def test_unknown_device_kind_is_an_error(self):
+        with policy.compile_target("TPU v99"):
+            with pytest.raises(ValueError, match="TPU v99"):
+                policy.vmem_capacity_bytes()
+        with policy.compile_target("TPU v5 lite"):
+            assert policy.vmem_capacity_bytes() == 128 << 20
+
+    def test_sampler_and_flash_attention_do_not_fall_back(self, rng):
+        """The sampling epilogue and the training flash kernel place
+        the compiled kernel under ``on`` too — off-TPU that is a
+        refused compile, never the XLA sampler / jnp reference."""
+        from paddle_tpu.ops.pallas import flash_attention
+        from paddle_tpu.serving import sampling
+        if jax.default_backend() == "tpu":
+            pytest.skip("off-TPU refusal")
+        tail = sampling._epilogue("on")
+        with pytest.raises(Exception, match="[Ii]nterpret|TPU"):
+            jax.jit(tail)(jnp.zeros((2, 40)), jnp.int32(0),
+                          jnp.zeros(2), jnp.zeros(2, jnp.int32)
+                          ).block_until_ready()
+        q = jnp.asarray(rng.randn(1, 16, 2, 8), jnp.float32)
+        with pytest.raises(ValueError, match="no VMEM figure"):
+            flash_attention(q, q, q, interpret=False)
+
+
+class TestKernelPathRecord:
+    """The engine reports, per compiled program, the path each kernel
+    site actually placed — recorded at trace time, where placement
+    happens."""
+
+    def test_paths_per_program_by_mode(self, rng):
+        for mode, want in (("interpret", policy.PATH_INTERPRET),
+                           ("off", policy.PATH_XLA)):
+            eng = _paged(pallas=mode)
+            # construction traced the decode program (its FLOPs)
+            assert eng.kernel_paths["decode"] == {
+                "attention": want, "sampler": want}
+            eng.submit(rng.randint(0, 40, 20).astype(np.int32),
+                       max_new=3)
+            eng.run_until_idle()
+            prefills = {k: v for k, v in eng.kernel_paths.items()
+                        if k.startswith("prefill_")}
+            assert len(prefills) == eng.compile_counts()["prefill"]
+            for rec in prefills.values():
+                assert rec == {"attention": want, "span_write": want,
+                               "sampler": want}
+            assert eng.health()["kernel_paths"] == eng.kernel_paths
+
+    def test_precompile_covers_the_chunk_grid(self, rng):
+        """precompile() runs every (bucket, span) program + decode on
+        an idle engine without disturbing what a request reads:
+        outputs equal an engine that compiled lazily, and traffic
+        afterwards compiles nothing new."""
         prompts = [rng.randint(0, 40, n).astype(np.int32)
                    for n in (5, 20)]
-        outs = {}
-        for mode in ("on", "off"):
-            eng = _paged(pallas=mode)
+        lazy, warm = _paged(pallas="off"), _paged(pallas="off")
+        counts = warm.precompile()
+        spans = warm.cache_len // warm.chunk_tokens
+        assert counts == {"prefill": spans * len(warm.buckets),
+                          "decode": 1}
+        outs = []
+        for eng in (lazy, warm):
             reqs = [eng.submit(p, max_new=5) for p in prompts]
             eng.run_until_idle()
-            outs[mode] = [r.output.tolist() for r in reqs]
-        assert outs["on"] == outs["off"]
-
-    def test_no_warning_spam_across_engine_lifecycle(self, rng):
-        """A full pallas="on" engine run off-TPU — chunk prefill
-        programs, decode, sampling epilogue — emits at most ONE
-        fallback RuntimeWarning in total (once per mode), never one
-        per compiled program."""
-        import warnings
-        if jax.default_backend() == "tpu":
-            pytest.skip("off-TPU fallback path")
-        fd._warned_fallback = set()
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            eng = _paged(pallas="on")
-            reqs = [eng.submit(rng.randint(0, 40, n).astype(np.int32),
-                               max_new=4) for n in (5, 9, 20)]
-            eng.run_until_idle()
-        assert all(r.output is not None for r in reqs)
-        fallback = [w for w in rec
-                    if issubclass(w.category, RuntimeWarning)
-                    and "falls back" in str(w.message)]
-        assert len(fallback) <= 1, [str(w.message) for w in fallback]
-
-
-class TestLoweringProbes:
-    """The MOSAIC_LOWERABLE constant became real probes: deviceless
-    XLA:TPU lowering of the actual kernels, cached per shape. These run
-    the probes on CPU — the same machinery ``serving_bench --tpu-check``
-    asserts — so a kernel change that breaks Mosaic legality fails
-    tier-1, not the first on-chip deploy."""
-
-    def test_decode_probe_accepts_all_kv_dtypes(self):
-        for kvd, dt in (("none", jnp.float32), ("int8", jnp.int8),
-                        ("int4", jnp.int8)):
-            assert fd.decode_lowering_ok(64, 4, BS, 1, 2,
-                                         CFG.head_dim, dt,
-                                         kv_dtype=kvd), kvd
-
-    def test_sample_probe_accepts(self):
-        assert fd.sample_lowering_ok(2, 40)
-
-    def test_probe_caches_by_signature(self):
-        fd._LOWERING_CACHE.clear()
-        assert fd.decode_lowering_ok(64, 4, BS, 1, 2, CFG.head_dim,
-                                     jnp.float32)
-        n = len(fd._LOWERING_CACHE)
-        assert fd.decode_lowering_ok(64, 4, BS, 1, 2, CFG.head_dim,
-                                     jnp.float32)
-        assert len(fd._LOWERING_CACHE) == n    # cache hit, no re-probe
-
-    def test_probe_refuses_unlowerable_shape(self):
-        """A genuinely illegal BlockSpec must come back False — the
-        probe is a real gate, not a rubber stamp — and the refusal
-        must leave its diagnostic in ``lowering_failures`` plus a
-        RuntimeWarning (a silent XLA fallback on a real chip would be
-        undiagnosable otherwise)."""
-        import warnings
-
-        def build():
-            import jax.numpy as jnp
-
-            def bad():
-                from jax.experimental import pallas as pl
-                # second-to-last block dim 1 against a multi-row
-                # array — the exact pre-relayout violation
-                return pl.pallas_call(
-                    lambda x_ref, o_ref: o_ref.__setitem__(
-                        ..., x_ref[...]),
-                    grid=(4,),
-                    in_specs=[pl.BlockSpec((4, 1, 8),
-                                           lambda i: (0, i, 0))],
-                    out_specs=pl.BlockSpec((4, 1, 8),
-                                           lambda i: (0, i, 0)),
-                    out_shape=jax.ShapeDtypeStruct((4, 4, 8),
-                                                   jnp.float32),
-                )(jnp.zeros((4, 4, 8), jnp.float32))
-
-            return bad, []
-
-        fd._LOWERING_CACHE.pop(("test-bad",), None)
-        fd._LOWERING_DETAIL.pop(("test-bad",), None)
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            assert fd.mosaic_lowerable(("test-bad",), build) is False
-        assert any("Mosaic lowering probe" in str(w.message)
-                   for w in rec)
-        assert ("test-bad",) in fd.lowering_failures("test-bad")
-        # cached refusal: no second probe, no second warning
-        with warnings.catch_warnings(record=True) as rec2:
-            warnings.simplefilter("always")
-            assert fd.mosaic_lowerable(("test-bad",), build) is False
-        assert not rec2
+            outs.append([r.output.tolist() for r in reqs])
+        assert outs[0] == outs[1]
+        assert warm.compile_counts() == counts
+        warm.submit(prompts[0], max_new=20)
+        warm.step()
+        with pytest.raises(RuntimeError, match="idle"):
+            warm.precompile()
 
 
 class TestInt8Serving:

@@ -123,6 +123,31 @@ class TestChunkPrefillKernel:
             # valid rows actually changed
             assert not (b[:, :, 2 * BS:2 * BS + c] == want).all()
 
+    def test_span_write_old_bytes_come_from_the_input_block(self):
+        """On the chip an aliased OUTPUT block starts as whatever VMEM
+        held; only input blocks are copied in. The generic interpreter
+        pre-fills outputs from their aliases and hid a kernel that read
+        old bytes off the output ref (first v5e run: garbage over every
+        padded row, NaN out of decode). The TPU interpreter models the
+        hardware: two pages through one program buffer, so a stale
+        output block shows the previous page's span."""
+        from jax.experimental.pallas import tpu as pltpu
+        L, H, M, D = 2, 2, 4 * BS, 8
+        pool = {"k": jnp.ones((L, H, M, D)), "v": jnp.full((L, H, M, D),
+                                                            2.0)}
+        spans = {"k": jnp.full((L, H, 2 * BS, D), 5.0),
+                 "v": jnp.full((L, H, 2 * BS, D), 6.0)}
+        valid = jnp.arange(2 * BS) < BS + 2      # page 2: 2 valid rows
+        out = fp.paged_span_write(
+            pool, spans, jnp.asarray([3, 1], jnp.int32), valid,
+            block_size=BS, interpret=pltpu.InterpretParams())
+        for leaf, old, new in (("k", 1.0, 5.0), ("v", 2.0, 6.0)):
+            a = np.asarray(out[leaf])
+            np.testing.assert_array_equal(a[:, :, 3 * BS:], new)
+            np.testing.assert_array_equal(a[:, :, BS:BS + 2], new)
+            np.testing.assert_array_equal(a[:, :, BS + 2:2 * BS], old)
+            np.testing.assert_array_equal(a[:, :, :BS], old)
+
     def test_kernel_direct_tile_sweep(self, rng):
         """flash_chunk_prefill over every legal tile returns identical
         values (tile schedules the gather, never the numerics)."""
@@ -182,14 +207,21 @@ class TestChunkPrefillKernel:
         # M-row pool head columns sat in VMEM) — a giant pool behind a
         # serving-sized chunk fits; the score scratch is what binds
         # now, so a huge (chunk x span) product does not
-        assert fp.prefill_kernel_fits(4 * 2048, 2048, 64, 4, 128,
-                                      jnp.bfloat16)
-        assert fp.prefill_kernel_fits(8 * 2048, 2048, 64, 4, 128,
-                                      jnp.bfloat16)
-        assert fp.prefill_kernel_fits(512 * 8192, 2048, 64, 4, 128,
-                                      jnp.bfloat16)
-        assert not fp.prefill_kernel_fits(512 * 8192, 8192, 512, 8,
-                                          256, jnp.float32)
+        from paddle_tpu.ops.pallas import policy
+        with policy.compile_target("TPU v5 lite"):
+            for M in (4 * 2048, 512 * 8192):
+                need = fp.prefill_vmem_bytes(M, 2048, 64, 4, 128, 2)
+                assert policy.vmem_limit_bytes(need, "t") >= need
+            with pytest.raises(ValueError, match="planning budget"):
+                policy.vmem_limit_bytes(fp.prefill_vmem_bytes(
+                    512 * 8192, 32768, 512, 8, 256, 4), "t")
+        # the span-write program holds ONE layer's page: its VMEM does
+        # not grow with the layer count, and the lane-padded scale
+        # column is what a quantized pool pays
+        blk = [((1, 8, 128, 64), jnp.bfloat16)]
+        assert fp.span_write_vmem_bytes(blk) == 6 * 8 * 128 * 128 * 2
+        assert fp.span_write_vmem_bytes(
+            [((1, 8, 128, 1), jnp.float32)]) == 6 * 8 * 128 * 128 * 4
         span = 64 * 2048
         assert (fp.prefill_vmem_bytes(span, 2048, 64, 4, 128, 1,
                                       "int8")

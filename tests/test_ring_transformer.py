@@ -281,7 +281,8 @@ class TestGQAEngines:
         including the six leaked `MasterService._snapshot_loop` /
         `_beat` daemon threads visible in the crash dump; threads
         exonerated). Host memory is not a factor (128 GB free, 1-core
-        host, 8 simulated XLA host devices, jax 0.4.37). Everything
+        host, 8 simulated XLA host devices; first seen under jax 0.4.37,
+        still there under the installed jax 0.9.0). Everything
         points at process state accumulated over the FULL sweep
         (hundreds of live LLVM-JIT'd executables) tripping a bug in
         XLA:CPU's compiler on these largest-in-repo grad programs —

@@ -31,6 +31,47 @@ class TestNativeCodec:
     def test_native_lib_builds(self):
         assert native.get() is not None, "g++ build of recordio.cc failed"
 
+    def test_library_is_keyed_by_source_and_compiler_line(
+            self, tmp_path, monkeypatch):
+        """A leftover binary is never loaded: the file name carries a
+        hash of recordio.cc and the compiler line (a copied tree
+        rewrites mtimes, so mtimes prove nothing); a build that fails
+        is logged once, loudly, and get() answers None."""
+        import shutil
+        assert native.get() is not None
+        so = native._so_path()
+        assert os.path.exists(so) and so.endswith(".so")
+        # another source or another flag set = another file name
+        src2 = tmp_path / "recordio.cc"
+        shutil.copy(native._SRC, src2)
+        with open(src2, "a") as f:
+            f.write("// changed\n")
+        monkeypatch.setattr(native, "_SRC", str(src2))
+        assert native._so_path() != so
+        monkeypatch.setattr(native, "_SRC", os.path.join(
+            os.path.dirname(so), "recordio.cc"))
+        monkeypatch.setattr(native, "_CXX", native._CXX + ["-O0"])
+        assert native._so_path() != so
+        # a failing build: loud, once, pure-Python takes over
+        monkeypatch.setattr(native, "_CXX", ["g++", "--no-such-flag"])
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+        monkeypatch.setattr(native, "_tried", False)
+        monkeypatch.setattr(native, "_lib", None)
+        import logging
+        seen = []
+        handler = logging.Handler(level=logging.ERROR)
+        handler.emit = lambda rec: seen.append(rec.getMessage())
+        native.log.addHandler(handler)
+        try:
+            assert native.get() is None
+            assert native.get() is None
+        finally:
+            native.log.removeHandler(handler)
+        assert len(seen) == 1, seen
+        assert "NATIVE RECORDIO UNAVAILABLE" in seen[0]
+        assert "pure-Python codec takes over" in seen[0]
+        assert not list(tmp_path.glob("*.so*"))
+
     def test_roundtrip(self, rio_file):
         got = list(recordio.read_records(rio_file))
         assert got == _records(257)

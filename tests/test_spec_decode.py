@@ -211,6 +211,27 @@ def _mk_spec(draft_params=DRAFT_PARAMS, draft_cfg=DRAFT_CFG, k=3, **kw):
 
 
 class TestSpecEngine:
+    def test_precompile_covers_the_spec_program_set(self, rng):
+        """precompile() runs the chunk grid of BOTH pools plus propose /
+        draft_verify / verify on inert inputs: traffic afterwards
+        compiles nothing new and decodes what a lazy engine decodes."""
+        prompts = [rng.randint(0, 40, n).astype(np.int32) for n in (5, 13)]
+
+        def run(eng):
+            reqs = [eng.submit(p, max_new=10) for p in prompts]
+            eng.run_until_idle()
+            return [list(r.tokens) for r in reqs]
+
+        lazy, warm = _mk_spec(), _mk_spec()
+        counts = warm.precompile()
+        spans = warm.cache_len // warm.chunk_tokens
+        assert counts == {
+            "prefill": spans * len(warm.buckets),
+            "draft_prefill": spans * len(warm.buckets), "decode": 0,
+            "propose": 1, "verify": 1, "draft_verify": 1}
+        assert run(warm) == run(lazy)
+        assert warm.compile_counts() == counts
+
     def test_greedy_bitwise_vs_target_only(self, rng):
         """Full traces through both engines: outputs identical even
         with an unrelated draft (acceptance is low, tokens equal)."""
