@@ -27,6 +27,7 @@ import numpy as np
 from paddle_tpu.core.ragged import (DEFAULT_BUCKETS, SequenceBatch,
                                     bucket_length, sub_lengths_matrix)
 from paddle_tpu.data_type import InputType, Kind, SeqLevel
+from paddle_tpu.observe.trace import trace_scope
 from paddle_tpu.topology import Value
 from paddle_tpu.utils import enforce
 
@@ -84,14 +85,25 @@ class DataFeeder:
                 if itype.kind == Kind.INDEX:
                     arr = np.ascontiguousarray(col, dtype=np.int32).reshape(-1)
                     self._check_index_range(arr, itype.dim, name)
+                    feeds[name] = Value(jnp.asarray(arr))
                 else:
-                    arr = np.ascontiguousarray(col, dtype=np.float32)
-                feeds[name] = Value(jnp.asarray(arr))
+                    feeds[name] = self._dense(col)
             return feeds
         for name, itype in self.data_types.items():
             col = [sample[self.feeding[name]] for sample in batch]
             feeds[name] = self._convert(col, itype, name)
         return feeds
+
+    @staticmethod
+    def _dense(rows) -> Value:
+        """One dense slot (a list of per-sample rows, or a pre-batched
+        column) as a device array, the host's half and the device's
+        apart: ``stack`` assembles one contiguous float32 array, ``put``
+        hands it to ``jnp.asarray``."""
+        with trace_scope("stack"):
+            arr = np.ascontiguousarray(rows, dtype=np.float32)
+        with trace_scope("put"):
+            return Value(jnp.asarray(arr))
 
     @staticmethod
     def _check_index_range(arr: np.ndarray, dim: int, name: str):
@@ -111,7 +123,7 @@ class DataFeeder:
     def _convert(self, col: List, itype: InputType, name: str = "?") -> Value:
         if itype.seq == SeqLevel.NO_SEQUENCE:
             if itype.kind == Kind.DENSE:
-                return Value(jnp.asarray(np.asarray(col, np.float32)))
+                return self._dense(col)
             if itype.kind == Kind.INDEX:
                 arr = np.asarray(col, np.int32)
                 self._check_index_range(arr, itype.dim, name)

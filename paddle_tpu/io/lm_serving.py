@@ -25,6 +25,7 @@ from paddle_tpu.io.checkpoint import _flatten          # shared pytree walk
 from paddle_tpu.io.merged import _add_member as _add   # shared tar append
 from paddle_tpu.observe import costs as _costs
 from paddle_tpu.observe import metrics as _metrics
+from paddle_tpu.observe import trace as _trace
 from paddle_tpu.serving import blocks as _blocks
 
 FORMAT_VERSION = 5   # max supported; plain artifacts still save as v1,
@@ -525,6 +526,12 @@ class LMServer:
         return HealthServer(registry=self.metrics, health_fn=self.health,
                             host=host, port=port)
 
+    def _engine_program(self, member: str):
+        """One of the engine's exported modules, deserialised."""
+        import jax.export
+        with _trace.trace_scope("artifact/programs"):
+            return jax.export.deserialize(self._engine_bins[member]).call
+
     def engine(self, *, seed: Optional[int] = None, registry=None,
                tracker=None, chunk_tokens: Optional[int] = None,
                tiers=None):
@@ -537,7 +544,6 @@ class LMServer:
         Raises on v1/v2 artifacts — re-export with
         ``engine_buckets=`` to serve continuously; ``generate()`` stays
         the lockstep fallback either way."""
-        import jax.export
         import jax.numpy as jnp
         from paddle_tpu.serving.engine import (DecodeEngine,
                                                PagedDecodeEngine)
@@ -576,15 +582,13 @@ class LMServer:
                     f"chunk_tokens={chunk_tokens} has no programs — "
                     f"re-export to change the grid")
             prefills = {}
-            for name, blob in self._engine_bins.items():
+            for name in self._engine_bins:
                 if not name.startswith("engine_prefill_paged_"):
                     continue
                 b, pv = name[len("engine_prefill_paged_"):
                              -len(".bin")].split("_")
-                prefills[(int(b), int(pv))] = \
-                    jax.export.deserialize(blob).call
-            decode = jax.export.deserialize(
-                self._engine_bins["engine_decode_paged.bin"]).call
+                prefills[(int(b), int(pv))] = self._engine_program(name)
+            decode = self._engine_program("engine_decode_paged.bin")
 
             def prefill(params, pool, tokens, length, pagevec, *rest):
                 key = (tokens.shape[1], pagevec.shape[0])
@@ -626,13 +630,13 @@ class LMServer:
                 draft_pool = transformer.init_block_pool(
                     dcfg, paged["num_blocks"], paged["block_size"])
                 dprefills = {}
-                for name, blob in self._engine_bins.items():
+                for name in self._engine_bins:
                     if not name.startswith("engine_draft_prefill_"):
                         continue
                     b, pv = name[len("engine_draft_prefill_"):
                                  -len(".bin")].split("_")
                     dprefills[(int(b), int(pv))] = \
-                        jax.export.deserialize(blob).call
+                        self._engine_program(name)
 
                 def draft_prefill(dp, dpool, tokens, length, pagevec):
                     key = (tokens.shape[1], pagevec.shape[0])
@@ -647,13 +651,10 @@ class LMServer:
                     draft_params=self.draft_params,
                     draft_cache=draft_pool,
                     draft_prefill=draft_prefill,
-                    propose=jax.export.deserialize(
-                        self._engine_bins["engine_propose.bin"]).call,
-                    verify=jax.export.deserialize(
-                        self._engine_bins["engine_verify.bin"]).call,
-                    draft_verify=jax.export.deserialize(
-                        self._engine_bins[
-                            "engine_draft_verify.bin"]).call,
+                    propose=self._engine_program("engine_propose.bin"),
+                    verify=self._engine_program("engine_verify.bin"),
+                    draft_verify=self._engine_program(
+                        "engine_draft_verify.bin"),
                     spec_k=spec["k"], **eng_kw)
             return PagedDecodeEngine(
                 prefill, decode, self.params, pool, **eng_kw)
@@ -667,11 +668,9 @@ class LMServer:
             raise ValueError(
                 "tiered spill (tiers=) needs a paged-engine artifact "
                 "— the row arena has no block pool to demote from")
-        prefills = {b: jax.export.deserialize(
-            self._engine_bins[f"engine_prefill_{b}.bin"]).call
-            for b in self.engine_buckets}
-        decode = jax.export.deserialize(
-            self._engine_bins["engine_decode.bin"]).call
+        prefills = {b: self._engine_program(f"engine_prefill_{b}.bin")
+                    for b in self.engine_buckets}
+        decode = self._engine_program("engine_decode.bin")
 
         def prefill(params, cache, tokens, *rest):
             return prefills[tokens.shape[1]](params, cache, tokens,
@@ -772,24 +771,32 @@ class LMServer:
                                np.stack(toks, axis=1)], axis=1)
 
 
+def _load_params(blob: bytes):
+    with np.load(_io.BytesIO(blob), allow_pickle=False) as z:
+        return _unflatten({k: z[k] for k in z.files})
+
+
 def load_lm_artifact(path: str) -> LMServer:
-    with tarfile.open(path, "r") as tar:
-        members = {m.name: tar.extractfile(m).read()
-                   for m in tar.getmembers()}
+    """Set-up spans (``utils.stat.global_stats``): ``artifact/read`` the
+    tar's members, ``artifact/params`` the ``.npz`` files decoded,
+    ``artifact/programs`` every exported module deserialised (here the
+    lockstep pair, the engine's in :meth:`LMServer.engine`)."""
+    with _trace.trace_scope("artifact/read"):
+        with tarfile.open(path, "r") as tar:
+            members = {m.name: tar.extractfile(m).read()
+                       for m in tar.getmembers()}
     meta = json.loads(members["meta.json"])
     if meta["format_version"] > FORMAT_VERSION:
         raise ValueError(f"artifact format {meta['format_version']} newer "
                          f"than this loader ({FORMAT_VERSION})")
-    with np.load(_io.BytesIO(members["params.npz"]),
-                 allow_pickle=False) as z:
-        params = _unflatten({k: z[k] for k in z.files})
-    draft_params = None
-    if "draft_params.npz" in members:
-        with np.load(_io.BytesIO(members["draft_params.npz"]),
-                     allow_pickle=False) as z:
-            draft_params = _unflatten({k: z[k] for k in z.files})
+    with _trace.trace_scope("artifact/params"):
+        params = _load_params(members["params.npz"])
+        draft_params = None
+        if "draft_params.npz" in members:
+            draft_params = _load_params(members["draft_params.npz"])
     engine_bins = {k: v for k, v in members.items()
                    if k.startswith("engine_")}
-    return LMServer(meta, params, members["prefill.bin"],
-                    members["decode.bin"], engine_bins=engine_bins,
-                    draft_params=draft_params)
+    with _trace.trace_scope("artifact/programs"):
+        return LMServer(meta, params, members["prefill.bin"],
+                        members["decode.bin"], engine_bins=engine_bins,
+                        draft_params=draft_params)

@@ -121,7 +121,13 @@ class CompileTracker:
         signature — the one-liner for call sites that don't need the
         wrapper object. kwargs participate in the signature: a shape
         change in a keyword argument is a cache miss like any other."""
-        sig = arg_signature(args, kwargs)
+        return self.call_signed(name, arg_signature(args, kwargs), fn,
+                                *args, **kwargs)
+
+    def call_signed(self, name: str, sig: Tuple, fn, *args, **kwargs):
+        """``track_call`` for a caller that took ``sig =
+        arg_signature(args, kwargs)`` ahead of the call (the serving
+        engine times the signature apart from the dispatch)."""
         t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         self.record(name, sig, time.perf_counter() - t0)

@@ -1,19 +1,23 @@
-"""Trace scopes: nested named timing regions that show up in xprof.
+"""Trace scopes: nested named timing regions on the profiler's clock.
 
 Wraps ``utils/stat.py``'s StatSet (the reference's REGISTER_TIMER_INFO
-accumulators) and, when profiling is enabled AND jax is importable, also
-opens ``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation`` scopes so
-hot-loop regions land in the xprof timeline on real TPUs. On CPU (or with
-profiling off, or without jax at all) the same scopes degrade to pure
-wall-clock timers — observability code never becomes a hard jax
-dependency.
+accumulators), records each closed scope into the bounded span buffer
+(``observe/chrome_trace.py``) and, whenever jax is already imported,
+opens a ``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation`` so
+the region lands in the profiler's own trace beside the device's
+operations. Outside a profiler session an annotation is a flag test, so
+there is nothing to switch on. Without jax in the process the same
+scopes are pure wall-clock timers — observability code never becomes a
+hard jax dependency.
 
 Scopes nest: a ``trace_scope("backward")`` inside ``trace_scope("step")``
 accumulates under the qualified name ``step/backward`` (per thread), so a
-StatSet print shows the call tree, flattened.
+StatSet print shows the call tree, flattened. The annotation carries the
+name the scope was given: a top-level ``engine/decode_sync`` reads so in
+the profiler, a nested ``convert`` reads ``convert`` under its parent.
 """
 
-import contextlib
+import sys
 import threading
 import time
 from typing import Optional
@@ -22,6 +26,10 @@ from paddle_tpu.observe import chrome_trace as _chrome
 from paddle_tpu.utils import stat as _stat
 
 _tls = threading.local()
+
+# (TraceAnnotation, StepTraceAnnotation), resolved once, the first time
+# a scope opens with jax in the process; False when jax has no profiler
+_annotations = None
 
 
 def _stack():
@@ -35,75 +43,84 @@ def current_scope() -> str:
     return "/".join(_stack())
 
 
-def _profiler_ctx(kind: str, name: str, **kw):
-    """A profiler annotation context, or nullcontext when the profiler
-    is unavailable — never an ImportError."""
+def _resolve_annotations():
+    global _annotations
     try:
-        import jax.profiler
-        return getattr(jax.profiler, kind)(name, **kw)
+        from jax import profiler
+        _annotations = (profiler.TraceAnnotation,
+                        profiler.StepTraceAnnotation)
     except Exception:  # noqa: BLE001 — observability must not crash the job
-        return contextlib.nullcontext()
+        _annotations = False
 
 
-def _profiling_enabled(use_profiler: Optional[bool]) -> bool:
-    if use_profiler is not None:
-        return use_profiler
-    from paddle_tpu.utils.flags import GLOBAL_FLAGS
-    return bool(GLOBAL_FLAGS.get("profile", False))
+def _annotation(step: bool, name: str, use_profiler, kw):
+    if _annotations is None and "jax" in sys.modules:
+        _resolve_annotations()
+    if not _annotations or use_profiler is False:
+        return None
+    return _annotations[step](name, **kw)
 
 
-@contextlib.contextmanager
+class _Scope:
+    """The context manager behind both scopes (a class, not a generator:
+    the serving engine opens nine of these a step)."""
+
+    __slots__ = ("_name", "_stats", "_annotation", "_args", "_qualified",
+                 "_wall0", "_start")
+
+    def __init__(self, name, stats, annotation, args):
+        self._name = name
+        self._stats = stats or _stat.global_stats
+        self._annotation = annotation
+        self._args = args
+
+    def __enter__(self) -> str:
+        stack = _stack()
+        stack.append(self._name)
+        self._qualified = "/".join(stack)
+        self._wall0 = time.time()
+        self._start = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self._qualified
+
+    def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        dur = time.perf_counter() - self._start
+        self._stats.get(self._qualified).add(dur)
+        _chrome.record_span(self._qualified, self._wall0, dur,
+                            args=self._args)
+        _stack().pop()
+        return False
+
+
 def trace_scope(name: str, stats: Optional[_stat.StatSet] = None,
-                use_profiler: Optional[bool] = None):
+                use_profiler: Optional[bool] = None,
+                args: Optional[dict] = None):
     """Open a named timing scope.
 
     - accumulates wall time into ``stats`` (default: the global StatSet)
       under the nesting-qualified name, e.g. ``train_step/forward``
-    - opens a ``jax.profiler.TraceAnnotation`` when profiling is on
+    - records the closed span, with ``args``, into the span buffer
+    - opens a ``jax.profiler.TraceAnnotation`` named ``name`` carrying
+      ``args`` (``use_profiler=False`` leaves the annotation out)
     """
-    stats = stats or _stat.global_stats
-    stack = _stack()
-    stack.append(name)
-    qualified = "/".join(stack)
-    ctx = (_profiler_ctx("TraceAnnotation", name)
-           if _profiling_enabled(use_profiler) else contextlib.nullcontext())
-    wall0 = time.time()
-    start = time.perf_counter()
-    try:
-        with ctx:
-            yield qualified
-    finally:
-        dur = time.perf_counter() - start
-        stats.get(qualified).add(dur)
-        _chrome.record_span(qualified, wall0, dur)
-        stack.pop()
+    return _Scope(name, stats,
+                  _annotation(False, name, use_profiler, args or {}), args)
 
 
-@contextlib.contextmanager
 def step_scope(step_num: int, name: str = "train",
                stats: Optional[_stat.StatSet] = None,
                use_profiler: Optional[bool] = None):
-    """Mark one training step. With profiling on this is a
-    ``jax.profiler.StepTraceAnnotation`` (xprof's step-time view keys on
-    it); always accumulates into the ``name`` timer. Participates in the
-    nesting stack like trace_scope, so an inner ``trace_scope("region")``
-    accumulates under ``train_step/region``."""
-    stats = stats or _stat.global_stats
-    stack = _stack()
-    stack.append(name)
-    qualified = "/".join(stack)
-    ctx = (_profiler_ctx("StepTraceAnnotation", name, step_num=step_num)
-           if _profiling_enabled(use_profiler) else contextlib.nullcontext())
-    wall0 = time.time()
-    start = time.perf_counter()
-    try:
-        with ctx:
-            yield
-    finally:
-        dur = time.perf_counter() - start
-        stats.get(qualified).add(dur)
-        _chrome.record_span(qualified, wall0, dur, args={"step": step_num})
-        stack.pop()
+    """Mark one training step: a ``jax.profiler.StepTraceAnnotation``
+    (xprof's step-time view keys on it) that accumulates into the
+    ``name`` timer. Participates in the nesting stack like trace_scope,
+    so an inner ``trace_scope("region")`` accumulates under
+    ``train_step/region``."""
+    return _Scope(name, stats,
+                  _annotation(True, name, use_profiler,
+                              {"step_num": step_num}), {"step": step_num})
 
 
 def traced(name: Optional[str] = None, **scope_kw):

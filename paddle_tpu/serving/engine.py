@@ -59,6 +59,7 @@ from paddle_tpu.observe import compile_tracker as _ct
 from paddle_tpu.observe import costs as _costs
 from paddle_tpu.observe import metrics as _metrics
 from paddle_tpu.observe import requests as _requests
+from paddle_tpu.observe import trace as _trace
 from paddle_tpu.observe.window import SloConfig, WindowedQuantiles
 from paddle_tpu.serving import blocks as _blocks
 
@@ -77,6 +78,55 @@ _LATENCY_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                     0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 _GOODPUT_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
                     500.0, 1000.0, 2500.0, 5000.0, 10000.0)
+# two decode steps completing further apart than this, with decoders in
+# flight throughout, count as one slow step: a stall, not a long program
+_SLOW_STEP_S = 1.0
+
+# the host's phases of one engine step: trace scope ``engine/<phase>``,
+# series ``engine_<phase>_seconds``. Siblings — no scope encloses them,
+# so a device gap in the profiler's trace is named by the phase under it
+_PHASES = {
+    "ingest": "replica loop: one inbox line parsed and submitted",
+    "schedule": "admission, page allocation, adoption, tier promotion, "
+                "preemption, and the bookkeeping around a prefill chunk",
+    "prefill_chunk": "one prefill (chunk) program dispatched and its "
+                     "sampled token read back",
+    "decode_stage": "slot state staged for the decode program: write "
+                    "pages, the [B] vectors uploaded, the compile "
+                    "tracker's signature",
+    "decode_dispatch": "the call into the decode program, until it "
+                       "returns",
+    "decode_sync": "the sampled ids read back: the wait for the device "
+                   "and the ids' way to the host",
+    "emit": "per-slot loop after the read-back: tokens emitted, "
+            "requests finished and recorded",
+    "reply": "replica loop: one finished request's result line written "
+             "to its sink",
+}
+
+
+class _Phase:
+    """One host phase: an ``observe.trace_scope("engine/<name>")`` whose
+    seconds also land in the engine's own ``engine_<name>_seconds``.
+    ``t0`` / ``end`` are the phase's edges on ``time.perf_counter``, for
+    the lifecycle stamps that used to take their own."""
+
+    __slots__ = ("_series", "_scope", "t0", "end")
+
+    def __init__(self, series, name, args):
+        self._series = series
+        self._scope = _trace.trace_scope("engine/" + name, args=args)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self._scope.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._scope.__exit__(*exc)
+        self.end = time.perf_counter()
+        self._series.observe(self.end - self.t0)
+        return False
 
 
 # the two scheduling tiers: "latency" admits ahead of "batch" and may
@@ -304,10 +354,17 @@ class DecodeEngine:
         self._m_rejected = reg.counter(
             "engine_requests_rejected_total",
             "submissions rejected at validation, by reason")
-        self._m_decode_mfu = reg.gauge(
-            "engine_decode_mfu", "model-FLOPs utilisation of the last "
-            "batched decode step (0 until decode FLOPs and a chip peak "
-            "are known; CPU peaks are nominal — see core/place.py)")
+        self._m_phase = {
+            name: reg.histogram(f"engine_{name}_seconds", help,
+                                buckets=_LATENCY_BUCKETS)
+            for name, help in _PHASES.items()}
+        self._m_slow_steps = reg.counter(
+            "engine_slow_steps_total", f"decode steps that completed "
+            f"more than {_SLOW_STEP_S:g} s after the one before, with "
+            f"decoders in flight throughout")
+        self._step_end: Optional[float] = None   # last decode step's,
+        #                                 while decoders stay in flight
+        self._tag = {"step": 0, "active": 0}     # span args of this step
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -369,6 +426,13 @@ class DecodeEngine:
         """One lifecycle event on this request's async trace track."""
         _chrome.record_event(name, self._wall(perf_t), ph, req.trace_id,
                              args=args or None)
+
+    def phase(self, name: str, **args) -> _Phase:
+        """Context manager over one of the step's host phases
+        (``_PHASES``); ``args`` join the step's number and active-slot
+        count on the recorded span."""
+        return _Phase(self._m_phase[name], name,
+                      dict(self._tag, **args) if args else self._tag)
 
     def _reject(self, rid: int, reason: str, msg: str) -> ValueError:
         """Account + trace a rejected submission; returns (does not
@@ -627,41 +691,45 @@ class DecodeEngine:
     def _admit(self, finished: List[EngineRequest]):
         jnp = self._jnp
         while self._queue and self._free:
-            req = self._queue.popleft()
-            slot = self._free.popleft()
-            now = time.perf_counter()
-            req.prefill_t = now
-            self._m_wait_s.observe(now - req.submit_t)
-            self._ev(req, "queued", "e", now)
-            self._ev(req, "admitted", "n", now, slot=slot,
-                     queue_wait_ms=round(1000 * (now - req.submit_t), 3))
-            self._ev(req, "prefill", "b", now)
-            padded = np.zeros((1, req.bucket), np.int32)
-            padded[0, :req.prompt.size] = req.prompt
-            t0 = time.perf_counter()
-            tok, self.cache = self._tracker.track_call(
-                "serving_engine.prefill", self._prefill_fn,
-                self.params, self.cache, jnp.asarray(padded),
-                np.int32(req.prompt.size), np.int32(slot),
-                np.float32(req.temperature), np.int32(req.top_k),
-                self._seed())
-            tok = int(np.asarray(tok))
-            now = time.perf_counter()
-            req.prefill_own_s = now - t0
-            self._m_prefill_s.observe(now - t0)
-            self._m_prefills.inc()
-            self._ev(req, "prefill_chunk", "n", now,
-                     tokens=int(req.prompt.size), bucket=req.bucket)
-            req.slot, req.status = slot, "running"
-            self._slot_req[slot] = req
-            if self._emit(req, tok, now):
-                finished.append(req)    # one-token request: slot already
-                continue                # recycled by _finish
-            self._active[slot] = True
-            self._pos[slot] = req.prompt.size
-            self._last[slot] = tok
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
+            with self.phase("schedule") as sched:
+                req = self._queue.popleft()
+                slot = self._free.popleft()
+                now = sched.t0
+                req.prefill_t = now
+                self._m_wait_s.observe(now - req.submit_t)
+                self._ev(req, "queued", "e", now)
+                self._ev(req, "admitted", "n", now, slot=slot,
+                         queue_wait_ms=round(
+                             1000 * (now - req.submit_t), 3))
+                self._ev(req, "prefill", "b", now)
+                padded = np.zeros((1, req.bucket), np.int32)
+                padded[0, :req.prompt.size] = req.prompt
+            with self.phase("prefill_chunk", tokens=int(req.prompt.size),
+                            bucket=req.bucket) as chunk:
+                tok, self.cache = self._tracker.track_call(
+                    "serving_engine.prefill", self._prefill_fn,
+                    self.params, self.cache, jnp.asarray(padded),
+                    np.int32(req.prompt.size), np.int32(slot),
+                    np.float32(req.temperature), np.int32(req.top_k),
+                    self._seed())
+                tok = int(np.asarray(tok))
+            with self.phase("schedule"):
+                now = chunk.end
+                req.prefill_own_s = now - chunk.t0
+                self._m_prefill_s.observe(now - chunk.t0)
+                self._m_prefills.inc()
+                self._ev(req, "prefill_chunk", "n", now,
+                         tokens=int(req.prompt.size), bucket=req.bucket)
+                req.slot, req.status = slot, "running"
+                self._slot_req[slot] = req
+                if self._emit(req, tok, now):
+                    finished.append(req)    # one-token request: slot
+                    continue                # already recycled by _finish
+                self._active[slot] = True
+                self._pos[slot] = req.prompt.size
+                self._last[slot] = tok
+                self._temp[slot] = req.temperature
+                self._topk[slot] = req.top_k
         self._m_queue.set(len(self._queue))
 
     # hooks the paged subclass specializes -------------------------------
@@ -695,41 +763,68 @@ class DecodeEngine:
         slots, run one batched decode step for everything in flight.
         Returns the requests that finished during this step."""
         finished: List[EngineRequest] = []
+        self._open_step()
         self._schedule(finished)
         if self._active.any():
-            self._pre_decode()
-            t0 = time.perf_counter()
-            nxt = self._dispatch_decode(self._seed())
-            nxt = np.asarray(nxt)       # the only device->host transfer:
-            now = time.perf_counter()   # [B] int32 ids
-            self._m_step_s.observe(now - t0)
-            self._m_steps.inc()
-            mfu = _costs.mfu(self.decode_flops, now - t0,
-                             self._peak_flops)
-            if mfu is not None:
-                self._m_decode_mfu.set(mfu)
-            for slot in np.flatnonzero(self._active):
-                if self._consume_forced(slot):
-                    continue
-                req = self._slot_req[slot]
-                tok = int(nxt[slot])
-                self._pos[slot] += 1
-                self._last[slot] = tok
-                if self._emit(req, tok, now):
-                    finished.append(req)
-        self._update_gauges()
+            with self.phase("decode_stage") as stage:
+                self._pre_decode()
+                staged = self._stage_decode(self._seed())
+            with self.phase("decode_dispatch"):
+                nxt = self._call_decode(*staged)
+            with self.phase("decode_sync") as sync:
+                # the only device->host transfer: [B] int32 ids
+                nxt = np.asarray(nxt)
+            now = self._close_decode(stage, sync)
+            with self.phase("emit"):
+                for slot in np.flatnonzero(self._active):
+                    if self._consume_forced(slot):
+                        continue
+                    req = self._slot_req[slot]
+                    tok = int(nxt[slot])
+                    self._pos[slot] += 1
+                    self._last[slot] = tok
+                    if self._emit(req, tok, now):
+                        finished.append(req)
+        self._close_step()
         return finished
 
-    def _dispatch_decode(self, seed):
-        """One batched decode program over the current slot state;
-        returns the sampled ids (still on device)."""
+    def _open_step(self):
+        self._tag = {"step": int(self._m_steps.value()),
+                     "active": self.active_count}
+
+    def _close_decode(self, stage: _Phase, sync: _Phase) -> float:
+        """Account one completed decode step (stage + dispatch + sync);
+        returns its completion time."""
+        now = sync.end
+        self._m_step_s.observe(now - stage.t0)
+        self._m_steps.inc()
+        if self._step_end is not None \
+                and now - self._step_end > _SLOW_STEP_S:
+            self._m_slow_steps.inc()
+        self._step_end = now
+        return now
+
+    def _close_step(self):
+        if not self._active.any():
+            self._step_end = None       # no decoder in flight: the next
+            #                             interval is not a step's
+        self._update_gauges()
+
+    def _stage_decode(self, seed):
+        """The decode program's arguments over the current slot state
+        (the [B] vectors uploaded) and their compile-tracker signature."""
         jnp = self._jnp
-        nxt, self.cache = self._tracker.track_call(
-            "serving_engine.decode", self._decode_fn,
-            self.params, self.cache, jnp.asarray(self._last),
-            jnp.asarray(self._pos), jnp.asarray(self._active),
-            *self._decode_extra(),
-            jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
+        args = (self.params, self.cache, jnp.asarray(self._last),
+                jnp.asarray(self._pos), jnp.asarray(self._active),
+                *self._decode_extra(),
+                jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
+        return args, _ct.arg_signature(args, {})
+
+    def _call_decode(self, args, sig):
+        """One batched decode program over staged arguments; returns
+        the sampled ids (still on device)."""
+        nxt, self.cache = self._tracker.call_signed(
+            "serving_engine.decode", sig, self._decode_fn, *args)
         return nxt
 
     def precompile(self) -> Dict[str, int]:
@@ -743,8 +838,10 @@ class DecodeEngine:
         Returns :meth:`compile_counts`."""
         if not self.idle:
             raise RuntimeError("precompile() needs an idle engine")
-        self._precompile_prefill()
-        self._precompile_decode()     # ends in a host read: all done
+        with _trace.trace_scope("precompile/prefill"):
+            self._precompile_prefill()
+        with _trace.trace_scope("precompile/decode"):
+            self._precompile_decode()     # ends in a host read: all done
         return self.compile_counts()
 
     def _precompile_prefill(self):
@@ -760,7 +857,7 @@ class DecodeEngine:
 
     def _precompile_decode(self):
         # no active row: every cache write of the step is dropped
-        np.asarray(self._dispatch_decode(np.int32(0)))
+        np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
 
     def run_until_idle(self, max_steps: int = 100_000
                        ) -> List[EngineRequest]:
@@ -780,9 +877,8 @@ class DecodeEngine:
     def decode_mfu(self) -> Optional[float]:
         """Mean decode-step MFU over this engine's lifetime: decode
         FLOPs / (mean step seconds × chip peak). None until a step ran
-        or when FLOPs/peak are unknown. Noise-robust against the
-        last-step gauge (``engine_decode_mfu``) — the figure
-        ``serving_bench`` reports."""
+        or when FLOPs/peak are unknown — the figure ``serving_bench``
+        reports (XLA's cost model over host time: no share of a chip)."""
         cell = self._m_step_s._peek({})
         if cell is None or not cell.count:
             return None
@@ -803,9 +899,6 @@ class DecodeEngine:
                "pallas": self.pallas_mode,
                "kernel_paths": self.kernel_paths,
                "prefill_buckets": list(self.buckets)}
-        mfu = self.decode_mfu()
-        if mfu is not None:
-            doc["decode_mfu"] = round(mfu, 9)
         self._update_window_gauges()
         ttft = self._win_ttft.quantiles((0.5, 0.95, 0.99))
         doc["window"] = {
@@ -875,7 +968,7 @@ class DecodeEngine:
 
 def _decode_step_flops(decode_fn, params, cache, batch, *extra):
     """Model FLOPs of one compiled decode step from the lowered HLO
-    cost model (None when unavailable) — the ``engine_decode_mfu``
+    cost model (None when unavailable) — the ``decode_mfu()``
     numerator the in-process engines derive themselves; AOT artifacts
     carry it stamped in ``meta.cost_analysis`` instead."""
     vec_i = np.zeros(batch, np.int32)
@@ -1937,38 +2030,51 @@ class PagedDecodeEngine(DecodeEngine):
 
     def _prefill_chunk(self, finished: List[EngineRequest]):
         from paddle_tpu.core import ragged
-        slot = self._prefilling.popleft()
-        req = self._slot_req[slot]
-        while self._try_adopt(slot):
-            pass
-        off = self._slot_off[slot]
-        c = min(req.prompt.size - off, self.chunk_tokens)
-        bucket = ragged.bucket_length(c, self.buckets)
-        end_page = -(-(off + c) // self.block_size)
-        while self._nalloc[slot] < end_page:
-            self._alloc_page(slot)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :c] = req.prompt[off:off + c]
-        # the page-vector PREFIX covering context + chunk: its length
-        # (off/bs context pages + the bucket's own span) is what makes
-        # the chunk program span-specialized — a cold chunk attends
-        # over C tokens, not cache_len. Entries past the allocated
-        # count back only padding positions, whose writes drop.
-        npages = off // self.block_size + -(-bucket // self.block_size)
-        stalled = bool(self._active.any())
-        t0 = time.perf_counter()
-        tok = int(np.asarray(self._dispatch_chunk(
-            slot, padded, c, npages, req.temperature, req.top_k,
-            self._seed())))
-        now = time.perf_counter()
+        with self.phase("schedule"):
+            slot = self._prefilling.popleft()
+            req = self._slot_req[slot]
+            while self._try_adopt(slot):
+                pass
+            off = self._slot_off[slot]
+            c = min(req.prompt.size - off, self.chunk_tokens)
+            bucket = ragged.bucket_length(c, self.buckets)
+            end_page = -(-(off + c) // self.block_size)
+            while self._nalloc[slot] < end_page:
+                self._alloc_page(slot)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :c] = req.prompt[off:off + c]
+            # the page-vector PREFIX covering context + chunk: its length
+            # (off/bs context pages + the bucket's own span) is what
+            # makes the chunk program span-specialized — a cold chunk
+            # attends over C tokens, not cache_len. Entries past the
+            # allocated count back only padding positions, whose writes
+            # drop.
+            npages = off // self.block_size \
+                + -(-bucket // self.block_size)
+            stalled = bool(self._active.any())
+        with self.phase("prefill_chunk", tokens=int(c),
+                        bucket=bucket) as chunk:
+            tok = int(np.asarray(self._dispatch_chunk(
+                slot, padded, c, npages, req.temperature, req.top_k,
+                self._seed())))
+        with self.phase("schedule"):
+            self._chunk_done(slot, req, off, c, tok, chunk, stalled,
+                             finished)
+
+    def _chunk_done(self, slot: int, req: EngineRequest, off: int, c: int,
+                    tok: int, chunk: _Phase, stalled: bool,
+                    finished: List[EngineRequest]):
+        """Bookkeeping after one chunk program: publish its blocks,
+        and on the prompt's final chunk hand the slot to decode."""
+        now = chunk.end
         # accumulate per-chunk device time; the histogram observes one
         # per-request total at the final chunk so its semantics match
-        # the row-arena engine's (chunk-grain timing lives in the stall
-        # histogram and engine_prefill_chunks_total)
-        self._slot_prefill_s[slot] += now - t0
+        # the row-arena engine's (chunk-grain timing lives in
+        # engine_prefill_chunk_seconds and the stall histogram)
+        self._slot_prefill_s[slot] += now - chunk.t0
         self._m_chunks.inc()
         if stalled:
-            self._m_stall.observe(now - t0)
+            self._m_stall.observe(now - chunk.t0)
         # publish the chunk's fully-written prompt blocks NOW (not at
         # prompt completion): a concurrent same-prefix request adopts
         # them instead of re-prefilling — a burst of shared-prefix
@@ -2050,7 +2156,8 @@ class PagedDecodeEngine(DecodeEngine):
         super()._finish(req, reason, now)
 
     def _schedule(self, finished: List[EngineRequest]):
-        self._admit(finished)
+        with self.phase("schedule"):
+            self._admit(finished)
         # With decoders in flight, at most ONE chunk runs per step —
         # the stall a prefill inflicts on them is bounded by a single
         # chunk program. With NOTHING decoding there is nobody to
@@ -2060,8 +2167,9 @@ class PagedDecodeEngine(DecodeEngine):
         while self._prefilling:
             self._prefill_chunk(finished)
             if finished:
-                self._admit(finished)   # a one-token request freed its
-                #                         slot mid-schedule
+                with self.phase("schedule"):
+                    self._admit(finished)   # a one-token request freed
+                    #                         its slot mid-schedule
             if self._active.any():
                 break
 
@@ -2324,94 +2432,99 @@ class SpecDecodeEngine(PagedDecodeEngine):
     def step(self) -> List[EngineRequest]:
         """One scheduler iteration: admission + chunk prefill as the
         paged engine, then ONE propose+verify round for everything in
-        flight (instead of one decode step)."""
+        flight (instead of one decode step). The round's phases:
+        ``decode_stage`` sizes the windows and allocates their pages,
+        ``decode_dispatch`` runs propose (and reads its proposals),
+        draft_verify and verify until the last returns, ``decode_sync``
+        reads the accepted tokens back."""
         finished: List[EngineRequest] = []
+        self._open_step()
         self._schedule(finished)
         if self._active.any():
             jnp = self._jnp
             B, W = self.batch, self.spec_k + 1
-            valid = np.ones(B, np.int32)
-            forced = np.zeros(B, bool)
-            for slot in np.flatnonzero(self._active):
-                req = self._slot_req[slot]
-                if self._slot_forced[slot]:
-                    forced[slot] = True
-                    valid[slot] = min(W, 1 + len(self._slot_forced[slot]))
-                else:
-                    cap = (req.prompt.size + req.max_new
-                           - int(self._pos[slot]) - 1)
-                    valid[slot] = max(min(W, cap), 1)
-            self._valid = valid
-            self._pre_decode()
-            t0 = time.perf_counter()
-            pages_dev = self._decode_extra()[0]
-            window = np.zeros((B, W), np.int32)
-            window[:, 0] = self._last
-            act_prop = self._active & ~forced
-            if act_prop.any():
-                props, self.draft_cache = self._tracker.track_call(
-                    "serving_engine.propose", self._propose_fn,
-                    self.draft_params, self.draft_cache,
-                    jnp.asarray(self._last), jnp.asarray(self._pos),
-                    jnp.asarray(act_prop), jnp.asarray(valid),
-                    pages_dev)
-                window[:, 1:] = np.asarray(props)
-            for slot in np.flatnonzero(forced):
-                # replay window: the known history IS the proposal set
-                f = list(self._slot_forced[slot])[:W - 1]
-                window[slot, 1:1 + len(f)] = f
-            win_dev = jnp.asarray(window)
-            if forced.any():
-                # keep the draft pool position-faithful on replay rows
-                # (propose writes were masked off for these slots)
-                self.draft_cache = self._tracker.track_call(
-                    "serving_engine.draft_verify",
-                    self._draft_verify_fn, self.draft_params,
-                    self.draft_cache, win_dev, jnp.asarray(self._pos),
-                    jnp.asarray(valid),
-                    jnp.asarray(forced & self._active), pages_dev)
-            X, n, self.cache = self._tracker.track_call(
-                "serving_engine.verify", self._verify_fn,
-                self.params, self.cache, win_dev,
-                jnp.asarray(self._pos), jnp.asarray(valid),
-                jnp.asarray(self._active), pages_dev,
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                self._seed())
-            X, n = np.asarray(X), np.asarray(n)
-            now = time.perf_counter()
-            self._m_step_s.observe(now - t0)
-            self._m_steps.inc()
+            with self.phase("decode_stage") as stage:
+                valid = np.ones(B, np.int32)
+                forced = np.zeros(B, bool)
+                for slot in np.flatnonzero(self._active):
+                    req = self._slot_req[slot]
+                    if self._slot_forced[slot]:
+                        forced[slot] = True
+                        valid[slot] = min(
+                            W, 1 + len(self._slot_forced[slot]))
+                    else:
+                        cap = (req.prompt.size + req.max_new
+                               - int(self._pos[slot]) - 1)
+                        valid[slot] = max(min(W, cap), 1)
+                self._valid = valid
+                self._pre_decode()
+                pages_dev = self._decode_extra()[0]
+                window = np.zeros((B, W), np.int32)
+                window[:, 0] = self._last
+                act_prop = self._active & ~forced
+            with self.phase("decode_dispatch"):
+                if act_prop.any():
+                    props, self.draft_cache = self._tracker.track_call(
+                        "serving_engine.propose", self._propose_fn,
+                        self.draft_params, self.draft_cache,
+                        jnp.asarray(self._last), jnp.asarray(self._pos),
+                        jnp.asarray(act_prop), jnp.asarray(valid),
+                        pages_dev)
+                    window[:, 1:] = np.asarray(props)
+                for slot in np.flatnonzero(forced):
+                    # replay window: the known history IS the proposal
+                    # set
+                    f = list(self._slot_forced[slot])[:W - 1]
+                    window[slot, 1:1 + len(f)] = f
+                win_dev = jnp.asarray(window)
+                if forced.any():
+                    # keep the draft pool position-faithful on replay
+                    # rows (propose writes were masked off for these
+                    # slots)
+                    self.draft_cache = self._tracker.track_call(
+                        "serving_engine.draft_verify",
+                        self._draft_verify_fn, self.draft_params,
+                        self.draft_cache, win_dev,
+                        jnp.asarray(self._pos), jnp.asarray(valid),
+                        jnp.asarray(forced & self._active), pages_dev)
+                X, n, self.cache = self._tracker.track_call(
+                    "serving_engine.verify", self._verify_fn,
+                    self.params, self.cache, win_dev,
+                    jnp.asarray(self._pos), jnp.asarray(valid),
+                    jnp.asarray(self._active), pages_dev,
+                    jnp.asarray(self._temp), jnp.asarray(self._topk),
+                    self._seed())
+            with self.phase("decode_sync") as sync:
+                X, n = np.asarray(X), np.asarray(n)
+            now = self._close_decode(stage, sync)
             self._m_spec_rounds.inc()
-            mfu = _costs.mfu(self.decode_flops, now - t0,
-                             self._peak_flops)
-            if mfu is not None:
-                self._m_decode_mfu.set(mfu)
-            for slot in np.flatnonzero(self._active):
-                req = self._slot_req[slot]
-                if forced[slot]:
-                    f = self._slot_forced[slot]
-                    m = min(int(valid[slot]), len(f))
-                    for _ in range(m):
-                        tok = f.popleft()
-                    self._pos[slot] += m
-                    self._last[slot] = tok
-                    continue
-                nprop = max(int(valid[slot]) - 1, 0)
-                m = int(n[slot])
-                self._m_spec_proposed.inc(nprop)
-                self._m_spec_accepted.inc(max(m - 1, 0))
-                fin, used = False, 0
-                for j in range(m):
-                    used += 1
-                    if self._emit(req, int(X[slot, j]), now):
-                        fin = True
-                        break
-                if fin:
-                    finished.append(req)
-                else:
-                    self._pos[slot] += used
-                    self._last[slot] = int(X[slot, used - 1])
-        self._update_gauges()
+            with self.phase("emit"):
+                for slot in np.flatnonzero(self._active):
+                    req = self._slot_req[slot]
+                    if forced[slot]:
+                        f = self._slot_forced[slot]
+                        m = min(int(valid[slot]), len(f))
+                        for _ in range(m):
+                            tok = f.popleft()
+                        self._pos[slot] += m
+                        self._last[slot] = tok
+                        continue
+                    nprop = max(int(valid[slot]) - 1, 0)
+                    m = int(n[slot])
+                    self._m_spec_proposed.inc(nprop)
+                    self._m_spec_accepted.inc(max(m - 1, 0))
+                    fin, used = False, 0
+                    for j in range(m):
+                        used += 1
+                        if self._emit(req, int(X[slot, j]), now):
+                            fin = True
+                            break
+                    if fin:
+                        finished.append(req)
+                    else:
+                        self._pos[slot] += used
+                        self._last[slot] = int(X[slot, used - 1])
+        self._close_step()
         return finished
 
     def import_prefix(self, payload: bytes) -> int:

@@ -160,7 +160,10 @@ class EngineLoop:
         if item is None:
             self._eof = True
             return
-        line, reply = item
+        with self.eng.phase("ingest"):
+            self._ingest_line(*item)
+
+    def _ingest_line(self, line, reply):
         if isinstance(line, str):
             if not line.strip():
                 return
@@ -259,6 +262,10 @@ class EngineLoop:
                                  "imported": int(n)}))
 
     def _finish(self, req):
+        with self.eng.phase("reply"):
+            self._reply(req)
+
+    def _reply(self, req):
         if req.rid in self._exports:
             reply, xid, prompt, trace = self._exports.pop(req.rid)
             payload = self.eng.export_prefix(prompt, trace=trace)

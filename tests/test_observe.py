@@ -232,6 +232,129 @@ class TestTraceScopes:
         assert s.get("hot").count == 1
 
 
+class _FakeAnnotation:
+    """Stands where ``jax.profiler``'s annotation classes stand."""
+
+    opened = []
+
+    def __init__(self, name, **kw):
+        self.opened.append((type(self).__name__, name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _FakeStepAnnotation(_FakeAnnotation):
+    pass
+
+
+class TestProfilerClock:
+    """Every scope is on the profiler's clock whenever jax is in the
+    process: no flag, no argument to switch it on (PR 24)."""
+
+    @pytest.fixture
+    def fakes(self, monkeypatch):
+        from paddle_tpu.observe import trace
+        monkeypatch.setattr(trace, "_annotations",
+                            (_FakeAnnotation, _FakeStepAnnotation))
+        monkeypatch.setattr(_FakeAnnotation, "opened", [])
+        return _FakeAnnotation.opened
+
+    @pytest.mark.parametrize("case", ["top_level_keeps_its_name",
+                                      "nested_keeps_its_leaf",
+                                      "step_scope", "switched_off",
+                                      "profile_flag_is_not_read"])
+    def test_annotation_opened(self, fakes, case):
+        s = stat.StatSet("t")
+        if case == "top_level_keeps_its_name":
+            with observe.trace_scope("engine/decode_sync", stats=s,
+                                     args={"step": 3}) as q:
+                assert q == "engine/decode_sync"
+            assert fakes == [("_FakeAnnotation", "engine/decode_sync",
+                              {"step": 3})]
+            span = observe.default_buffer().spans()[-1]
+            assert (span[0], span[4]) == ("engine/decode_sync",
+                                          {"step": 3})
+        elif case == "nested_keeps_its_leaf":
+            with observe.trace_scope("feed", stats=s):
+                with observe.trace_scope("convert", stats=s) as q:
+                    assert q == "feed/convert"
+            assert [f[1] for f in fakes] == ["feed", "convert"]
+        elif case == "step_scope":
+            with observe.step_scope(7, "train_step", stats=s):
+                pass
+            assert fakes == [("_FakeStepAnnotation", "train_step",
+                              {"step_num": 7})]
+        elif case == "switched_off":
+            with observe.trace_scope("quiet", stats=s, use_profiler=False):
+                pass
+            assert fakes == [] and s.get("quiet").count == 1
+        else:
+            from paddle_tpu.utils.flags import GLOBAL_FLAGS
+            assert not GLOBAL_FLAGS.get("profile")
+            with observe.trace_scope("hot", stats=s):
+                pass
+            assert [f[1] for f in fakes] == ["hot"]
+
+    @pytest.mark.parametrize("jax_present", [False, True])
+    def test_classes_resolved_once_and_only_with_jax(self, monkeypatch,
+                                                     jax_present):
+        import sys
+
+        from paddle_tpu.observe import trace
+        monkeypatch.setattr(trace, "_annotations", None)
+        if not jax_present:
+            monkeypatch.delitem(sys.modules, "jax")
+        s = stat.StatSet("t")
+        with observe.trace_scope("a", stats=s):
+            with observe.step_scope(0, "b", stats=s):
+                pass
+        assert s.get("a").count == 1 and s.get("a/b").count == 1
+        if jax_present:
+            import jax
+            assert trace._annotations == (
+                jax.profiler.TraceAnnotation,
+                jax.profiler.StepTraceAnnotation)
+        else:
+            # nothing imported, nothing resolved: asked again next time
+            assert trace._annotations is None
+            assert "jax" not in sys.modules
+
+
+class TestFeederScopes:
+    @pytest.mark.parametrize("path", ["per_sample", "prebatched"])
+    def test_stack_and_put_once_per_dense_slot(self, path):
+        from paddle_tpu import data_type as dt
+        from paddle_tpu.data_feeder import DataFeeder
+        from paddle_tpu.utils.stat import global_stats
+        feeder = DataFeeder({"a": dt.dense_vector(4),
+                             "b": dt.dense_vector(6),
+                             "y": dt.integer_value(3)})
+        a = np.arange(20, dtype=np.float32).reshape(5, 4)
+        b = np.ones((5, 6), np.float32)
+        y = np.array([0, 1, 2, 0, 1], np.int32)
+        batch = (a, b, y) if path == "prebatched" else \
+            [(a[i], b[i], int(y[i])) for i in range(5)]
+        before = {n: global_stats.get(f"feed/convert/{n}").count
+                  for n in ("stack", "put")}
+        with observe.trace_scope("feed"):
+            with observe.trace_scope("convert"):
+                feeds = feeder.feed(batch)
+        np.testing.assert_array_equal(np.asarray(feeds["a"].array), a)
+        np.testing.assert_array_equal(np.asarray(feeds["y"].array), y)
+        names = [s[0] for s in observe.default_buffer().spans()]
+        for n in ("stack", "put"):          # two dense slots, one index
+            assert names.count(f"feed/convert/{n}") == 2
+            assert global_stats.get(f"feed/convert/{n}").count \
+                == before[n] + 2
+        # each put follows its stack, both inside convert
+        assert names == ["feed/convert/stack", "feed/convert/put"] * 2 \
+            + ["feed/convert", "feed"]
+
+
 class TestStatFixes:
     def test_min_reported_and_empty_guarded(self):
         s = stat.Stat("op")

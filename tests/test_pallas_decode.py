@@ -364,8 +364,10 @@ class TestEnginePallas:
         eng.run_until_idle()
         mfu = eng.decode_mfu()
         assert mfu is not None and mfu > 0
-        assert eng.health().get("decode_mfu", 0) > 0
-        assert "engine_decode_mfu" in eng.metrics_text()
+        # the per-step gauge and the /healthz field went (PR 24): XLA's
+        # cost model over host time is no share of a chip
+        assert "decode_mfu" not in eng.health()
+        assert "engine_decode_mfu" not in eng.metrics_text()
 
 
 class TestNoSilentFallback:

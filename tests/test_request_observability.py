@@ -258,7 +258,7 @@ class TestTrainerBottleneck:
 # -- engine-side lifecycle tests (tiny transformer, CPU) -------------------
 
 def _paged_engine(batch=2, cache_len=64, block_size=8, chunk_tokens=8,
-                  **kw):
+                  d_model=16, n_layers=2, **kw):
     import jax
     import jax.numpy as jnp
 
@@ -266,8 +266,9 @@ def _paged_engine(batch=2, cache_len=64, block_size=8, chunk_tokens=8,
     from paddle_tpu.observe.compile_tracker import CompileTracker
     from paddle_tpu.serving import PagedDecodeEngine
     cfg = transformer.TransformerConfig(
-        vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
-        d_ff=32, max_len=cache_len, dtype=jnp.float32, use_rope=True)
+        vocab=40, d_model=d_model, n_heads=2, n_kv_heads=1,
+        n_layers=n_layers, d_ff=2 * d_model, max_len=cache_len,
+        dtype=jnp.float32, use_rope=True)
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     return PagedDecodeEngine.from_params(
         params, cfg, batch=batch, cache_len=cache_len,
@@ -433,19 +434,7 @@ class TestEngineLifecycle:
         assert eng.health().get("status") is None     # breach gone
 
     def test_slot_engine_lifecycle_joined_too(self, rng):
-        import jax
-        import jax.numpy as jnp
-
-        from paddle_tpu.models import transformer
-        from paddle_tpu.observe.compile_tracker import CompileTracker
-        from paddle_tpu.serving import DecodeEngine
-        cfg = transformer.TransformerConfig(
-            vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
-            d_ff=32, max_len=64, dtype=jnp.float32, use_rope=True)
-        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        eng = DecodeEngine.from_params(params, cfg, batch=2,
-                                       cache_len=32, buckets=(8, 16),
-                                       seed=0, tracker=CompileTracker())
+        eng = _slot_engine()
         r = eng.submit(rng.randint(0, 40, 6).astype(np.int32), max_new=3)
         eng.run_until_idle()
         evs = _lifecycle_events(r.trace_id)
@@ -458,6 +447,156 @@ class TestEngineLifecycle:
         assert rec["prefill_own_s"] > 0
         # monolithic prefill: stall is measurement slack, not a phase
         assert rec["prefill_stall_s"] < rec["ttft_s"]
+
+
+# -- the host's phases of an engine step (PR 24) ---------------------------
+
+PHASES = ("ingest", "schedule", "prefill_chunk", "decode_stage",
+          "decode_dispatch", "decode_sync", "emit", "reply")
+
+
+def _phase_seconds(eng):
+    return {p: eng.metrics.get(f"engine_{p}_seconds").snapshot()
+            for p in PHASES}
+
+
+@pytest.fixture(scope="module")
+def phase_run():
+    """One paged engine behind its replica loop, 12 requests on 4
+    slots: the phases as the registry and the span buffer saw them,
+    and the loop's wall time."""
+    from paddle_tpu.serving.replica import EngineLoop, ListReply
+    # wide enough that a step's programs, not the loop around the
+    # phases, take the time
+    eng = _paged_engine(batch=4, d_model=256, n_layers=8, chunk_tokens=32)
+    eng.precompile()
+    observe.reset()
+    r = np.random.RandomState(7)
+    loop, reply = EngineLoop(eng), ListReply()
+    for i in range(12):
+        loop.feed({"id": i, "max_new": 6 + i % 5,
+                   "prompt": r.randint(0, 40, 5 + i % 13).tolist()}, reply)
+    loop.feed_eof()
+    t0 = time.perf_counter()
+    loop.run()
+    wall = time.perf_counter() - t0
+    assert len(reply.docs) == 12
+    return {"eng": eng, "wall": wall, "seconds": _phase_seconds(eng),
+            "spans": [s for s in observe.default_buffer().spans()
+                      if s[5] == "X" and s[0].startswith("engine/")]}
+
+
+class TestEnginePhases:
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_phase_recorded_in_registry_and_span_buffer(self, phase_run,
+                                                        phase):
+        cell = phase_run["seconds"][phase]
+        spans = [s for s in phase_run["spans"]
+                 if s[0] == f"engine/{phase}"]
+        assert cell["count"] > 0 and cell["sum"] > 0
+        assert len(spans) == cell["count"]
+        # the registry's seconds are the span's, plus the scope's own
+        # exit: the same phase on two clocks
+        inner = sum(s[2] for s in spans)
+        assert inner <= cell["sum"] <= inner + 5e-4 * len(spans)
+        assert set(spans[0][4]) >= {"step", "active"}
+
+    def test_phases_cover_the_loop_and_none_encloses_another(self,
+                                                             phase_run):
+        named = sum(c["sum"] for c in phase_run["seconds"].values())
+        assert 0.9 * phase_run["wall"] <= named <= phase_run["wall"]
+        spans = sorted(phase_run["spans"], key=lambda s: s[1])
+        assert {s[0] for s in spans} == {f"engine/{p}" for p in PHASES}
+        assert len({s[3] for s in spans}) == 1      # one thread
+        for a, b in zip(spans, spans[1:]):          # siblings, in turn
+            assert a[1] + a[2] <= b[1] + 1e-4, (a[0], b[0])
+        eng = phase_run["eng"]
+        steps = eng.metrics.get("engine_decode_steps_total").value()
+        chunk = [s for s in spans if s[0] == "engine/prefill_chunk"]
+        assert len(chunk) == eng.metrics.get(
+            "engine_prefill_chunks_total").value()
+        assert chunk[0][4]["tokens"] >= 1 and chunk[0][4]["bucket"] >= 1
+        # decode_step_seconds keeps its meaning: stage + dispatch + sync
+        step_s = eng.metrics.get("engine_decode_step_seconds").snapshot()
+        three = sum(phase_run["seconds"][p]["sum"] for p in
+                    ("decode_stage", "decode_dispatch", "decode_sync"))
+        assert step_s["count"] == steps
+        assert step_s["sum"] == pytest.approx(three, rel=0.1)
+
+    @pytest.mark.parametrize("engine", ["paged", "slot", "spec"])
+    def test_every_engine_steps_through_the_phases(self, engine, rng):
+        if engine == "paged":
+            eng = _paged_engine()
+        elif engine == "slot":
+            eng = _slot_engine()
+        else:
+            eng = _spec_engine()
+        for n in (5, 9):
+            eng.submit(rng.randint(0, 40, n).astype(np.int32), max_new=4)
+        eng.run_until_idle()
+        got = {p for p, c in _phase_seconds(eng).items() if c["count"]}
+        assert got == set(PHASES) - {"ingest", "reply"}
+        steps = eng.metrics.get("engine_decode_steps_total").value()
+        for p in ("decode_stage", "decode_dispatch", "decode_sync",
+                  "emit"):
+            assert _phase_seconds(eng)[p]["count"] == steps
+
+    @pytest.mark.parametrize("stall_s,slow", [(1.2, 1), (0.0, 0)])
+    def test_slow_step_counted_once(self, rng, stall_s, slow):
+        """A decode step that completes more than a second after the
+        one before counts once; a run without one, and the waits with
+        no decoder in flight (before and after), count nothing."""
+        eng = _paged_engine()
+        decode, calls = eng._decode_fn, []
+
+        def stalling(*a):
+            calls.append(1)
+            if len(calls) == 3:
+                time.sleep(stall_s)
+            return decode(*a)
+
+        eng._decode_fn = stalling
+        eng.submit(rng.randint(0, 40, 5).astype(np.int32), max_new=6)
+        eng.run_until_idle()
+        time.sleep(0.01)
+        eng.submit(rng.randint(0, 40, 5).astype(np.int32), max_new=3)
+        eng.run_until_idle()
+        assert len(calls) >= 5
+        assert eng.metrics.get("engine_slow_steps_total").value() == slow
+        assert "engine_slow_steps_total" in eng.metrics_text()
+
+
+def _slot_engine():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import transformer
+    from paddle_tpu.observe.compile_tracker import CompileTracker
+    from paddle_tpu.serving import DecodeEngine
+    cfg = transformer.TransformerConfig(
+        vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
+        d_ff=32, max_len=64, dtype=jnp.float32, use_rope=True)
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    return DecodeEngine.from_params(params, cfg, batch=2, cache_len=64,
+                                    buckets=(8, 16), seed=0,
+                                    tracker=CompileTracker())
+
+
+def _spec_engine():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import transformer
+    from paddle_tpu.serving import SpecDecodeEngine
+    kw = dict(vocab=40, n_heads=2, n_kv_heads=1, d_ff=32, max_len=64,
+              dtype=jnp.float32, use_rope=True)
+    cfg = transformer.TransformerConfig(d_model=16, n_layers=2, **kw)
+    dcfg = transformer.TransformerConfig(d_model=8, n_layers=1, **kw)
+    return SpecDecodeEngine.from_params(
+        transformer.init_params(jax.random.PRNGKey(0), cfg), cfg,
+        transformer.init_params(jax.random.PRNGKey(1), dcfg), dcfg,
+        spec_k=2, batch=2, cache_len=64, block_size=8, chunk_tokens=8,
+        seed=0)
 
 
 class TestHealthStatusMapping:
