@@ -526,11 +526,26 @@ class LMServer:
         return HealthServer(registry=self.metrics, health_fn=self.health,
                             host=host, port=port)
 
-    def _engine_program(self, member: str):
-        """One of the engine's exported modules, deserialised."""
+    def _engine_program(self, member: str, donate_pool: bool = False):
+        """One of the engine's exported modules, deserialised.
+        ``donate_pool`` donates argument 1 (the KV pool) at the call,
+        so the program's pool writes land in the caller's buffer: the
+        paged decode and prefill programs, whose caller rebinds the
+        pool from every result."""
+        import jax
         import jax.export
         with _trace.trace_scope("artifact/programs"):
-            return jax.export.deserialize(self._engine_bins[member]).call
+            exported = jax.export.deserialize(self._engine_bins[member])
+        if not donate_pool:
+            return exported.call
+
+        # the name is the traced module's: a bare ``exported.call``
+        # runs as ``jit_call_exported`` and the benchmark's readers
+        # find the decode program by that name
+        def call_exported(*args):
+            return exported.call(*args)
+
+        return jax.jit(call_exported, donate_argnums=(1,))
 
     def engine(self, *, seed: Optional[int] = None, registry=None,
                tracker=None, chunk_tokens: Optional[int] = None,
@@ -587,8 +602,10 @@ class LMServer:
                     continue
                 b, pv = name[len("engine_prefill_paged_"):
                              -len(".bin")].split("_")
-                prefills[(int(b), int(pv))] = self._engine_program(name)
-            decode = self._engine_program("engine_decode_paged.bin")
+                prefills[(int(b), int(pv))] = self._engine_program(
+                    name, donate_pool=True)
+            decode = self._engine_program("engine_decode_paged.bin",
+                                          donate_pool=True)
 
             def prefill(params, pool, tokens, length, pagevec, *rest):
                 key = (tokens.shape[1], pagevec.shape[0])

@@ -581,6 +581,27 @@ def kv_rel_l2_budget(cfg: TransformerConfig, kv_dtype: str) -> float:
     return min(0.5, 2.0 * math.sqrt(2 * cfg.n_layers) * half_step)
 
 
+def _gather_pages(tab, groups, pages, block_size: int, num_blocks: int):
+    """Whole pages of one pool table, read where they lie. ``tab`` is
+    the table as flat rows ``[G * num_blocks * block_size, ...]`` (G =
+    layers x kv-heads: a bitcast of the head-major array), ``groups``
+    the (layer, head) row groups wanted (``layer * Hkv + head``) and
+    ``pages`` the block ids, shaped to broadcast against
+    ``groups[..., None]`` -> ``[*broadcast dims, P * block_size, ...]``.
+    Layer and head ride in the gather's index, so no layer slab is
+    sliced out of the pool and the pool keeps its layout (a gather on
+    the position axis of the 4-D array makes the TPU compiler re-lay
+    the WHOLE pool out first, position-major). Block ids are the
+    engine's own, always in range: ``clip`` is the gather's native
+    mode and spares the fill mask."""
+    bs = int(block_size)
+    idx = groups[..., None] * num_blocks + pages
+    g = jnp.take(tab.reshape((-1, bs) + tab.shape[1:]), idx, axis=0,
+                 mode="clip")
+    return g.reshape(idx.shape[:-1] + (idx.shape[-1] * bs,)
+                     + tab.shape[1:])
+
+
 def prefill(params, tokens: jax.Array, cfg: TransformerConfig,
             cache_len: int, *, mesh: Optional[Mesh] = None):
     """Batched prompt ingestion: the SAME traced block the training path
@@ -808,15 +829,30 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     a gathered logical view ``[B, T]`` (T = P·block_size) built from
     its page vector — every shape static, so the engine still compiles
     the decode step exactly ONCE for any paging. Row b writes its new
-    k/v at the physical index ``pages[b, pos[b]//bs]·bs + pos[b]%bs``
-    (the pool's position axis) via a scatter whose inactive rows target
-    an out-of-bounds index and are DROPPED (mode="drop") —
-    admission/recycling can't perturb in-flight neighbours, matching
-    ``decode_step_slots``'s inactive-row contract. The XLA path's
-    gathered view transposes back to the [B, T, Hkv, Dh] shape the
-    slot-major pool produced, so the attention arithmetic — and its
-    bitwise contract against ``decode_step_slots`` — is untouched by
-    the relayout.
+    k/v at the physical position ``pages[b, pos[b]//bs]·bs + pos[b]%bs``
+    via a scatter whose inactive rows target an out-of-bounds index and
+    are DROPPED (mode="drop") — admission/recycling can't perturb
+    in-flight neighbours, matching ``decode_step_slots``'s inactive-row
+    contract.
+
+    THE POOL IS UPDATED IN PLACE. It rides the layer loop as the CARRY
+    (never as scan ``xs``/``ys``: those are two buffers, and every
+    layer's slab would be sliced out, re-laid-out and update-sliced
+    back), each table viewed as rows ``[L·Hkv·M, ...]`` — a bitcast of
+    the head-major array for head widths of whole lane rows (Dh a
+    multiple of 128; narrower heads are stored position-minor by the
+    device and pay one re-layout in and out per call, around the loop,
+    not in it). Layer and head are folded into the row index, so the
+    scatter writes ``Hkv·B`` rows at ``(l·Hkv + h)·M + position`` into
+    the carried buffer and the read gathers whole pages at
+    ``(l·Hkv + h)·(M/bs) + page`` (``_gather_pages``): the loop touches
+    the rows it writes and the pages attention reads, nothing else.
+    A caller that DONATES the pool (the engine does, at ``jax.jit`` and
+    around the exported call) gets the writes in its own buffer; the
+    pool it passed is dead after the call. The gathered view
+    transposes to the ``[B, T, Hkv, Dh]`` shape the slot arena has, so
+    the attention arithmetic — and its bitwise contract against
+    ``decode_step_slots`` — is that of the slot path.
 
     For a slot whose pages tile a contiguous span (the identity mapping)
     the gathered view IS the old arena row, T equals the arena's
@@ -835,9 +871,12 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     gathered ``[B, T, Hkv, Dh]`` view or ``[B, H, T]`` score tensor in
     HBM, bitwise the XLA path's logits on aligned fp32 shapes (pinned
     in tests/test_pallas_decode.py). The pool WRITE of the step's new
-    k/v stays the same scatter on either engine. ``params`` may carry
-    int8 weights ({"q8","scale"} nodes): they ride the layer scan as
-    int8 xs and dequantize inside the body (``_live_layer_weights``
+    k/v stays the same scatter on either engine; the kernel's signature
+    takes ONE layer's tables, which are sliced out of the carry for it
+    (one slab read and written per table and layer — until the kernel
+    learns to take the whole pool and a layer index). ``params`` may
+    carry int8 weights ({"q8","scale"} nodes): they ride the layer scan
+    as int8 xs and dequantize inside the body (``_live_layer_weights``
     anti-hoist defenses), so serving reads weights at 1 byte/elt.
 
     QUANTIZED pools (``init_block_pool(kv_dtype="int8"/"int4")``,
@@ -855,10 +894,12 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     P = pages.shape[1]
     bs = int(block_size)
     T = P * bs
+    L = cfg.n_layers
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
     kvd = Hkv * Dh
     M = cache["k"].shape[2]
+    NB = M // bs                          # pages of one (layer, head)
     quantized = _blocks_quantized(params)
     kvq = pool_kv_dtype(cache, cfg)       # "none" | "int8" | "int4"
     mode = _pallas_policy.pallas_mode(pallas)
@@ -874,24 +915,33 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
         x = x + jnp.take(params["pos"], pos, axis=0).astype(cfg.dtype)
     rope_tabs = _rope_tables(pos, Dh, cfg.rope_theta) \
         if cfg.use_rope else None
-    # logical->physical index map per slot [B, T]: page-strided spans
-    gidx = (pages[:, :, None] * bs
-            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
-            ).reshape(B, T)
-    # physical write index per row; inactive rows aim out of bounds so
-    # the scatter drops them (the paged analog of the where()-write)
-    wpage = jnp.take_along_axis(pages, (pos // bs)[:, None],
-                                axis=1)[:, 0]
-    widx = jnp.where(active, wpage * bs + pos % bs, M)
+    # physical write position per row, within one (layer, head) span
+    wpos = jnp.take_along_axis(pages, (pos // bs)[:, None],
+                               axis=1)[:, 0] * bs + pos % bs
+    heads = jnp.arange(Hkv, dtype=jnp.int32)
     attend = (jnp.arange(T, dtype=jnp.int32)[None, :]
               <= pos[:, None])                           # [B, T] logical
 
-    def block(x, scanned):
-        if kvq != "none":
-            w, li, kc, vc, ksc, vsc = scanned  # + scales [Hkv, M]
-        else:
-            w, li, kc, vc = scanned            # kc/vc [Hkv, M, Dh]
-            ksc = vsc = None
+    def write(tab, rows, new):
+        """Scatter the step's rows ``new [B, Hkv, ...]`` into a flat
+        table at ``rows [Hkv * B]``. Layer and head are folded into
+        the row index so the scattered axis is the table's major one:
+        the compiler then updates the carried buffer in place."""
+        new = jnp.swapaxes(new, 0, 1)
+        return tab.at[rows].set(
+            new.reshape((Hkv * B,) + new.shape[2:]).astype(tab.dtype),
+            mode="drop")
+
+    def view(tab, groups):
+        """One layer's logical view ``[B, T, Hkv, ...]`` of a flat
+        table (``groups [Hkv]``: the layer's heads) — the only rows of
+        the pool the XLA attention reads."""
+        g = _gather_pages(tab, groups[:, None], pages, bs, NB)
+        return jnp.transpose(g, (1, 2, 0) + tuple(range(3, g.ndim)))
+
+    def block(carry, scanned):
+        x, pool = carry       # pool: flat tables [L*Hkv*M, ...], carried
+        w, li = scanned
         if quantized:
             w = _live_layer_weights(w, li)
         h = _layer_norm(x, w["ln1"], w["ln1_b"])
@@ -902,60 +952,47 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
                 B, H * Dh)
             k = _rope_rows(k.reshape(B, Hkv, Dh), rope_tabs).reshape(
                 B, kvd)
+        new = {"k": k.reshape(B, Hkv, Dh), "v": v.reshape(B, Hkv, Dh)}
         if kvq != "none":
             # write-time quantization: one scale per (row, head); the
-            # same scatter discipline drops inactive rows for values
-            # AND scales, so isolation holds for both tables (the
-            # head-major pool scatters on its position axis, values
-            # transposed to [Hkv, B, ...] — same values, new placement)
-            kq, ks_new = ops_q8.quantize_kv(k.reshape(B, Hkv, Dh), kvq)
-            vq, vs_new = ops_q8.quantize_kv(v.reshape(B, Hkv, Dh), kvq)
-            kc = kc.at[:, widx].set(jnp.swapaxes(kq, 0, 1),
-                                    mode="drop")
-            vc = vc.at[:, widx].set(jnp.swapaxes(vq, 0, 1),
-                                    mode="drop")
-            ksc = ksc.at[:, widx].set(jnp.swapaxes(ks_new, 0, 1),
-                                      mode="drop")
-            vsc = vsc.at[:, widx].set(jnp.swapaxes(vs_new, 0, 1),
-                                      mode="drop")
-        else:
-            kc = kc.at[:, widx].set(
-                jnp.swapaxes(k.reshape(B, Hkv, Dh), 0,
-                             1).astype(kc.dtype), mode="drop")
-            vc = vc.at[:, widx].set(
-                jnp.swapaxes(v.reshape(B, Hkv, Dh), 0,
-                             1).astype(vc.dtype), mode="drop")
+            # same scatter drops inactive rows for values AND scales,
+            # so isolation holds for both tables
+            new["k"], new["k_scale"] = ops_q8.quantize_kv(new["k"], kvq)
+            new["v"], new["v_scale"] = ops_q8.quantize_kv(new["v"], kvq)
+        # inactive rows aim past the table's end and are dropped (the
+        # paged analog of the where()-write)
+        groups = li * Hkv + heads                  # this layer's heads
+        rows = jnp.where(active[None, :],
+                         (groups * M)[:, None] + wpos[None, :],
+                         L * Hkv * M).reshape(Hkv * B)
+        pool = {n: write(pool[n], rows, new[n]) for n in pool}
         g = H // Hkv
         if use_pallas:
             # the kernel reads the just-written pool (pos attends to
             # itself) and resolves the page walk via scalar prefetch;
             # for quantized pools the dequant multiply runs in-register
-            # on the streamed blocks (int8/int4 HBM reads)
+            # on the streamed blocks (int8/int4 HBM reads). Its
+            # signature takes ONE layer's tables: a slice of the carry
+            slab = {n: jax.lax.dynamic_index_in_dim(
+                t.reshape((L, Hkv, M) + t.shape[1:]), li, 0,
+                keepdims=False) for n, t in pool.items()}
             attn = _pallas_decode.flash_decode_attention(
-                q.reshape(B, Hkv, g, Dh), kc, vc, pages, pos,
-                block_size=bs, k_scale=ksc, v_scale=vsc, kv_dtype=kvq,
+                q.reshape(B, Hkv, g, Dh), slab["k"], slab["v"], pages,
+                pos, block_size=bs, k_scale=slab.get("k_scale"),
+                v_scale=slab.get("v_scale"), kv_dtype=kvq,
                 interpret=(mode == "interpret"))
         else:
-            # gather on the pool's position axis, then transpose the
-            # logical view back to [B, T, Hkv, ...] — the exact shape
-            # (and values) the slot-major pool produced, so everything
-            # downstream is bitwise the pre-relayout path
+            # the barrier pins the widening beside its consumer: left
+            # free, the compiler hoists the fp32 convert up to the
+            # gather and the view crosses HBM twice more at 4 bytes/elt
+            seen = jax.lax.optimization_barrier(
+                {n: view(t, groups) for n, t in pool.items()})
             if kvq != "none":
-                kt = ops_q8.dequantize_kv(
-                    jnp.transpose(jnp.take(kc, gidx, axis=1),
-                                  (1, 2, 0, 3)),
-                    jnp.transpose(jnp.take(ksc, gidx, axis=1),
-                                  (1, 2, 0)), kvq)
-                vt = ops_q8.dequantize_kv(
-                    jnp.transpose(jnp.take(vc, gidx, axis=1),
-                                  (1, 2, 0, 3)),
-                    jnp.transpose(jnp.take(vsc, gidx, axis=1),
-                                  (1, 2, 0)), kvq)
+                kt = ops_q8.dequantize_kv(seen["k"], seen["k_scale"], kvq)
+                vt = ops_q8.dequantize_kv(seen["v"], seen["v_scale"], kvq)
             else:
-                kt = jnp.transpose(jnp.take(kc, gidx, axis=1),
-                                   (1, 2, 0, 3)).astype(jnp.float32)
-                vt = jnp.transpose(jnp.take(vc, gidx, axis=1),
-                                   (1, 2, 0, 3)).astype(jnp.float32)
+                kt = seen["k"].astype(jnp.float32)
+                vt = seen["v"].astype(jnp.float32)
             q32 = q.reshape(B, Hkv, g, Dh).astype(jnp.float32)
             s = jnp.einsum("bkgd,btkd->bkgt", q32, kt) / math.sqrt(Dh)
             s = jnp.where(attend[:, None, None, :], s, -1e30)
@@ -977,23 +1014,18 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
         else:
             ff = jax.nn.gelu(h2 @ w["mlp_in"].astype(h2.dtype))
             x = x + ff @ w["mlp_out"].astype(ff.dtype)
-        if kvq != "none":
-            return x, (kc, vc, ksc, vsc)
-        return x, (kc, vc)
+        return (x, pool), None
 
-    li = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    if kvq != "none":
-        x, (kn, vn, ksn, vsn) = jax.lax.scan(
-            block, x, (params["blocks"], li, cache["k"], cache["v"],
-                       cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": kn, "v": vn, "k_scale": ksn, "v_scale": vsn}
-    else:
-        x, (kn, vn) = jax.lax.scan(block, x, (params["blocks"], li,
-                                              cache["k"], cache["v"]))
-        new_cache = {"k": kn, "v": vn}
+    # every table of the pool as rows [L*Hkv*M, ...]: a bitcast of the
+    # head-major array (POOL_LAYOUT unchanged)
+    flat = {n: t.reshape((L * Hkv * M,) + t.shape[3:])
+            for n, t in cache.items()}
+    (x, flat), _ = jax.lax.scan(
+        block, (x, flat),
+        (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
     x = _layer_norm(x, params["ln_f"], params["ln_f_b"])
     logits = _vocab_logits(x, params)
-    return logits, new_cache
+    return logits, {n: t.reshape(cache[n].shape) for n, t in flat.items()}
 
 
 def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
@@ -1042,7 +1074,10 @@ def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
 
     Quantized pools and int8 {"q8","scale"} weight trees ride exactly
     as in ``decode_step_paged`` (write-time KV quantization with
-    mode="drop" on values AND scales, in-scan weight dequant)."""
+    mode="drop" on values AND scales, in-scan weight dequant). The
+    pool itself still rides the layer scan as ``xs``/``ys`` here (the
+    decode step carries it and updates it in place; ROADMAP Speed 4
+    lists this program as left): same values written and read."""
     from paddle_tpu.ops import q8 as ops_q8
     B, W = tokens.shape
     N = B * W
@@ -1072,7 +1107,8 @@ def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
                          axis=0).astype(cfg.dtype)
     rope_tabs = _rope_tables(gpos.reshape(N), Dh, cfg.rope_theta) \
         if cfg.use_rope else None
-    # logical->physical map per slot [B, T] (decode's gidx, unchanged)
+    # logical->physical map per slot [B, T]: the same rows the decode
+    # step gathers by whole pages
     gidx = (pages[:, :, None] * bs
             + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
             ).reshape(B, T)
@@ -1281,15 +1317,17 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
     else:
         # context gather (once, all layers): every context position is
         # real (ctx tokens were written by hits/earlier chunks), no
-        # mask needed. The head-major pool gathers on its position
-        # axis, then the view transposes back to the position-leading
-        # [L, S, Hkv, ...] shape the slot-major pool produced — same
-        # values, so the scan body below is bitwise the old path's
-        gidx = (pages[:P - pc, None] * bs
-                + jnp.arange(bs, dtype=jnp.int32)[None, :]).reshape(S)
+        # mask needed. Whole pages are gathered by (layer, head, page)
+        # off the flat rows, then the view transposes to the
+        # position-leading [L, S, Hkv, ...] shape the scan body reads
+        groups = jnp.arange(cfg.n_layers * Hkv, dtype=jnp.int32).reshape(
+            cfg.n_layers, Hkv)
 
         def _ctx(n):
-            g = jnp.take(cache[n], gidx, axis=2)   # [L, Hkv, S, ...]
+            tab = cache[n]
+            g = _gather_pages(
+                tab.reshape((-1,) + tab.shape[3:]), groups,
+                pages[:P - pc], bs, tab.shape[2] // bs)  # [L,Hkv,S,..]
             perm = (0, 2, 1) + tuple(range(3, g.ndim))
             return jnp.transpose(g, perm)          # [L, S, Hkv, ...]
 

@@ -33,16 +33,18 @@ def topology_device(topology: str = TOPOLOGY):
         platform="tpu", topology_name=topology).devices[0]
 
 
-def compile_for(device, fn, *args):
+def compile_for(device, fn, *args, donate_argnums=()):
     """``fn(*args)`` (abstract args) compiled for ``device`` by the
     real compiler; kernels resolve their VMEM budget against the
-    device's kind, not the host's."""
+    device's kind, not the host's. ``donate_argnums`` as the caller
+    of the real program donates (the paged engine: 1, the pool)."""
     sharding = jax.sharding.SingleDeviceSharding(device)
     placed = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                        sharding=sharding), args)
     with policy.compile_target(device.device_kind):
-        return jax.jit(fn).lower(*placed).compile()
+        return jax.jit(fn, donate_argnums=donate_argnums).lower(
+            *placed).compile()
 
 
 def engine_programs(cfg, *, batch: int, cache_len: int,
@@ -94,7 +96,9 @@ def compile_engine_programs(cfg, *, device=None, **geometry
     out = {}
     for name, (fn, args) in programs.items():
         t0 = time.perf_counter()
-        mem = compile_for(device, fn, *args).memory_analysis()
+        # the pool donated, as the engine calls them
+        mem = compile_for(device, fn, *args,
+                          donate_argnums=(1,)).memory_analysis()
         out[name] = {
             "kernel_paths": paths[name],
             "compile_s": round(time.perf_counter() - t0, 2),

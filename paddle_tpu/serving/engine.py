@@ -1232,12 +1232,15 @@ class PagedDecodeEngine(DecodeEngine):
             cfg, block_size, pallas=pallas)
         pool = transformer.init_block_pool(cfg, nb, block_size,
                                            kv_dtype=kv_dtype)
-        jdf = jax.jit(decode_fn)
+        # the pool (argument 1) is donated: both programs update it in
+        # place and the engine rebinds self.cache from every result
+        jdf = jax.jit(decode_fn, donate_argnums=(1,))
         if "decode_flops" not in kw:    # the trace is not free — skip
             pages = np.zeros((batch, cache_len // block_size), np.int32)
             kw["decode_flops"] = _decode_step_flops(
                 jdf, params, pool, batch, pages)
-        return cls(jax.jit(prefill_fn), jdf, params, pool,
+        return cls(jax.jit(prefill_fn, donate_argnums=(1,)), jdf, params,
+                   pool,
                    batch=batch, cache_len=cache_len,
                    block_size=block_size, num_blocks=nb,
                    chunk_tokens=chunk_tokens, chunk_buckets=chunk_buckets,
@@ -2360,7 +2363,7 @@ class SpecDecodeEngine(PagedDecodeEngine):
                                            kv_dtype=kv_dtype)
         draft_pool = transformer.init_block_pool(draft_cfg, nb,
                                                  block_size)
-        jdf = jax.jit(decode_fn)
+        jdf = jax.jit(decode_fn, donate_argnums=(1,))
         jvf = jax.jit(spec["verify"])
         if "decode_flops" not in kw:
             # MFU accounting numerator = ONE VERIFY ROUND's model FLOPs
@@ -2374,8 +2377,8 @@ class SpecDecodeEngine(PagedDecodeEngine):
                 np.zeros(batch, np.float32), np.zeros(batch, np.int32),
                 np.int32(0))
             kw["decode_flops"] = (cost or {}).get("flops")
-        return cls(jax.jit(prefill_fn), jdf, params, pool,
-                   draft_params=draft_params, draft_cache=draft_pool,
+        return cls(jax.jit(prefill_fn, donate_argnums=(1,)), jdf, params,
+                   pool, draft_params=draft_params, draft_cache=draft_pool,
                    draft_prefill=jax.jit(spec["draft_prefill"]),
                    propose=jax.jit(spec["propose"]), verify=jvf,
                    draft_verify=jax.jit(spec["draft_verify"]),

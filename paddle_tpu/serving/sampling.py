@@ -225,6 +225,15 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
               pages [B, P], temperature [B], top_k [B], seed ())
               → (tokens [B], pool)
 
+    Both programs update the pool IN PLACE when their caller donates
+    it (argument 1): decode carries it through its layer loop and
+    scatters the step's rows into it, prefill writes the chunk's spans
+    by ``dynamic_update_slice`` after its scan, and both read context
+    by gathering whole pages where they lie. The engine donates at
+    ``jax.jit(..., donate_argnums=(1,))`` and, for an artifact, around
+    ``Exported.call`` (``io/lm_serving``), and rebinds its pool from
+    every result: the pool passed in is dead after the call.
+
     The chunk's context length is implied by the SHAPES: the pages
     vector covers context + chunk, so each (chunk bucket, context
     pages) pair is its own compiled program. Sampling runs inside both:

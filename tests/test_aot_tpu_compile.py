@@ -124,14 +124,131 @@ ENGINE = aot.engine_programs(CFG, batch=B, cache_len=CACHE,
 def test_engine_program_compiles_with_every_kernel_placed(device,
                                                           program):
     """The program the engine would dispatch — kernels inside the layer
-    scan, the pool riding as scan xs, the sampler fused at the tail —
-    not just each kernel alone."""
+    loop, the pool riding it as the carry (decode) or read where it
+    lies (prefill), the sampler fused at the tail — not just each
+    kernel alone."""
     programs, paths = ENGINE
     fn, args = programs[program]
     mem = aot.compile_for(device, fn, *args).memory_analysis()
     assert set(paths[program].values()) == {"pallas"}, paths[program]
     # one v5e chip: 16 GB of HBM
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12e9
+
+
+def _pool_sized_results(text, pool):
+    """Per op kind, the instructions of the optimized program whose
+    result has the shape of a whole value table of the pool (k, v), of
+    its flat-row view or of one layer's slab of it: a round trip
+    through HBM of 1/L of the table or more (the scale tables of a
+    quantized pool are 1/Dh of that and not looked for). In-place
+    updates show as ``fusion`` (the scatter) or a bare
+    ``dynamic-update-slice`` over the aliased buffer and are not what
+    this looks for: a ``copy`` or a ``dynamic-slice`` (bare, or leading
+    a fusion's name) is."""
+    import math
+    import re
+    dt = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
+    shapes = set()
+    for t in (pool["k"], pool["v"]):
+        tag = dt[jnp.dtype(t.dtype).name]
+        for dims in (t.shape, (math.prod(t.shape[:3]),) + t.shape[3:],
+                     t.shape[1:], (1,) + t.shape[1:]):
+            shapes.add(f"{tag}[{','.join(map(str, dims))}]")
+    found = {}
+    fused = False           # inside a fusion's own computation: what
+    for line in text.splitlines():   # crosses HBM is the fusion's result
+        if line.endswith("{") and " = " not in line:
+            fused = "fused_computation" in line.split("(")[0]
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                     r"([\w\-]+)\(", line)
+        if fused or not m or m.group(2) not in shapes:
+            continue
+        name, _, op = m.groups()
+        for kind in ("copy", "dynamic-slice", "dynamic-update-slice"):
+            if op == kind or (op == "fusion" and kind in name
+                              and not (kind == "dynamic-slice"
+                                       and "update" in name)):
+                found.setdefault(kind, []).append(name)
+    return found
+
+
+def _pool_bytes(pool):
+    return sum(t.size * jnp.dtype(t.dtype).itemsize
+               for t in pool.values())
+
+
+# the same model with heads of one full lane width, as the benchmark's
+# 1.3B has: the device keeps a [.., M, 128] table row-major, so its
+# flat-row view is a bitcast. At the file's 64-wide heads the device
+# lays the pool out position-minor ({2,3,1,0}) and the view costs one
+# re-layout in and one out (below)
+CFG_DH128 = transformer.TransformerConfig(
+    vocab=32000, d_model=1024, n_heads=8, n_layers=6, d_ff=2048,
+    max_len=2048, dtype=jnp.bfloat16)
+
+
+def _paged_programs(cfg, pallas, kv=None):
+    from paddle_tpu.serving import sampling
+    programs, _ = aot.engine_programs(
+        cfg, batch=B, cache_len=CACHE, block_size=BS,
+        chunk_tokens=CHUNK, kv_dtype=kv)
+    prefill_fn, decode_fn = sampling.paged_step_fns(cfg, BS,
+                                                    pallas=pallas)
+    return programs, prefill_fn, decode_fn
+
+
+@pytest.mark.parametrize("pallas", ["off", "on"])
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["bf16", "int8"])
+def test_decode_program_updates_the_donated_pool_in_place(device, kv,
+                                                          pallas):
+    """The guard against the pool going back onto the layer scan's
+    ``xs``/``ys``: called with the pool donated, the compiled decode
+    program aliases the whole pool to its output and holds no op that
+    copies, slices out or update-slices a pool-sized or slab-sized
+    array. With the kernels placed, the kernel's signature takes one
+    layer's tables, so exactly its two operands (k and v) are sliced
+    out of the carry, once each, and nothing else is."""
+    programs, _, decode_fn = _paged_programs(CFG_DH128, pallas, kv)
+    pool = programs["decode"][1][1]
+    compiled = aot.compile_for(
+        device, decode_fn, *programs["decode"][1], donate_argnums=(1,))
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= _pool_bytes(pool)
+    found = _pool_sized_results(compiled.as_text(), pool)
+    sliced = found.pop("dynamic-slice", [])
+    assert len(sliced) == (2 if pallas == "on" else 0), sliced
+    assert not found, found
+
+
+def test_decode_program_at_64_wide_heads_relays_the_pool_out_once(device):
+    """What the file's own geometry shows: 64 is half a lane row, the
+    device stores the pool position-minor, and the decode program pays
+    one re-layout of each table into rows and one back — around the
+    layer loop, not in it. Nothing slab-sized moves, and the pool is
+    still aliased."""
+    programs, _, decode_fn = _paged_programs(CFG, "off")
+    pool = programs["decode"][1][1]
+    compiled = aot.compile_for(
+        device, decode_fn, *programs["decode"][1], donate_argnums=(1,))
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= _pool_bytes(pool)
+    found = _pool_sized_results(compiled.as_text(), pool)
+    assert len(found.pop("copy")) == 4      # k and v, in and out
+    assert not found, found
+
+
+def test_prefill_program_updates_the_donated_pool_in_place(device):
+    """The same aliasing for a paged prefill chunk with context: its
+    span writes land in the donated pool, and the context is gathered
+    off the pool as it lies — no copy of the pool, donated or not."""
+    programs, prefill_fn, _ = _paged_programs(CFG_DH128, "off")
+    args = programs[f"prefill_{CHUNK}_{CACHE // BS}"][1]
+    compiled = aot.compile_for(device, prefill_fn, *args,
+                               donate_argnums=(1,))
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= _pool_bytes(args[1])
+    assert "copy" not in _pool_sized_results(compiled.as_text(), args[1])
 
 
 def test_training_flash_attention_compiles_fwd_and_bwd(device):

@@ -452,3 +452,200 @@ class TestPagedEngineScheduling:
         h = eng.health()
         assert h["blocks_total"] == eng.pool.num_blocks
         assert h["blocks_in_use"] == 0 and h["block_size"] == 8
+
+
+def _reference_decode_step_paged(params, cache, tokens, pos, active,
+                                 pages, cfg, *, block_size):
+    """The decode step as it was spelt before the pool went in place,
+    kept here as the plain reference: the pool rides the layer scan as
+    ``xs``/``ys`` (each layer's slab sliced out, written by a scatter
+    on its position axis, read by a gather on the same axis, stacked
+    back), XLA attention, dense FFN."""
+    import math
+
+    from paddle_tpu.ops import q8 as ops_q8
+    T = transformer
+    B, P = pages.shape
+    bs = int(block_size)
+    Tl = P * bs
+    H, Dh, Hkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    kvd = Hkv * Dh
+    M = cache["k"].shape[2]
+    kvq = T.pool_kv_dtype(cache, cfg)
+    x = T._embed_rows(params, tokens, cfg)
+    if not cfg.use_rope:
+        x = x + jnp.take(params["pos"], pos, axis=0).astype(cfg.dtype)
+    rope_tabs = T._rope_tables(pos, Dh, cfg.rope_theta) \
+        if cfg.use_rope else None
+    gidx = (pages[:, :, None] * bs
+            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
+            ).reshape(B, Tl)
+    wpage = jnp.take_along_axis(pages, (pos // bs)[:, None],
+                                axis=1)[:, 0]
+    widx = jnp.where(active, wpage * bs + pos % bs, M)
+    attend = jnp.arange(Tl, dtype=jnp.int32)[None, :] <= pos[:, None]
+    names = tuple(n for n in ("k", "v", "k_scale", "v_scale")
+                  if n in cache)
+
+    def block(x, scanned):
+        w, tabs = scanned[0], dict(zip(names, scanned[1:]))
+        h = T._layer_norm(x, w["ln1"], w["ln1_b"])
+        qkv = h @ w["qkv"].astype(h.dtype)
+        q, k, v = jnp.split(qkv, [H * Dh, H * Dh + kvd], axis=-1)
+        if cfg.use_rope:
+            q = T._rope_rows(q.reshape(B, H, Dh), rope_tabs).reshape(
+                B, H * Dh)
+            k = T._rope_rows(k.reshape(B, Hkv, Dh), rope_tabs).reshape(
+                B, kvd)
+        new = {"k": k.reshape(B, Hkv, Dh), "v": v.reshape(B, Hkv, Dh)}
+        if kvq != "none":
+            new["k"], new["k_scale"] = ops_q8.quantize_kv(new["k"], kvq)
+            new["v"], new["v_scale"] = ops_q8.quantize_kv(new["v"], kvq)
+        tabs = {n: t.at[:, widx].set(
+            jnp.swapaxes(new[n], 0, 1).astype(t.dtype), mode="drop")
+            for n, t in tabs.items()}
+        seen = {n: jnp.transpose(
+            jnp.take(t, gidx, axis=1),
+            (1, 2, 0) + tuple(range(3, t.ndim + 1)))
+            for n, t in tabs.items()}            # [B, T, Hkv, ...]
+        if kvq != "none":
+            kt = ops_q8.dequantize_kv(seen["k"], seen["k_scale"], kvq)
+            vt = ops_q8.dequantize_kv(seen["v"], seen["v_scale"], kvq)
+        else:
+            kt = seen["k"].astype(jnp.float32)
+            vt = seen["v"].astype(jnp.float32)
+        q32 = q.reshape(B, Hkv, H // Hkv, Dh).astype(jnp.float32)
+        s = jnp.einsum("bkgd,btkd->bkgt", q32, kt) / math.sqrt(Dh)
+        s = jnp.where(attend[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        attn = jnp.einsum("bkgt,btkd->bkgd", p, vt)
+        attn = attn.reshape(B, cfg.d_model).astype(cfg.dtype)
+        x = x + attn @ w["attn_out"].astype(attn.dtype)
+        h2 = T._layer_norm(x, w["ln2"], w["ln2_b"])
+        ff = jax.nn.gelu(h2 @ w["mlp_in"].astype(h2.dtype))
+        x = x + ff @ w["mlp_out"].astype(ff.dtype)
+        return x, tuple(tabs[n] for n in names)
+
+    x, out = jax.lax.scan(
+        block, x, (params["blocks"],) + tuple(cache[n] for n in names))
+    x = T._layer_norm(x, params["ln_f"], params["ln_f_b"])
+    return T._vocab_logits(x, params), dict(zip(names, out))
+
+
+CFG_BF16 = transformer.TransformerConfig(
+    vocab=40, d_model=32, n_heads=4, n_kv_heads=2, n_layers=3, d_ff=64,
+    max_len=64, dtype=jnp.bfloat16, use_rope=False)
+
+
+def _random_pool(cfg, num_blocks, kv_dtype, rng):
+    """A pool whose every row holds something: what a step must leave
+    alone shows if it does not."""
+    pool = transformer.init_block_pool(cfg, num_blocks, BS,
+                                       kv_dtype=kv_dtype)
+    out = {}
+    for n, t in pool.items():
+        if t.dtype == jnp.int8:
+            a = rng.randint(-127, 128, t.shape)
+        elif n.endswith("_scale"):
+            a = rng.uniform(0.001, 0.02, t.shape)
+        else:
+            a = rng.standard_normal(t.shape)
+        out[n] = jnp.asarray(a, t.dtype)
+    return out
+
+
+class TestPoolInPlace:
+    """The decode program carries the pool through its layer loop and
+    is called with the pool donated: same values as the xs/ys spelling,
+    and no caller is left holding a donated pool."""
+
+    @pytest.mark.parametrize("paging", ["identity", "shuffled"])
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                             ids=["bf16", "int8"])
+    def test_in_place_decode_bitwise_matches_xs_ys_reference(
+            self, kv_dtype, paging, rng):
+        cfg = CFG_BF16
+        params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+        B, P = 3, 4
+        pool = _random_pool(cfg, B * P, kv_dtype, rng)
+        ids = np.arange(B * P, dtype=np.int32)
+        if paging == "shuffled":
+            ids = rng.permutation(ids).astype(np.int32)
+        pages = jnp.asarray(ids.reshape(B, P))
+        tok = jnp.asarray(rng.randint(0, 40, B), jnp.int32)
+        pos = jnp.asarray([9, 4, 17], jnp.int32)
+        active = jnp.asarray([True, False, True])
+        want_l, want_pool = jax.jit(
+            lambda p, c: _reference_decode_step_paged(
+                p, c, tok, pos, active, pages, cfg, block_size=BS))(
+            params, pool)
+        donated = {n: jnp.copy(t) for n, t in pool.items()}
+        got_l, got_pool = jax.jit(
+            lambda p, c: transformer.decode_step_paged(
+                p, c, tok, pos, active, pages, cfg, block_size=BS,
+                pallas="off"), donate_argnums=(1,))(params, donated)
+        assert all(t.is_deleted() for t in donated.values())
+        np.testing.assert_array_equal(np.asarray(want_l),
+                                      np.asarray(got_l))
+        assert sorted(got_pool) == sorted(pool)
+        for n in pool:
+            assert got_pool[n].shape == pool[n].shape
+            assert got_pool[n].dtype == pool[n].dtype
+            np.testing.assert_array_equal(np.asarray(want_pool[n]),
+                                          np.asarray(got_pool[n]))
+        # the inactive row wrote nothing; the active rows wrote theirs
+        before, after = np.asarray(pool["k"]), np.asarray(got_pool["k"])
+        changed = np.flatnonzero((before != after).any(axis=(0, 1, 3)))
+        rows = [int(ids[b * P + p // BS]) * BS + p % BS
+                for b, p in ((0, 9), (2, 17))]
+        assert sorted(changed) == sorted(rows)
+
+    @pytest.mark.parametrize("resume", ["remap", "replay"])
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                             ids=["fp32", "int8"])
+    def test_engine_never_touches_a_donated_pool(self, kv_dtype, resume,
+                                                 rng):
+        """Over a preempt/resume schedule every program call consumes
+        the pool it is handed: the engine's pool is live after every
+        step, the one it held before is gone, and the victim's output
+        is the unpreempted run's (a read of a donated pool raises)."""
+        def engine():
+            return PagedDecodeEngine.from_params(
+                PARAMS, CFG, batch=2, cache_len=32, block_size=BS,
+                chunk_tokens=8, num_blocks=4, seed=0,
+                kv_dtype=kv_dtype, tracker=CompileTracker())
+
+        prompt = rng.randint(0, 40, 8).astype(np.int32)
+        solo = engine()
+        ref = solo.submit(prompt, max_new=16)
+        solo.run_until_idle()
+        eng = engine()
+        eng.precompile()
+        v = eng.submit(prompt, max_new=16, tier="batch")
+        consumed = 0
+
+        def step():
+            nonlocal consumed
+            held = dict(eng.cache)
+            eng.step()
+            assert not any(t.is_deleted() for t in eng.cache.values())
+            if eng.cache["k"] is not held["k"]:
+                consumed += 1
+                assert all(t.is_deleted() for t in held.values())
+
+        for _ in range(6):
+            step()
+        # remap: the victim's parked blocks survive; replay: the
+        # adversary's worst case is the whole pool and evicts them
+        n = 8 if resume == "remap" else 16
+        lat = eng.submit(rng.randint(0, 40, n).astype(np.int32),
+                         max_new=n, tier="latency")
+        step()
+        assert v.status == "preempted"
+        while not eng.idle:
+            step()
+        assert consumed >= 16
+        assert lat.finish_reason == "max_tokens"
+        assert list(v.tokens) == list(ref.tokens)
+        assert int(eng.metrics.get("engine_resumes_total").value(
+            mode=resume)) == 1
