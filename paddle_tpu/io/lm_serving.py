@@ -15,6 +15,7 @@ or temperature sampling happens host-side between compiled calls.
 import dataclasses
 import io as _io
 import json
+import os
 import tarfile
 import time
 from typing import Optional, Sequence
@@ -192,6 +193,19 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         raise ValueError(f"draft vocab {engine_draft_config.vocab} != "
                          f"target vocab {cfg.vocab}")
 
+    hybrid = cfg.skeleton == "gated_hybrid"
+    if hybrid:
+        # the skeleton runs through the paged engine's programs alone
+        # (bf16 weight leaves, the model's own KV width); the lockstep
+        # pair and the slot engine are not exported for it
+        for on, what in ((weights_int8, "int8 weights"),
+                         (engine_kv_dtype, "an int8 / int4 KV pool"),
+                         (engine_draft_params is not None,
+                          "speculative decoding"),
+                         (not engine_paged, "an artifact without "
+                          "engine_paged=True")):
+            if on:
+                transformer.require_gpt2(cfg, what)
     if weights_int8:
         params = quantize_lm_params(params)
 
@@ -215,15 +229,19 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         params)
     toks = jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)
     jit_prefill, jit_decode = jax.jit(prefill_fn), jax.jit(decode_fn)
-    exp_prefill = jax.export.export(jit_prefill, **kw)(
-        p_shapes, toks)
-    cache_shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-        transformer.init_cache(cfg, batch, cache_len))
-    decode_args = (p_shapes, cache_shapes,
-                   jax.ShapeDtypeStruct((batch,), jnp.int32),
-                   jax.ShapeDtypeStruct((), jnp.int32))
-    exp_decode = jax.export.export(jit_decode, **kw)(*decode_args)
+    lockstep = {}
+    if not hybrid:
+        exp_prefill = jax.export.export(jit_prefill, **kw)(
+            p_shapes, toks)
+        cache_shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            transformer.init_cache(cfg, batch, cache_len))
+        decode_args = (p_shapes, cache_shapes,
+                       jax.ShapeDtypeStruct((batch,), jnp.int32),
+                       jax.ShapeDtypeStruct((), jnp.int32))
+        exp_decode = jax.export.export(jit_decode, **kw)(*decode_args)
+        lockstep = {"prefill.bin": exp_prefill.serialize(),
+                    "decode.bin": exp_decode.serialize()}
 
     # format-v3 engine programs: slot prefill per bucket + one vector-
     # position decode step with the sampler fused in (token ids are the
@@ -298,7 +316,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
             pool_shapes = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                 transformer.init_block_pool(
-                    cfg, nb, bs, kv_dtype=engine_kv_dtype))
+                    cfg, nb, bs, kv_dtype=engine_kv_dtype, slots=batch))
             # one chunk-prefill module per (bucket, context span) the
             # fixed chunk grid can reach: a chunk's context length is
             # encoded in its page-vector SHAPE (span specialization —
@@ -311,6 +329,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                         p_shapes, pool_shapes,
                         jax.ShapeDtypeStruct((1, b), jnp.int32), i32,
                         jax.ShapeDtypeStruct((pv,), jnp.int32),
+                        *((i32,) if hybrid else ()),    # the slot
                         f32, i32, i32)
                     engine_members[
                         f"engine_prefill_paged_{b}_{pv}.bin"] = \
@@ -392,8 +411,9 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     # time (the loader has no model code to re-derive it from): the MFU
     # denominator's numerator for any host that serves this file
     cost_analysis = {}
-    phases = [("prefill", jit_prefill, (p_shapes, toks)),
-              ("decode", jit_decode, decode_args)]
+    phases = [] if hybrid else [
+        ("prefill", jit_prefill, (p_shapes, toks)),
+        ("decode", jit_decode, decode_args)]
     if engine_buckets:
         phases.append(("engine_decode", jit_eng_decode, eng_decode_args))
     if engine_draft_params is not None:
@@ -430,16 +450,24 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         dbuf = _io.BytesIO()
         np.savez(dbuf, **_flatten(engine_draft_params))
         draft_blob = dbuf.getvalue()
-    flat = _flatten(params)
-    buf = _io.BytesIO()
-    np.savez(buf, **flat)
     with tarfile.open(path, "w") as tar:
         _add(tar, "meta.json", json.dumps(meta).encode())
-        _add(tar, "params.npz", buf.getvalue())
+        # through a file beside the artifact, not through memory: an
+        # .npz built in a BytesIO and handed on as bytes holds the
+        # weights four times over (the leaves, the buffer, its value,
+        # the member's stream), which a 7 GB model does not survive
+        part = path + ".params.part"
+        try:
+            with open(part, "wb") as f:
+                np.savez(f, **_npz_leaves(_flatten(params)))
+            tar.add(part, arcname="params.npz")
+        finally:
+            if os.path.exists(part):
+                os.remove(part)
         if draft_blob is not None:
             _add(tar, "draft_params.npz", draft_blob)
-        _add(tar, "prefill.bin", exp_prefill.serialize())
-        _add(tar, "decode.bin", exp_decode.serialize())
+        for name, blob in lockstep.items():
+            _add(tar, name, blob)
         for name, blob in engine_members.items():
             _add(tar, name, blob)
 
@@ -472,8 +500,10 @@ class LMServer:
         self.params = params
         # v5: the stamped speculative-decoding draft (None below v5)
         self.draft_params = draft_params
-        self._prefill = jax.export.deserialize(prefill_bin)
-        self._decode = jax.export.deserialize(decode_bin)
+        # the lockstep pair (absent from a gated_hybrid artifact, which
+        # serves through engine() alone)
+        self._prefill = prefill_bin and jax.export.deserialize(prefill_bin)
+        self._decode = decode_bin and jax.export.deserialize(decode_bin)
         # format-v3 continuous-batching modules (absent on v1/v2):
         # deserialized lazily by engine() — lockstep-only consumers of a
         # v3 artifact pay nothing for them
@@ -623,7 +653,7 @@ class LMServer:
                 kvd = None
             pool = transformer.init_block_pool(
                 cfg, paged["num_blocks"], paged["block_size"],
-                kv_dtype=kvd)
+                kv_dtype=kvd, slots=self.meta["batch"])
             eng_kw = dict(
                 batch=self.meta["batch"],
                 cache_len=self.meta["cache_len"],
@@ -720,6 +750,10 @@ class LMServer:
         the decode loop early once EVERY row has emitted it (rows that
         finish first keep emitting ``eos_id`` as padding), so the result
         is ``[B, prompt_len + n]`` with ``n <= max_new``."""
+        if self._prefill is None:
+            from paddle_tpu.models import transformer
+            transformer.require_gpt2(self.cfg, "LMServer.generate (the "
+                                     "lockstep path)")
         import jax.numpy as jnp
         if max_new < 1:
             raise ValueError(f"generate: max_new must be >= 1, "
@@ -788,32 +822,56 @@ class LMServer:
                                np.stack(toks, axis=1)], axis=1)
 
 
-def _load_params(blob: bytes):
-    with np.load(_io.BytesIO(blob), allow_pickle=False) as z:
-        return _unflatten({k: z[k] for k in z.files})
+_BF16 = "@bfloat16"     # numpy's .npy format has no bfloat16: such a
+#                         leaf is stored as its uint16 bits under a
+#                         marked key
+
+
+def _npz_leaves(flat: dict) -> dict:
+    return {(k + _BF16 if v.dtype.name == "bfloat16" else k):
+            (v.view(np.uint16) if v.dtype.name == "bfloat16" else v)
+            for k, v in flat.items()}
+
+
+def _load_params(f):
+    """``f``: the ``.npz`` as a seekable file object (a member of the
+    open tar: decoded where it lies, never read whole beside its own
+    arrays)."""
+    import ml_dtypes
+    with np.load(f, allow_pickle=False) as z:
+        return _unflatten({
+            (k[:-len(_BF16)] if k.endswith(_BF16) else k):
+            (z[k].view(ml_dtypes.bfloat16) if k.endswith(_BF16) else z[k])
+            for k in z.files})
 
 
 def load_lm_artifact(path: str) -> LMServer:
     """Set-up spans (``utils.stat.global_stats``): ``artifact/read`` the
-    tar's members, ``artifact/params`` the ``.npz`` files decoded,
+    tar's index, ``meta.json`` and the exported modules' bytes,
+    ``artifact/params`` the ``.npz`` members read out of the open tar
+    and decoded (one pass: the weights are never held twice),
     ``artifact/programs`` every exported module deserialised (here the
     lockstep pair, the engine's in :meth:`LMServer.engine`)."""
-    with _trace.trace_scope("artifact/read"):
-        with tarfile.open(path, "r") as tar:
+    with tarfile.open(path, "r") as tar:
+        with _trace.trace_scope("artifact/read"):
+            npz = {m.name: m for m in tar.getmembers()
+                   if m.name.endswith(".npz")}
             members = {m.name: tar.extractfile(m).read()
-                       for m in tar.getmembers()}
-    meta = json.loads(members["meta.json"])
-    if meta["format_version"] > FORMAT_VERSION:
-        raise ValueError(f"artifact format {meta['format_version']} newer "
-                         f"than this loader ({FORMAT_VERSION})")
-    with _trace.trace_scope("artifact/params"):
-        params = _load_params(members["params.npz"])
-        draft_params = None
-        if "draft_params.npz" in members:
-            draft_params = _load_params(members["draft_params.npz"])
+                       for m in tar.getmembers() if m.name not in npz}
+        meta = json.loads(members["meta.json"])
+        if meta["format_version"] > FORMAT_VERSION:
+            raise ValueError(
+                f"artifact format {meta['format_version']} newer "
+                f"than this loader ({FORMAT_VERSION})")
+        with _trace.trace_scope("artifact/params"):
+            params = _load_params(tar.extractfile(npz["params.npz"]))
+            draft_params = None
+            if "draft_params.npz" in npz:
+                draft_params = _load_params(
+                    tar.extractfile(npz["draft_params.npz"]))
     engine_bins = {k: v for k, v in members.items()
                    if k.startswith("engine_")}
     with _trace.trace_scope("artifact/programs"):
-        return LMServer(meta, params, members["prefill.bin"],
-                        members["decode.bin"], engine_bins=engine_bins,
+        return LMServer(meta, params, members.get("prefill.bin"),
+                        members.get("decode.bin"), engine_bins=engine_bins,
                         draft_params=draft_params)

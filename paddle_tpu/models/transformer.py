@@ -61,8 +61,45 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01       # load-balance loss weight (added
                                        # to lm_loss per layer)
+    # -- the gated hybrid skeleton (models/gated_hybrid.py) ---------------
+    skeleton: str = "gpt2"             # "gpt2": LayerNorm + GELU block
+                                       # below. "gated_hybrid": zero-
+                                       # centred RMSNorm, bias-free maps,
+                                       # untied head; periods of
+                                       # ``full_attn_interval - 1`` gated
+                                       # delta-rule layers and one gated
+                                       # full-attention layer; every FFN a
+                                       # dropless expert layer (d_ff is the
+                                       # expert width) plus a shared expert
+    attn_head_dim: int = 0             # 0 = d_model // n_heads
+    rotary_dim: int = 0                # leading dims of a head that
+                                       # rotate; 0 = the whole head
+    norm_eps: float = 1e-6
+    full_attn_interval: int = 4
+    rec_key_heads: int = 0             # delta-rule layers: key heads,
+    rec_value_heads: int = 0           # value heads (a multiple),
+    rec_key_dim: int = 0               # their head widths and the
+    rec_value_dim: int = 0             # causal convolution's kernel
+    rec_conv: int = 4
+    moe_held: tuple = ()               # (first, count): the experts this
+                                       # chip holds of moe_experts (expert
+                                       # parallelism's share); () = all
+    moe_shared_ff: int = 0             # width of the shared expert
 
     def __post_init__(self):
+        object.__setattr__(self, "moe_held",
+                           tuple(int(i) for i in self.moe_held))
+        if self.skeleton not in ("gpt2", "gated_hybrid"):
+            raise ValueError(f"skeleton must be 'gpt2' or 'gated_hybrid',"
+                             f" got {self.skeleton!r}")
+        if self.skeleton == "gated_hybrid":
+            from paddle_tpu.models import gated_hybrid
+            gated_hybrid.check_config(self)
+        elif self.attn_head_dim or self.rotary_dim or self.moe_held \
+                or self.moe_shared_ff:
+            raise ValueError("attn_head_dim, rotary_dim, moe_held and "
+                             "moe_shared_ff belong to skeleton="
+                             "'gated_hybrid'")
         if self.cp_mode not in ("ring", "alltoall"):
             raise ValueError(
                 f"cp_mode must be 'ring' or 'alltoall', got "
@@ -83,7 +120,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def kv_heads(self):
@@ -98,6 +135,9 @@ class TransformerConfig:
 
 def init_params(key: jax.Array, cfg: TransformerConfig):
     """Parameter pytree; block weights stacked on axis 0 (scan layout)."""
+    if cfg.skeleton == "gated_hybrid":
+        from paddle_tpu.models import gated_hybrid
+        return gated_hybrid.init_params(key, cfg)
     k = jax.random.split(key, 8)
     D, F, L, V = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab
     kvd = cfg.kv_heads * cfg.head_dim     # == D for MHA; smaller for GQA
@@ -147,6 +187,7 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh):
     MoE experts sharded over ``expert``. An axis the mesh doesn't carry
     degrades to replication, so the same layout serves DP-only,
     DPxTP and DPxEP meshes."""
+    require_gpt2(cfg, "param_shardings (a sharded mesh)")
     M = place.AXIS_MODEL if place.AXIS_MODEL in mesh.axis_names else None
 
     def ns(*spec):
@@ -174,6 +215,16 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh):
         },
         "ln_f": ns(), "ln_f_b": ns(),
     }
+
+
+def require_gpt2(cfg, what: str):
+    """THE check of everything ``skeleton="gated_hybrid"`` does not
+    run (``models/gated_hybrid.refuse`` says what is supported): every
+    entry point below that computes on the GPT-2 block calls it first,
+    so nothing computes silently on the wrong block."""
+    if cfg.skeleton == "gated_hybrid":
+        from paddle_tpu.models import gated_hybrid
+        gated_hybrid.refuse(what)
 
 
 def _layer_norm(x, g, b):
@@ -305,6 +356,13 @@ def forward(params, tokens: jax.Array, cfg: TransformerConfig, *,
     ``return_aux=True`` additionally returns the summed MoE
     load-balance loss (zero for dense configs) — lm_loss adds it.
     """
+    if cfg.skeleton == "gated_hybrid":
+        from paddle_tpu.models import gated_hybrid
+        if mesh is not None or return_kv or return_aux \
+                or dropout_key is not None:
+            gated_hybrid.refuse("forward(mesh=, return_kv=, return_aux=, "
+                                "dropout_key=)")
+        return gated_hybrid.forward(params, tokens, cfg, lengths=lengths)
     return _forward_impl(params, tokens, cfg, mesh, lengths, return_kv,
                          head="all", dropout_key=dropout_key,
                          return_aux=return_aux)
@@ -471,6 +529,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     [L, B, max_len, kv_heads, Dh] (kv_heads < n_heads under GQA)
     (the serving-side analog of the reference's recurrent generation
     machinery, trainer/tests/test_recurrent_machine_generation.cpp slot)."""
+    require_gpt2(cfg, "init_cache (the row-arena KV cache)")
     shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -490,7 +549,8 @@ from paddle_tpu.ops.pallas.decode import POOL_LAYOUT  # noqa: E402
 
 
 def init_block_pool(cfg: TransformerConfig, num_blocks: int,
-                    block_size: int, kv_dtype: Optional[str] = None):
+                    block_size: int, kv_dtype: Optional[str] = None,
+                    slots: Optional[int] = None):
     """Paged KV pool for the block-table decode engine, HEAD-MAJOR:
     [L, kv_heads, num_blocks * block_size, Dh] per k/v — the standard
     TPU paged-KV layout (kv-head leading). Block ``i`` owns the aligned
@@ -513,6 +573,17 @@ def init_block_pool(cfg: TransformerConfig, num_blocks: int,
     Scales are per pool ROW (write-local): a decode step writing one
     token never rescales a block's resident neighbours, which is what
     keeps hit-replay bitwise and blocks relocatable."""
+    if cfg.skeleton == "gated_hybrid":
+        # pages for the full-attention layers only, and per slot the
+        # recurrent rows of the others (``slots``: the engine's batch)
+        from paddle_tpu.models import gated_hybrid
+        if kv_dtype not in (None, "none"):
+            gated_hybrid.refuse(f"an {kv_dtype} KV pool")
+        if slots is None:
+            raise ValueError("init_block_pool(slots=...): a gated_hybrid "
+                             "pool holds recurrent rows per engine slot")
+        return gated_hybrid.init_block_pool(cfg, num_blocks, block_size,
+                                            int(slots))
     M = int(num_blocks) * int(block_size)
     if kv_dtype in (None, "none"):
         shape = (cfg.n_layers, cfg.kv_heads, M, cfg.head_dim)
@@ -561,6 +632,8 @@ def kv_pool_bytes_per_token(cfg: TransformerConfig,
         per = 2 * Hkv * (Dh // 2) + 2 * Hkv * 4
     else:
         raise ValueError(f"kv_dtype {kv_dtype!r}")
+    if cfg.skeleton == "gated_hybrid":       # full-attention layers only
+        return cfg.n_layers // cfg.full_attn_interval * per
     return cfg.n_layers * per
 
 
@@ -602,6 +675,22 @@ def _gather_pages(tab, groups, pages, block_size: int, num_blocks: int):
                      + tab.shape[1:])
 
 
+def _hybrid_step(params, cache, cfg, pallas, program: str, *args, **kw):
+    """A paged step program of ``skeleton="gated_hybrid"``
+    (``models/gated_hybrid``): XLA path, weights and pool in the
+    model's dtype; ``(logits, pool)`` and with ``return_stats`` the
+    expert layer's counts as a third."""
+    from paddle_tpu.models import gated_hybrid
+    if _pallas_policy.pallas_mode(pallas) != "off":
+        gated_hybrid.refuse("PADDLE_TPU_PALLAS other than 'off' (no "
+                            "kernel takes its head width)")
+    if "k_scale" in cache:
+        gated_hybrid.refuse("an int8 / int4 KV pool")
+    if _blocks_quantized({"blocks": params}):
+        gated_hybrid.refuse("int8 weights")
+    return getattr(gated_hybrid, program)(params, cache, *args, cfg, **kw)
+
+
 def prefill(params, tokens: jax.Array, cfg: TransformerConfig,
             cache_len: int, *, mesh: Optional[Mesh] = None):
     """Batched prompt ingestion: the SAME traced block the training path
@@ -610,6 +699,7 @@ def prefill(params, tokens: jax.Array, cfg: TransformerConfig,
     ``cache_len``. Returns (last-position logits [B, vocab] fp32, cache).
     Packed (equal-length) prompts only — the decode loop's position
     counter is shared across the batch."""
+    require_gpt2(cfg, "prefill (the lockstep path)")
     T = tokens.shape[1]
     logits, (kc, vc) = _forward_impl(params, tokens, cfg, mesh, None,
                                      True, head="last")
@@ -623,6 +713,7 @@ def decode_step(params, cache, tokens: jax.Array, pos: jax.Array,
     → (logits [B, vocab] fp32, updated cache). All shapes static; the
     cache updates via dynamic_update_slice so the step compiles once and
     is replayed for every position (lax.scan-friendly)."""
+    require_gpt2(cfg, "decode_step (the lockstep path)")
     B = tokens.shape[0]
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
@@ -705,6 +796,7 @@ def prefill_into_slot(params, cache, tokens: jax.Array, length: jax.Array,
     BEFORE any per-slot attention mask (``pos >= position``) can read it.
     Rows other than ``slot`` are untouched (dynamic_update_slice writes a
     1-row slab)."""
+    require_gpt2(cfg, "prefill_into_slot (the row-arena engine)")
     if tokens.shape[0] != 1:
         raise ValueError(f"prefill_into_slot takes one request "
                          f"([1, Tb] tokens), got {tokens.shape}")
@@ -746,6 +838,7 @@ def decode_step_slots(params, cache, tokens: jax.Array, pos: jax.Array,
     int8 xs and dequantize inside the body (``_live_layer_weights``
     anti-hoist defenses), so serving reads weights at 1 byte/elt."""
     B = tokens.shape[0]
+    require_gpt2(cfg, "decode_step_slots (the row-arena engine)")
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
     kvd = Hkv * Dh
@@ -818,7 +911,8 @@ def decode_step_slots(params, cache, tokens: jax.Array, pos: jax.Array,
 def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
                       active: jax.Array, pages: jax.Array,
                       cfg: TransformerConfig, *, block_size: int,
-                      pallas: Optional[str] = None):
+                      pallas: Optional[str] = None,
+                      return_stats: bool = False):
     """One incremental step over the PAGED block pool: tokens [B] int32,
     ``pos`` [B] int32, ``active`` [B] bool, ``pages`` [B, P] int32 block
     ids → (logits [B, vocab] fp32, updated pool).
@@ -890,6 +984,11 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     quantized path (tests/test_kv_quant.py)."""
     from paddle_tpu.ops import q8 as ops_q8
     from paddle_tpu.ops.pallas import decode as _pallas_decode
+    if cfg.skeleton == "gated_hybrid":
+        return _hybrid_step(params, cache, cfg, pallas, "decode_step",
+                            tokens, pos, active, pages,
+                            block_size=block_size,
+                            return_stats=return_stats)
     B = tokens.shape[0]
     P = pages.shape[1]
     bs = int(block_size)
@@ -1078,6 +1177,7 @@ def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     pool itself still rides the layer scan as ``xs``/``ys`` here (the
     decode step carries it and updates it in place; ROADMAP Speed 4
     lists this program as left): same values written and read."""
+    require_gpt2(cfg, "verify_step_paged (speculative decoding)")
     from paddle_tpu.ops import q8 as ops_q8
     B, W = tokens.shape
     N = B * W
@@ -1221,7 +1321,9 @@ def verify_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
 def prefill_into_blocks(params, cache, tokens: jax.Array,
                         length: jax.Array, pages: jax.Array,
                         cfg: TransformerConfig, *, block_size: int,
-                        pallas: Optional[str] = None):
+                        pallas: Optional[str] = None,
+                        slot: Optional[jax.Array] = None,
+                        return_stats: bool = False):
     """Prefill ONE CHUNK of one request's prompt into its pages of the
     block pool.
 
@@ -1272,6 +1374,16 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
     writes run the ``paged_span_write`` kernel (block-mapped through
     the page vector via scalar prefetch). The XLA path above is what
     ``off`` selects, and the numerics reference."""
+    if cfg.skeleton == "gated_hybrid":
+        # ``slot``: whose recurrent rows the chunk reads and writes
+        if slot is None:
+            raise ValueError("prefill_into_blocks(slot=...): a "
+                             "gated_hybrid chunk updates its slot's "
+                             "recurrent rows")
+        return _hybrid_step(params, cache, cfg, pallas, "prefill_chunk",
+                            tokens, length, pages, slot,
+                            block_size=block_size,
+                            return_stats=return_stats)
     from paddle_tpu.ops import q8 as ops_q8
     if tokens.shape[0] != 1:
         raise ValueError(f"prefill_into_blocks takes one request "
@@ -1479,6 +1591,7 @@ def generate(params, prompt: jax.Array, cfg: TransformerConfig, *,
     reads the weights from HBM at 1 byte/elt with the dequant multiply
     fused into the matmul operand reads (decode is weight-read-bound;
     a loop-invariant dequant would silently restore 4-byte reads)."""
+    require_gpt2(cfg, "generate (the lockstep path)")
     from paddle_tpu.ops import q8 as ops_q8
 
     B, Tp = prompt.shape
@@ -1569,6 +1682,7 @@ def beam_search(params, prompt: jax.Array, cfg: TransformerConfig, *,
     length penalty: all hypotheses here have identical length max_new,
     so any GNMT-style α rescales every score equally; EOS-terminated
     variable-length decoding is the recurrent DSL's beam_search domain.)"""
+    require_gpt2(cfg, "beam_search (the lockstep path)")
     B, Tp = prompt.shape
     if max_new < 1:
         raise ValueError(f"beam_search: max_new must be >= 1, got {max_new}")

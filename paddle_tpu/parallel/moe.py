@@ -216,3 +216,107 @@ def moe_ffn_a2a(params, x: jax.Array, cfg: MoEConfig, mesh: Mesh,
         out_specs=(P(ax, None), P()),
         check_vma=False)
     return fn(params["gate"], params["w_in"], params["w_out"], x)
+
+
+# -- dropless expert layer (no capacity, nothing dropped) ----------------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def dropless_init_params(key: jax.Array, d_model: int, d_ff: int,
+                         num_experts: int, held: int, shared_ff: int,
+                         dtype=jnp.float32):
+    """``router`` over ALL experts (float32), SwiGLU weights of the
+    ``held`` ones, a shared expert of width ``shared_ff`` under a
+    sigmoid gate."""
+    k = jax.random.split(key, 8)
+    D, F, Fs = d_model, d_ff, shared_ff
+    s = 1.0 / math.sqrt(D)
+
+    def nrm(kk, shape, scale):
+        return (jax.random.normal(kk, shape, jnp.float32)
+                * scale).astype(dtype)
+
+    return {
+        "router": jax.random.normal(k[0], (D, num_experts),
+                                    jnp.float32) * s,
+        "w1": nrm(k[1], (held, D, F), s), "w3": nrm(k[2], (held, D, F), s),
+        "w2": nrm(k[3], (held, F, D), 1.0 / math.sqrt(F)),
+        "s_gate": nrm(k[4], (D,), s),
+        "s_w1": nrm(k[5], (D, Fs), s), "s_w3": nrm(k[6], (D, Fs), s),
+        "s_w2": nrm(k[7], (Fs, D), 1.0 / math.sqrt(Fs)),
+    }
+
+
+def moe_dropless(params, x: jax.Array, *, top_k: int,
+                 held: Tuple[int, int], valid: Optional[jax.Array] = None,
+                 layer=0) -> Tuple[jax.Array, jax.Array]:
+    """Top-k SwiGLU experts without capacity: x [N, D] -> (out [N, D],
+    stats int32 [2] = (assignments kept here, distinct held experts hit)).
+
+    The router scores ALL experts in float32 (``params["router"]``
+    [D, E]) and the k chosen weights are renormalised over the k, as
+    expert parallelism has every chip do; of the N*k assignments this
+    chip keeps those whose expert lies in ``held = (first, count)``,
+    the span whose weights it holds (``w1``/``w3`` [count, D, F], ``w2``
+    [count, F, D]). Kept rows are sorted by expert and go through three
+    grouped matrix products (``jax.lax.ragged_dot``: a group per held
+    expert, an expert nobody chose costs nothing); each row returns to
+    its token under its weight. An assignment to an absent expert adds
+    nothing here: its weight took part in the renormalisation, its
+    product is the other chip's. The shared expert (``s_w1``/``s_w3``/
+    ``s_w2`` under ``sigmoid(x . s_gate)``) is added once, to every
+    token. Rows where ``valid`` [N] is False (a chunk's padding) are
+    routed nowhere and counted nowhere.
+
+    The expert weights may be a STACK over layers ([..., count, D, F]:
+    every leading axis a layer axis) with ``layer`` (traced) the one to
+    use: the groups are then the stack's experts and only this layer's
+    have rows, so the grouped product reads the hit experts where they
+    lie. Slicing the layer out of the stack first (a scan's ``xs``)
+    copies its whole 800 MB for a step that needs a fraction of them.
+    """
+    N, D = x.shape
+    first, count = held
+    k = int(top_k)
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=_HI)
+    gate, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    local = expert - first
+    kept = (local >= 0) & (local < count)
+    if valid is not None:
+        kept = kept & valid[:, None]
+    w1, w3, w2 = (params[n].reshape((-1,) + params[n].shape[-2:])
+                  .astype(x.dtype) for n in ("w1", "w3", "w2"))
+    groups = w1.shape[0]                    # layers in the stack x count
+    # absent and padded assignments sort behind every group
+    flat = jnp.where(kept, local + layer * count, groups).reshape(N * k)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((groups + 1,), jnp.int32).at[flat].add(1)[:groups]
+    rows = jnp.take(x, order // k, axis=0)                  # [N*k, D]
+    up = jax.lax.ragged_dot(rows, w1, sizes,
+                            preferred_element_type=jnp.float32)
+    lin = jax.lax.ragged_dot(rows, w3, sizes,
+                             preferred_element_type=jnp.float32)
+    y = jax.lax.ragged_dot((jax.nn.silu(up) * lin).astype(x.dtype), w2,
+                           sizes, preferred_element_type=jnp.float32)
+    # rows past the last group belong to no expert: whatever the grouped
+    # product left there is masked, not multiplied
+    wrow = jnp.take(gate.reshape(N * k), order)
+    y = jnp.where(jnp.take(kept.reshape(N * k), order)[:, None],
+                  y * wrow[:, None], 0.0)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    out = jnp.sum(jnp.take(y, back, axis=0).reshape(N, k, D), axis=1)
+    sw1, sw3, sw2 = (params[n].astype(x.dtype)
+                     for n in ("s_w1", "s_w3", "s_w2"))
+    sh = (jax.nn.silu(x @ sw1) * (x @ sw3)) @ sw2
+    sg = jax.nn.sigmoid(jnp.einsum(
+        "nd,d->n", x.astype(jnp.float32),
+        params["s_gate"].astype(jnp.float32), precision=_HI))
+    out = out + sg[:, None] * sh.astype(jnp.float32)
+    stats = jnp.stack([jnp.sum(kept.astype(jnp.int32)),
+                       jnp.sum((sizes > 0).astype(jnp.int32))])
+    return out.astype(x.dtype), stats

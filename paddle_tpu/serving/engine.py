@@ -755,6 +755,11 @@ class DecodeEngine:
         preempts."""
         return False
 
+    def _step_ids(self, out: np.ndarray) -> np.ndarray:
+        """The [B] sampled ids of a decode program's host-side result
+        (the paged engine's programs may append counts to them)."""
+        return out
+
     def _update_gauges(self):
         self._m_occupancy.set(self.active_count)
 
@@ -776,6 +781,7 @@ class DecodeEngine:
                 nxt = np.asarray(nxt)
             now = self._close_decode(stage, sync)
             with self.phase("emit"):
+                nxt = self._step_ids(nxt)
                 for slot in np.flatnonzero(self._active):
                     if self._consume_forced(slot):
                         continue
@@ -1095,6 +1101,26 @@ class PagedDecodeEngine(DecodeEngine):
             per_tok += 2 * Hkv * 4         # fp32 scale rows (k + v)
         self.kv_bytes_per_token = int(L) * per_tok
         self.pool_bytes = self.kv_bytes_per_token * self.num_blocks * bs
+        # A model with RECURRENT layers (``models/gated_hybrid``): the
+        # pool pytree also holds, per slot, fixed-size state rows, and
+        # ``k``/``v`` cover the attention layers only (so the arithmetic
+        # above counts those). Pages are then NOT all the state a
+        # position depends on: nothing stores the recurrent rows at a
+        # block boundary, so a prefix hit could not be resumed from.
+        # Prefix publishing, lookup, adoption, export/import and tier
+        # demotion are off; preemption releases the pages unpublished
+        # and resume replays from position 0.
+        self.recurrent = "rec_state" in cache
+        self.recurrent_state_bytes = sum(
+            int(cache[n].size) * cache[n].dtype.itemsize
+            for n in ("rec_state", "rec_tail") if n in cache)
+        if self.recurrent and tiers is not None:
+            raise ValueError("tiered spill (tiers=) is off for a model "
+                             "with recurrent state: no prefix block is "
+                             "ever published")
+        # the expert layer's three counters, made when a step program
+        # first returns counts after its ids (``_moe_counters``)
+        self._m_moe = None
         B = self.batch
         # page table uploaded on change (most decode steps reuse the
         # cached device copy); unallocated entries stay 0 and are only
@@ -1164,6 +1190,13 @@ class PagedDecodeEngine(DecodeEngine):
             "pool's kv_dtype) — the per-token decode-read traffic and "
             "the slots-at-equal-HBM denominator")
         self._m_kv_bytes.set(self.kv_bytes_per_token)
+        reg.gauge("engine_kv_pool_bytes", "HBM bytes of the paged K/V "
+                  "pool (the attention layers' pages)"
+                  ).set(self.pool_bytes)
+        reg.gauge("engine_recurrent_state_bytes", "HBM bytes of the "
+                  "per-slot recurrent rows beside the pool (0 for a "
+                  "model without recurrent layers)"
+                  ).set(self.recurrent_state_bytes)
         self._m_kv_exported = reg.counter(
             "engine_kv_blocks_exported_total", "prefix-cache blocks "
             "serialized out over the P/D transfer wire "
@@ -1231,7 +1264,7 @@ class PagedDecodeEngine(DecodeEngine):
         prefill_fn, decode_fn = sampling.paged_step_fns(
             cfg, block_size, pallas=pallas)
         pool = transformer.init_block_pool(cfg, nb, block_size,
-                                           kv_dtype=kv_dtype)
+                                           kv_dtype=kv_dtype, slots=batch)
         # the pool (argument 1) is donated: both programs update it in
         # place and the engine rebinds self.cache from every result
         jdf = jax.jit(decode_fn, donate_argnums=(1,))
@@ -1324,7 +1357,7 @@ class PagedDecodeEngine(DecodeEngine):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         per = self.chunk_tokens // self.block_size
         usable = ((int(prompt.size) - 1) // self.chunk_tokens) * per
-        if usable <= 0:
+        if usable <= 0 or self.recurrent:   # nothing transferable
             return []
         return _blocks.prompt_block_hashes(prompt,
                                            self.block_size)[:usable]
@@ -1403,6 +1436,10 @@ class PagedDecodeEngine(DecodeEngine):
         (the PR-6 hit-vs-cold guarantee: identical KV bytes, identical
         chunk grid for the locally-computed tail)."""
         from paddle_tpu.serving import transfer as _transfer
+        if self.recurrent:
+            raise ValueError("import_prefix is off for a model with "
+                             "recurrent state: pages are not all the "
+                             "state a prefix leaves behind")
         meta, blocks = _transfer.deserialize_blocks(payload)
         _transfer.check_pool_match(meta, self.cache, self.block_size,
                                    self.kv_dtype)
@@ -1597,6 +1634,8 @@ class PagedDecodeEngine(DecodeEngine):
         from paddle_tpu.serving import blocks as _blocks
         bs = self.block_size
         Tp = req.prompt.size
+        if self.recurrent:      # no lookup: every prompt prefills cold
+            return [], [], -(-(Tp + req.max_new) // bs), 0
         hashes = req.block_hashes
         if hashes is None:      # computed once per request: the digests
             #                     are a pure function of the prompt, and
@@ -1807,7 +1846,17 @@ class PagedDecodeEngine(DecodeEngine):
         now = time.perf_counter()
         bs = self.block_size
         blocks = list(self._slot_blocks[slot])
-        if req.status == "running":
+        if req.status == "running" and self.recurrent:
+            # nothing to publish and no cursor worth keeping: the
+            # recurrent rows are lost with the slot, so resume replays
+            # the prompt cold and the emitted tokens through decode
+            if req.decode_open:
+                self._ev(req, "decode", "e", now)
+                req.decode_open = False
+            req.snapshot = None
+            published = 0
+            self._active[slot] = False
+        elif req.status == "running":
             if req.decode_open:
                 self._ev(req, "decode", "e", now)
                 req.decode_open = False
@@ -1893,8 +1942,8 @@ class PagedDecodeEngine(DecodeEngine):
         snap = req.snapshot
         bs = self.block_size
         blocks: List[int] = []
-        ok = True
-        for h in snap["hashes"]:
+        ok = snap is not None       # None: recurrent state, replay only
+        for h in (snap["hashes"] if ok else ()):
             b = self.pool.lookup(h)
             if b is None:
                 ok = False
@@ -1978,7 +2027,7 @@ class PagedDecodeEngine(DecodeEngine):
         off = self._slot_off[slot]
         bs, K = self.block_size, self.chunk_tokens
         cap = ((req.prompt.size - 1) // K) * K
-        if off % K or off >= cap:
+        if off % K or off >= cap or self.recurrent:
             return False
         hashes = self._slot_hashes[slot]
         first = off // bs
@@ -2009,10 +2058,13 @@ class PagedDecodeEngine(DecodeEngine):
         """One chunk-prefill program over ``slot``'s first ``npages``
         pages; returns the sampled first token (still on device)."""
         jnp = self._jnp
+        # a recurrent model's chunk also names the slot whose rows it
+        # reads and writes
+        whose = (np.int32(slot),) if self.recurrent else ()
         tok, self.cache = self._tracker.track_call(
             "serving_engine.prefill", self._prefill_fn,
             self.params, self.cache, jnp.asarray(padded),
-            np.int32(c), jnp.asarray(self._pages[slot, :npages]),
+            np.int32(c), jnp.asarray(self._pages[slot, :npages]), *whose,
             np.float32(temperature), np.int32(top_k), seed)
         # the spec engine's draft model prefills the SAME chunk into
         # its own pool here (same page vector — one block table maps
@@ -2057,9 +2109,13 @@ class PagedDecodeEngine(DecodeEngine):
             stalled = bool(self._active.any())
         with self.phase("prefill_chunk", tokens=int(c),
                         bucket=bucket) as chunk:
-            tok = int(np.asarray(self._dispatch_chunk(
+            tok = np.asarray(self._dispatch_chunk(
                 slot, padded, c, npages, req.temperature, req.top_k,
-                self._seed())))
+                self._seed()))
+            if tok.ndim:                # [token, 3 counts]
+                self._moe_counters()[0].inc(int(tok[1]))
+                tok = tok[0]
+            tok = int(tok)
         with self.phase("schedule"):
             self._chunk_done(slot, req, off, c, tok, chunk, stalled,
                              finished)
@@ -2084,7 +2140,8 @@ class PagedDecodeEngine(DecodeEngine):
         # arrivals cold-prefills the prefix exactly once
         cold = 0
         for j in range(off // self.block_size,
-                       (off + c) // self.block_size):
+                       0 if self.recurrent
+                       else (off + c) // self.block_size):
             self.pool.publish(self._slot_hashes[slot][j],
                               int(self._pages[slot, j]))
             self._m_prefix_miss.inc()
@@ -2188,6 +2245,43 @@ class PagedDecodeEngine(DecodeEngine):
             self._pages_dev = self._jnp.asarray(self._pages)
         return (self._pages_dev,)
 
+    @property
+    def moe_stats(self) -> bool:
+        """Whether the step programs append the expert layer's three
+        counts to the ids they return
+        (``sampling._hybrid_paged_step_fns``): read off their results,
+        so known from ``precompile()`` or the first step on."""
+        return self._m_moe is not None
+
+    def _moe_counters(self):
+        if self._m_moe is None:
+            reg = self.metrics
+            self._m_moe = (
+                reg.counter(
+                    "engine_moe_assignments_total", "token-to-expert "
+                    "assignments computed on this chip (those whose "
+                    "expert it holds), prefill and decode"),
+                reg.counter(
+                    "engine_moe_decode_experts_hit_total", "distinct "
+                    "held experts hit, summed over the expert-layer "
+                    "calls of decode steps"),
+                reg.counter(
+                    "engine_moe_decode_layer_calls_total", "expert-layer "
+                    "calls made by decode steps"))
+        return self._m_moe
+
+    def _precompile_decode(self):
+        out = np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
+        if out.size > self.batch:   # counts after the ids, none counted
+            self._moe_counters()
+
+    def _step_ids(self, out: np.ndarray) -> np.ndarray:
+        B = self.batch
+        if out.size > B:
+            for m, n in zip(self._moe_counters(), out[B:]):
+                m.inc(int(n))
+        return out[:B]
+
     def _update_gauges(self):
         super()._update_gauges()
         pool = self.pool
@@ -2210,6 +2304,7 @@ class PagedDecodeEngine(DecodeEngine):
                     "kv_dtype": self.kv_dtype,
                     "kv_bytes_per_token": self.kv_bytes_per_token,
                     "pool_bytes": self.pool_bytes,
+                    "recurrent_state_bytes": self.recurrent_state_bytes,
                     "preempted_queued": len(self._preempted),
                     "preemptions": int(self._m_preempts.value())})
         # per-token decode FLOPs: the recompute cost the fleet router's
@@ -2278,6 +2373,10 @@ class SpecDecodeEngine(PagedDecodeEngine):
                  verify: Callable, draft_verify: Callable, spec_k: int,
                  tracker: Optional[_ct.CompileTracker] = None,
                  **kw):
+        if "rec_state" in (cache or ()) or "rec_state" in (draft_cache
+                                                            or ()):
+            from paddle_tpu.models import gated_hybrid
+            gated_hybrid.refuse("speculative decoding (SpecDecodeEngine)")
         if kw.get("tiers") is not None:
             # a spilled payload carries only TARGET pool rows; adopting
             # one would leave the draft pool's rows beside it stale —

@@ -260,6 +260,10 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
     from paddle_tpu.ops.pallas import policy as _pallas_policy
 
     mode = _pallas_policy.pallas_mode(pallas)
+    if cfg.skeleton == "gated_hybrid":
+        if dequant is not None:
+            transformer.require_gpt2(cfg, "int8 weights")
+        return _hybrid_paged_step_fns(cfg, block_size, mode)
     _live = _prefill_live(dequant)
     _live_d = _decode_live(dequant)
     tail = _epilogue(mode)
@@ -279,6 +283,43 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
             _live_d(params), pool, tokens, pos, active, pages, cfg,
             block_size=block_size, pallas=mode)
         return tail(logits, seed, temperature, top_k), pool
+
+    paths = {}
+    prefill_fn = _recorded(
+        prefill_fn, paths, lambda p, c, tokens, length, pages, *_:
+        f"prefill_{tokens.shape[1]}_{pages.shape[0]}")
+    decode_fn = _recorded(decode_fn, paths, lambda *_: "decode")
+    prefill_fn.kernel_paths = decode_fn.kernel_paths = paths
+    return prefill_fn, decode_fn
+
+
+def _hybrid_paged_step_fns(cfg, block_size: int, mode: str):
+    """``paged_step_fns`` for ``skeleton="gated_hybrid"``: the chunk
+    program also takes the ``slot`` whose recurrent rows it updates
+    (after ``pages``), and both programs append the expert layer's
+    three counts (``gated_hybrid._run_layers``) to the ids they return
+    (``token [1 + 3]``, ``tokens [B + 3]``, int32), so the engine reads
+    them back in the transfer it already makes."""
+    from paddle_tpu.models import transformer
+    tail = _epilogue(mode)
+
+    def prefill_fn(params, pool, tokens, length, pages, slot,
+                   temperature, top_k, seed):
+        logits, pool, stats = transformer.prefill_into_blocks(
+            params, pool, tokens, length, pages, cfg,
+            block_size=block_size, pallas=mode, slot=slot,
+            return_stats=True)
+        tok = tail(logits, seed, jnp.reshape(temperature, (1,)),
+                   jnp.reshape(top_k, (1,)))
+        return jnp.concatenate([tok.astype(jnp.int32), stats]), pool
+
+    def decode_fn(params, pool, tokens, pos, active, pages, temperature,
+                  top_k, seed):
+        logits, pool, stats = transformer.decode_step_paged(
+            params, pool, tokens, pos, active, pages, cfg,
+            block_size=block_size, pallas=mode, return_stats=True)
+        ids = tail(logits, seed, temperature, top_k)
+        return jnp.concatenate([ids.astype(jnp.int32), stats]), pool
 
     paths = {}
     prefill_fn = _recorded(
@@ -363,6 +404,9 @@ def paged_spec_fns(cfg, draft_cfg, block_size: int, spec_k: int,
     ``.kernel_paths``."""
     from paddle_tpu.models import transformer
     from paddle_tpu.ops.pallas import policy as _pallas_policy
+
+    for c in (cfg, draft_cfg):
+        transformer.require_gpt2(c, "speculative decoding")
 
     mode = _pallas_policy.pallas_mode(pallas)
     _live_d = _decode_live(dequant)
