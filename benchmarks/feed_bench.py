@@ -4,7 +4,8 @@ bench.py measures with device-resident synthetic tensors; the reference
 trained from host-side data providers with an async double-buffer
 (paddle/gserver/dataproviders/PyDataProvider2.cpp:195). Our equivalents
 are the trainer's one-batch-lookahead feed path (trainer.py
-_prefetch_feeds) and, beyond it, the staged async input pipeline
+_feed_next: batch N+1 converted while step N is on the device) and,
+beyond it, the staged async input pipeline
 (paddle_tpu/pipeline/): transform workers + staging ring + device
 double-buffer, enabled with ``trainer.train(..., prefetch=N)``.
 

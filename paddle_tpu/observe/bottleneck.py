@@ -5,9 +5,13 @@ spans since PR 2, but nothing *classified* a step — an operator watching
 step time regress still had to eyeball a trace. This module derives the
 classification from those same three measurements:
 
-- ``feed_s``      time obtaining the step's feeds (next() on the feed
-                  iterator: DataFeeder convert + H2D on the sync path,
-                  the blocking staging-ring get on the pipelined path)
+- ``feed_s``      time the trainer's thread spent on input in this
+                  step: on the sync path the pull + DataFeeder convert +
+                  H2D dispatch of the NEXT batch, which run between this
+                  step's dispatch and its sync (so ``sync_s`` is the
+                  wait LEFT after them, and a feed that outlasts the
+                  device reads input_bound); the blocking staging-ring
+                  get on the pipelined path
 - ``dispatch_s``  host-side dispatch of the jitted step (python +
                   tracing; balloons on a recompile)
 - ``sync_s``      host blocked reading back the loss. Under jax's

@@ -86,7 +86,10 @@ class StepAccountant:
              compile_miss: bool = False,
              median_s: Optional[float] = None):
         """Account one trained batch: ``dt`` is the step wall
-        (dispatch + sync), ``feed_s`` the feed wait. On a jit cache
+        (dispatch + sync), ``feed_s`` the trainer thread's seconds on
+        input, booked apart from ``dt`` (the synchronous feed runs
+        while the step is on the device, so there it is an upper bound
+        of what the device waited for input). On a jit cache
         miss the steady median (when known) stays useful and the
         excess is recompile — the first-ever step has no median yet,
         so its whole wall is compile, which is what it is."""

@@ -624,7 +624,14 @@ class SGD:
         which additionally makes resume exact: the pipeline's stream
         position rides inside every checkpoint and a restore continues
         mid-epoch on the exact next batch. 0 keeps the synchronous
-        one-batch-lookahead path.
+        path, on the trainer's own thread and one batch ahead: batch 0
+        is pulled and converted before the loop, batch N+1 after step N
+        has been dispatched and before its loss is read back, so the
+        host's conversion runs while the device works and costs the
+        device nothing until it outlasts the step. One step is in
+        flight at a time; a reader or feeder failure on batch N+1 is
+        raised after step N has finished (its ``EndIteration`` and its
+        checkpoint included).
 
         Elastic contract: under a supervisor (PADDLE_ELASTIC_DIR set by
         ``runtime/supervisor.py``) this entry is crash-re-enterable —
@@ -765,45 +772,26 @@ class SGD:
                 pipe.close()   # user-passed pipelines stay open: their
                                # state_dict/resume lifecycle is theirs
 
-    def _prefetch_feeds(self, reader, feeder):
-        """One-batch-lookahead feed pipeline: batch N+1 is fed and its
-        (asynchronous) host→device transfer dispatched BEFORE batch N is
-        yielded, so the transfer rides under batch N's step instead of
-        serializing after the step's host sync (the reference's data
-        providers double-buffer into the trainer the same way —
-        PyDataProvider2.cpp:195 async pool). jax.device_put returns
-        immediately with the copy in flight; the step that consumes the
-        buffer joins it on-device."""
-        prev = None
-        it = iter(reader())
-        while True:
-            try:
-                data_batch = next(it)
-                # feed() already dispatches the H2D copies (jnp.asarray
-                # is asynchronous); the sharded put is likewise async
-                with observe.trace_scope("feed"):
-                    with observe.trace_scope("convert"):
-                        feeds = feeder.feed(data_batch)
-                    if self.parallel is not None:
-                        with observe.trace_scope("transfer"):
-                            feeds = jax.device_put(
-                                feeds,
-                                self.parallel.feed_shardings(feeds))
-            except StopIteration:
-                break
-            except Exception:
-                # batch N is already fed; train it before surfacing
-                # batch N+1's failure, or the crash would both lose N
-                # and point at the wrong batch index
-                if prev is not None:
-                    yield prev
-                    prev = None
-                raise
-            if prev is not None:
-                yield prev
-            prev = feeds
-        if prev is not None:
-            yield prev
+    def _feed_next(self, batches, feeder):
+        """Pull the next batch off the reader and convert it (under
+        ``self.parallel`` also put it to the feed shardings). Returns
+        None, and records no ``feed`` span, when the reader is at its
+        end. ``feeder.feed`` and the sharded put only DISPATCH the
+        host→device copies (``jnp.asarray`` / ``jax.device_put`` are
+        asynchronous); the step that consumes the buffers joins them on
+        the device."""
+        try:
+            data_batch = next(batches)
+        except StopIteration:
+            return None
+        with observe.trace_scope("feed"):
+            with observe.trace_scope("convert"):
+                feeds = feeder.feed(data_batch)
+            if self.parallel is not None:
+                with observe.trace_scope("transfer"):
+                    feeds = jax.device_put(
+                        feeds, self.parallel.feed_shardings(feeds))
+        return feeds
 
     def _train_passes(self, reader, num_passes, event_handler, feeder, ks,
                       log_period, ckpt, period, pipe=None, hb=None):
@@ -823,20 +811,34 @@ class SGD:
             pass_t0 = time.perf_counter()
             pass_examples = 0
             # pipelined mode: one iter() == one epoch, resuming mid-epoch
-            # after a restore; feeds arrive converted + device-resident
-            feed_iter = (iter(pipe) if pipe is not None
-                         else self._prefetch_feeds(reader, feeder))
+            # after a restore; feeds arrive converted + device-resident.
+            # Synchronous mode: batch 0 is fed here, before anything is
+            # on the device; batch N+1 is fed under step N, below
+            ahead, ahead_exc = None, None
+            if pipe is not None:
+                feed_iter = iter(pipe)
+            else:
+                batches = iter(reader())
+                feed_t0 = time.perf_counter()
+                ahead = self._feed_next(batches, feeder)
+                acct.add("input_stall", time.perf_counter() - feed_t0)
             batch_id = -1
             while True:
-                # feed wait timed explicitly: the input component of the
-                # step's bottleneck attribution (sync path: convert+H2D
-                # of the NEXT batch; pipelined: the staging-ring get)
-                feed_t0 = time.perf_counter()
-                try:
-                    feeds = next(feed_iter)
-                except StopIteration:
-                    break
-                feed_s = time.perf_counter() - feed_t0
+                if pipe is not None:
+                    # the staging-ring get, timed as the pipelined
+                    # step's input wait
+                    feed_t0 = time.perf_counter()
+                    try:
+                        feeds = next(feed_iter)
+                    except StopIteration:
+                        break
+                    feed_s = time.perf_counter() - feed_t0
+                else:
+                    if ahead_exc is not None:
+                        raise ahead_exc
+                    if ahead is None:
+                        break
+                    feeds = ahead
                 batch_id += 1
                 # chaos site 'step': kill/hang/crash BEFORE the step
                 # executes, so "kill at step k" means exactly k steps
@@ -865,6 +867,24 @@ class SGD:
                          self.parameters.state, outs) = step_fn(*step_args)
                 dispatch_s = time.perf_counter() - step_t0
                 self._step += 1
+                if pipe is None:
+                    # step N is on the device: pull and convert batch
+                    # N+1 NOW, before any read of step N's outputs waits
+                    # for it (the evaluators' add_batch below is such a
+                    # read), so the feed costs the device nothing until
+                    # it outlasts the step. feed_s is this stretch,
+                    # sync_s below the wait that is left after it
+                    feed_t0 = time.perf_counter()
+                    try:
+                        ahead = self._feed_next(batches, feeder)
+                    except Exception as e:
+                        # step N is in flight: finish it (sync, monitor,
+                        # EndIteration, its checkpoint) and surface batch
+                        # N+1's failure at the top of the next iteration,
+                        # or the crash would both lose N and point at the
+                        # wrong batch index
+                        ahead, ahead_exc = None, e
+                    feed_s = time.perf_counter() - feed_t0
                 self.evaluators.add_batch(outs)
                 # float(loss) is the host sync — per-step wall time must
                 # include it or async dispatch hides the real step time
@@ -877,8 +897,11 @@ class SGD:
                 n0 = tracker.count("train_step")
                 tracker.record("train_step", sig, step_dt)
                 # goodput split: an unseen signature IS a compile — the
-                # steady median stays useful, the excess is recompile
-                acct.step(step_dt, feed_s=feed_s,
+                # steady median stays useful, the excess is recompile.
+                # The synchronous feed lies INSIDE step_dt: it is booked
+                # once, as input, so the buckets still sum to the wall
+                acct.step(step_dt - feed_s if pipe is None else step_dt,
+                          feed_s=feed_s,
                           compile_miss=tracker.count("train_step") > n0,
                           median_s=monitor.median())
                 self._last_step_wall = time.perf_counter()

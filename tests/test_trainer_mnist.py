@@ -104,40 +104,183 @@ def test_params_tar_roundtrip(trained, tmp_path):
     np.testing.assert_allclose(p2["pred.w"], params["pred.w"])
 
 
-class TestPrefetchFeeds:
-    """The feed pipeline must run one batch AHEAD of consumption so the
-    H2D transfer overlaps the in-flight step (the reference's
-    double-buffering data providers, PyDataProvider2.cpp:195)."""
+class TestFeedUnderStep:
+    """The synchronous feed path of ``SGD.train``: batch 0 is fed before
+    the loop, batch N+1 between step N's dispatch and its host sync, on
+    the trainer's own thread (the reference's double-buffering data
+    providers, PyDataProvider2.cpp:195, as an order of three statements).
+    Every case goes through ``SGD.train`` on a small dense model with a
+    reader of whole batches that notes its pulls, a feeder that notes its
+    feeds and a handler that notes the events."""
 
-    def test_one_batch_lookahead_order(self):
-        from paddle_tpu import trainer as trainer_mod
+    FEEDING = {"fus_x": 0, "fus_y": 1}
 
-        log = []
+    @staticmethod
+    def _trainer(parallel=None):
+        x = layer.data("fus_x", paddle.data_type.dense_vector(12))
+        y = layer.data("fus_y", paddle.data_type.integer_value(4))
+        h = layer.fc(x, 16, act=paddle.activation.Relu(), name="fus_h")
+        out = layer.fc(h, 4, act=paddle.activation.Softmax(), name="fus_o")
+        cost = layer.classification_cost(out, y, name="fus_c")
+        params = paddle.parameters.create(cost, KeySource(77))
+        return paddle.trainer.SGD(
+            cost=cost, parameters=params, parallel=parallel,
+            update_equation=paddle.optimizer.Momentum(learning_rate=0.05,
+                                                      momentum=0.9))
+
+    @staticmethod
+    def _batches(n, batch=8):
+        rng = np.random.RandomState(5)
+        return [[(rng.randn(12).astype(np.float32), int(rng.randint(4)))
+                 for _ in range(batch)] for _ in range(n)]
+
+    def _run(self, tr, batches, log, fail_at=None, on_end=None):
+        """One pass over ``batches``; ``log`` takes ("pull", k), ("feed",
+        k), ("eof",), ("begin", n), ("end", n) in the order they happen.
+        ``fail_at=k``: the reader raises where batch k is due."""
+        def reader():
+            for k, b in enumerate(batches):
+                if k == fail_at:
+                    raise OSError(f"batch {k} unreadable")
+                log.append(("pull", k))
+                yield b
+            log.append(("eof",))
+
+        real = tr._feeder(self.FEEDING)
+        fed = iter(range(len(batches)))
 
         class SpyFeeder:
             def feed(self, b):
-                log.append(("feed", b))
-                return {"x": b}
+                log.append(("feed", next(fed)))
+                return real.feed(b)
 
-        sgd = object.__new__(trainer_mod.SGD)
-        sgd.parallel = None
-        for got in sgd._prefetch_feeds(lambda: iter(range(3)),
-                                       SpyFeeder()):
-            log.append(("consume", got["x"]))
-        # feed(N+1) is dispatched before batch N is consumed
-        assert log == [("feed", 0), ("feed", 1), ("consume", 0),
-                       ("feed", 2), ("consume", 1), ("consume", 2)]
+        tr._feeder = lambda feeding: SpyFeeder()
 
-    def test_empty_reader_yields_nothing(self):
-        from paddle_tpu import trainer as trainer_mod
+        def handler(e):
+            if isinstance(e, paddle.event.BeginIteration):
+                log.append(("begin", e.batch_id))
+            elif isinstance(e, paddle.event.EndIteration):
+                log.append(("end", e.batch_id))
+                if on_end is not None:
+                    on_end(e)
 
-        class F:
-            def feed(self, b):           # pragma: no cover
-                raise AssertionError("must not be called")
+        tr.train(reader, num_passes=1, event_handler=handler,
+                 feeding=self.FEEDING)
 
-        sgd = object.__new__(trainer_mod.SGD)
-        sgd.parallel = None
-        assert list(sgd._prefetch_feeds(lambda: iter([]), F())) == []
+    def test_next_batch_is_fed_inside_the_iteration(self):
+        """Batch N+1 is pulled and fed after BeginIteration(N) and before
+        EndIteration(N); batch N+2 is not."""
+        log = []
+        self._run(self._trainer(), self._batches(3), log)
+        assert log == [
+            ("pull", 0), ("feed", 0),
+            ("begin", 0), ("pull", 1), ("feed", 1), ("end", 0),
+            ("begin", 1), ("pull", 2), ("feed", 2), ("end", 1),
+            ("begin", 2), ("eof",), ("end", 2)]
+
+    def test_bitwise_equal_to_feed_then_dispatch_then_sync(self):
+        """Same work, same results: the losses of K steps and the
+        parameters at every EndIteration(N) are bitwise those of the
+        parent's order (feed, then dispatch, then sync), spelled out here
+        on a second trainer with the same initial values."""
+        import jax.numpy as jnp
+        from paddle_tpu.utils.rng import global_key_source
+        batches = self._batches(5)
+
+        ref = self._trainer()
+        feeder, ks = ref._feeder(self.FEEDING), global_key_source()
+        ref_losses, ref_params = [], []
+        for b in batches:
+            feeds = feeder.feed(b)
+            step_fn = ref._pick_train_step(feeds)
+            (loss, ref.parameters.values, ref.opt_state,
+             ref.parameters.state, _) = step_fn(
+                ref.parameters.values, ref.opt_state, ref.parameters.state,
+                feeds, jnp.asarray(ref._step, jnp.int32),
+                ks.step("dropout", ref._step))
+            ref._step += 1
+            ref_losses.append(float(loss))
+            ref_params.append({k: np.asarray(v) for k, v in
+                               ref.parameters.values.items()})
+
+        tr = self._trainer()
+        losses, seen = [], []
+
+        def on_end(e):
+            losses.append(e.cost)
+            # the outputs of step N, with exactly N+1 steps dispatched
+            assert tr._step == e.batch_id + 1
+            seen.append({k: np.asarray(v) for k, v in
+                         tr.parameters.values.items()})
+
+        self._run(tr, batches, [], on_end=on_end)
+        assert losses == ref_losses
+        assert len(seen) == len(ref_params) == 5
+        for n, (got, want) in enumerate(zip(seen, ref_params)):
+            assert got.keys() == want.keys()
+            for k in want:
+                np.testing.assert_array_equal(got[k], want[k],
+                                              err_msg=f"step {n} {k}")
+
+    @pytest.mark.parametrize("breaks", ["reader", "feeder"])
+    def test_failure_on_next_batch_surfaces_after_the_step(self, breaks):
+        """A reader (or feeder) that fails on batch N+1 delivers
+        EndIteration(N) first, then the exception; no further step."""
+        log, tr = [], self._trainer()
+        batches = self._batches(4)
+        if breaks == "reader":
+            with pytest.raises(OSError, match="batch 2 unreadable"):
+                self._run(tr, batches, log, fail_at=2)
+            tail = [("begin", 1), ("end", 1)]
+        else:
+            batches[2] = [(np.zeros(12, np.float32),)]   # no label column
+            with pytest.raises(Exception) as ei:
+                self._run(tr, batches, log)
+            assert not isinstance(ei.value, (OSError, StopIteration))
+            tail = [("begin", 1), ("pull", 2), ("feed", 2), ("end", 1)]
+        assert log == [("pull", 0), ("feed", 0),
+                       ("begin", 0), ("pull", 1), ("feed", 1), ("end", 0)
+                       ] + tail
+        assert tr._step == 2
+
+    @pytest.mark.parametrize("n", [0, 1])
+    def test_empty_and_one_batch_readers(self, n):
+        """An empty reader trains nothing and never reaches the feeder; a
+        one-batch reader trains exactly one step."""
+        log, tr = [], self._trainer()
+        self._run(tr, self._batches(n), log)
+        assert tr._step == n
+        assert log == [[("eof",)],
+                       [("pull", 0), ("feed", 0), ("begin", 0), ("eof",),
+                        ("end", 0)]][n]
+
+    @pytest.mark.parametrize("sharded", [False, True])
+    def test_scopes_top_level_one_feed_span_per_step(self, sharded):
+        """``feed`` and ``host_sync`` stay top-level scopes, closed in the
+        order feed(0), then per step: dispatch(N), feed(N+1),
+        host_sync(N); one ``feed`` span a step and none for the pull that
+        ends the pass. Under ``parallel`` the sharded put moves with the
+        feed (``feed/transfer``)."""
+        from paddle_tpu import observe, parallel
+        from paddle_tpu.core import place
+        par = None
+        if sharded:
+            par = parallel.DistConfig(
+                place.make_mesh((4,), (place.AXIS_DATA,)))
+        tr = self._trainer(par)
+        buf = observe.default_buffer()
+        buf.clear()
+        self._run(tr, self._batches(3), [])
+        names = [s[0] for s in buf.spans() if s[5] == "X"]
+        order = [n for n in names if n in
+                 ("feed", "train_step/dispatch", "host_sync")]
+        assert order == (["feed"] +
+                         ["train_step/dispatch", "feed", "host_sync"] * 2 +
+                         ["train_step/dispatch", "host_sync"])
+        assert names.count("feed/convert") == 3
+        assert names.count("feed/transfer") == (3 if sharded else 0)
+        assert not [n for n in names
+                    if n.endswith(("/feed", "/host_sync"))]
 
 
 class TestGradAccum:
