@@ -35,7 +35,6 @@ FAMILIES = {
     "serving": {
         "glob": "*serving_paged*.json",
         "figures": [
-            ("serving_paged_speedup", "higher", 0.15),
             ("throughput.engine_paged.tokens_per_sec", "higher", 0.25),
             ("latency.engine_paged.ttft_p99_s", "lower", 0.35),
             # decode-MFU + int8-serving floors (PR-10 artifact fields;

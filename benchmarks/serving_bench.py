@@ -1,15 +1,12 @@
 #!/usr/bin/env python
-"""Paged vs row-arena vs lockstep LM serving under Poisson load.
+"""The serving engine vs lockstep LM serving under Poisson load.
 
-Replays request traces against three serving surfaces:
+Replays request traces against two serving surfaces:
 
 - ``engine_paged`` — ``serving.PagedDecodeEngine``: block-table KV
   pool, chunked prefill interleaved with decode, content-hash prefix
   cache (shared prompts prefill once, concurrent same-prefix requests
   adopt each other's blocks mid-flight), on-device sampling.
-- ``engine_slots`` — the PR-3 ``serving.DecodeEngine``: whole-row KV
-  arena, monolithic bucketed prefill (one long prompt stalls every
-  in-flight decoder for its full duration).
 - ``lockstep``    — the ``LMServer.generate``-shaped baseline: FIFO
   batch formation, one shared prompt bucket, every row decodes to the
   LONGEST request's max_new, host-side argmax per token.
@@ -52,13 +49,11 @@ TWO phases, each its own trace over the same request mix:
 - **throughput** — every request arrives at t=0 (offered load
   saturates the engine), no adversary: wall clock measures CAPACITY,
   which is where the prefix cache pays (tokens/sec, block occupancy,
-  hit counts). ``serving_paged_speedup`` = paged/row-arena tokens/sec.
+  hit counts).
 - **latency** — Poisson arrivals at ``--rate`` (chosen so the engines
   keep up): TTFT percentiles measure the SCHEDULING path.
   ``--long-prompt-adversarial`` drops ONE near-``cache_len`` prompt
-  mid-burst — the row-arena engine stalls everything for its
-  monolithic prefill, the paged engine interleaves chunks with decode
-  steps. ``serving_paged_ttft_p99_ratio`` = paged/row-arena TTFT p99.
+  mid-burst — the engine interleaves its chunks with decode steps.
 
 Trace shaping: ``--shared-prefix-frac F`` injects one common system
 prompt (``--shared-prefix-len`` tokens) into fraction F of each trace
@@ -67,8 +62,8 @@ prompt (``--shared-prefix-len`` tokens) into fraction F of each trace
 Each (variant, phase) replays ``--repeats`` times on a FRESH engine
 (cold prefix cache; compiled programs shared via one jit + tracker)
 and reports the best run — the least-machine-interference estimate on
-a noisy host. Engine compile discipline (one compile per prefill
-bucket / (chunk bucket, context span) pair + one decode) is asserted
+a noisy host. Engine compile discipline (one compile per (chunk
+bucket, context span) pair + one decode) is asserted
 via the compile tracker. A JSON artifact lands in benchmarks/runs/
 (``--out`` to override; skipped under ``--smoke`` unless --out given).
 
@@ -151,11 +146,9 @@ def build_workload(n, rate, prompt_lens, max_news, vocab, seed, *,
     additionally inserts ONE near-``cache_len`` prompt arriving
     MID-BURST: the ``burst`` trace arrivals after the midpoint are
     compressed to land milliseconds behind it — the field study's
-    long-multimodal-prompt-vs-interactive-traffic collision. A
-    row-arena engine must run its monolithic prefill (and then each
-    victim's, sequentially) before the burst sees first tokens; the
-    paged engine interleaves the victims' (often prefix-cache-hit)
-    chunks with the adversary's."""
+    long-multimodal-prompt-vs-interactive-traffic collision: the
+    engine interleaves the victims' (often prefix-cache-hit) chunks
+    with the adversary's."""
     rng = np.random.RandomState(seed)
     prefix = rng.randint(0, vocab, shared_len).astype(np.int32)
     t, work = 0.0, []
@@ -244,7 +237,7 @@ def attribution_section(work, reqs, burst, request_log):
     summary: the burst requests arriving just behind the adversary,
     whose TTFT the chunked-prefill design promises is dominated by
     prefill-stall (bounded, one chunk at a time) rather than queue
-    wait (the row-arena failure mode) or decode.
+    wait or decode.
 
     Records come from the ENGINE's own ring (``eng.request_log``) —
     one source of truth for the field mapping — joined to the trace's
@@ -413,28 +406,6 @@ def paged_factory(params, cfg, *, batch, cache_len, block_size,
             block_size=block_size, num_blocks=nb,
             chunk_tokens=chunk_tokens, seed=0, tracker=tracker,
             decode_flops=flops, pallas_mode=mode, kv_dtype=kv_dtype)
-
-    return make
-
-
-def slots_factory(params, cfg, *, batch, cache_len, buckets, tracker):
-    """() -> fresh row-arena DecodeEngine, same shared-compile setup."""
-    import jax
-
-    from paddle_tpu.models import transformer
-    from paddle_tpu.serving import DecodeEngine, sampling
-    from paddle_tpu.serving.engine import _decode_step_flops
-    prefill_fn, decode_fn = sampling.engine_step_fns(cfg, pallas="off")
-    jpf, jdf = jax.jit(prefill_fn), jax.jit(decode_fn)
-    cache0 = transformer.init_cache(cfg, batch, cache_len)
-    flops = _decode_step_flops(jdf, params, cache0, batch)
-
-    def make():
-        cache = transformer.init_cache(cfg, batch, cache_len)
-        return DecodeEngine(jpf, jdf, params, cache, batch=batch,
-                            cache_len=cache_len, buckets=buckets,
-                            seed=0, tracker=tracker, decode_flops=flops,
-                            pallas_mode="off")
 
     return make
 
@@ -1717,12 +1688,7 @@ def main(argv=None):
                     help="decode slots (= lockstep batch size)")
     ap.add_argument("--rate", type=float, default=16.0,
                     help="latency-phase Poisson arrival rate, req/s "
-                         "(the throughput phase arrives all-at-once). "
-                         "The default offers a load BETWEEN the two "
-                         "engines' measured capacities: the row engine "
-                         "falls steadily behind while the paged engine "
-                         "keeps up — the SLO band the prefix cache "
-                         "buys")
+                         "(the throughput phase arrives all-at-once)")
     ap.add_argument("--vocab", type=int, default=2048)
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--layers", type=int, default=4)
@@ -1737,10 +1703,8 @@ def main(argv=None):
                     help="fraction of requests carrying one common "
                          "system prompt (prefix-cache traffic)")
     ap.add_argument("--shared-prefix-len", type=int, default=256,
-                    help="length of the shared system prompt (long "
-                         "enough that the row engine's bucket-padded "
-                         "prefill cost is material — the field study's "
-                         "system-prompt regime)")
+                    help="length of the shared system prompt (the "
+                         "field study's system-prompt regime)")
     ap.add_argument("--long-prompt-adversarial", action="store_true",
                     help="insert ONE near-cache_len prompt mid-burst "
                          "into the latency trace (the chunked-prefill "
@@ -1754,8 +1718,8 @@ def main(argv=None):
                     default=DEFAULT_CHUNK_TOKENS,
                     help="paged-engine prefill chunk size (tokens)")
     ap.add_argument("--num-blocks", type=int, default=None,
-                    help="paged pool size (default: HBM parity with "
-                         "the row arena, batch*cache_len/block_size)")
+                    help="paged pool size (default: "
+                         "batch*cache_len/block_size)")
     ap.add_argument("--pallas", default=None,
                     choices=("auto", "on", "off", "interpret"),
                     help="PADDLE_TPU_PALLAS override for the "
@@ -1902,8 +1866,8 @@ def main(argv=None):
         args.seed + 1, adversarial=args.long_prompt_adversarial,
         burst=args.batch, **shaping)
     all_lens = {len(p) for _, p, _ in work_tp + work_lat}
-    # row-arena/lockstep prompt buckets must cover every trace length
-    # (the paged engine needs no such bucket: chunked prefill)
+    # the lockstep prompt buckets must cover every trace length (the
+    # engine needs no such bucket: chunked prefill)
     buckets = tuple(sorted({min(
         2 ** int(np.ceil(np.log2(max(n, 2)))), args.cache_len)
         for n in all_lens}))
@@ -1931,16 +1895,12 @@ def main(argv=None):
                     chunk_tokens=args.chunk_tokens,
                     num_blocks=args.num_blocks)
     paged_tr = CompileTracker(storm_threshold=storm)
-    slots_tr = CompileTracker()
     int8_tr = CompileTracker(storm_threshold=storm)
     # the baselines PIN pallas="off": on TPU the ambient policy would
     # otherwise resolve "on" and the "XLA engine" baseline would BE the
     # Pallas path — serving_pallas_speedup comparing Pallas vs Pallas
     mk_paged = paged_factory(params, cfg, tracker=paged_tr,
                              pallas="off", **paged_kw)
-    mk_slots = slots_factory(
-        params, cfg, batch=args.batch, cache_len=args.cache_len,
-        buckets=buckets, tracker=slots_tr)
     # fp32-vs-int8: the same paged engine over quantize_lm_params
     # weights — decode reads int8 (in-scan dequant), prefill dequantizes
     # wholesale; XLA attention either way so the figure isolates the
@@ -1975,17 +1935,14 @@ def main(argv=None):
     results = {"pallas": {"mode": pallas_mode, "timed": pallas_timed}}
     repeats = max(1, args.repeats)
     for phase, work in (("throughput", work_tp), ("latency", work_lat)):
-        engines = [("engine_paged", mk_paged),
-                   ("engine_slots", mk_slots)]
+        engines = [("engine_paged", mk_paged)]
         if phase == "throughput":
             # the throughput phase carries the kernel/int8/kv8 A/Bs
             # (their figures of merit are tokens/sec and decode MFU)
             if mk_pallas is not None:
-                engines.insert(1, ("engine_paged_pallas", mk_pallas))
-            engines.insert(len(engines) - 1,
-                           ("engine_paged_int8", mk_int8))
-            engines.insert(len(engines) - 1,
-                           ("engine_paged_kv8", mk_kv8))
+                engines.append(("engine_paged_pallas", mk_pallas))
+            engines.append(("engine_paged_int8", mk_int8))
+            engines.append(("engine_paged_kv8", mk_kv8))
         warms = {name: warm_engine(mk, work, args.vocab)
                  for name, mk in engines}
         lk_warm(work)
@@ -2016,7 +1973,7 @@ def main(argv=None):
             metrics_write(**r)
 
     # compile discipline across BOTH phases and all repeats: one
-    # program per (chunk bucket, context span) / prompt bucket + one
+    # program per (chunk bucket, context span) + one
     # decode, regardless of paging, hits, adoption, weight dtype, or
     # attention engine
     progs = _paged_programs(all_lens, chunk, args.block_size,
@@ -2035,8 +1992,6 @@ def main(argv=None):
             f"{name} compile invariant: expected {len(want)} chunk "
             f"programs {sorted(want)}, saw "
             f"{tr.count('serving_engine.prefill')}")
-    assert slots_tr.count("serving_engine.decode") == 1
-    assert slots_tr.count("serving_engine.prefill") <= len(buckets)
 
     # the interpret-mode kernels must not rot on CPU-only CI: replay a
     # tiny greedy trace on pallas=interpret engines and demand ids
@@ -2231,17 +2186,12 @@ def main(argv=None):
                   f"({len(reqs)} requests, all lifecycles joined)",
                   file=sys.stderr)
 
-    tp, lat = results["throughput"], results["latency"]
-    speedup = (tp["engine_paged"]["tokens_per_sec"]
-               / max(tp["engine_slots"]["tokens_per_sec"], 1e-9))
-    ttft_ratio = (lat["engine_paged"]["ttft_p99_s"]
-                  / max(lat["engine_slots"]["ttft_p99_s"], 1e-9))
+    tp = results["throughput"]
     int8_speedup = (tp["engine_paged_int8"]["tokens_per_sec"]
                     / max(tp["engine_paged"]["tokens_per_sec"], 1e-9))
     kv8_speedup = (tp["engine_paged_kv8"]["tokens_per_sec"]
                    / max(tp["engine_paged"]["tokens_per_sec"], 1e-9))
-    figures = [("serving_paged_speedup", speedup),
-               ("serving_paged_ttft_p99_ratio", ttft_ratio),
+    figures = [
                # int8-vs-fp32 on the SAME engine: >1 where weight reads
                # bound decode (TPU); CPU pays the dequant ALU instead
                # and reports honestly below 1

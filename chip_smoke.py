@@ -533,8 +533,7 @@ def phase_export(lm, args):
         lm_serving.save_lm_artifact(
             os.path.join(WORK, name), params, cfg, batch=lm["slots"],
             prompt_len=8, cache_len=lm["cache_len"],
-            engine_buckets=lm["buckets"], engine_paged=True,
-            engine_block_size=block)
+            engine_buckets=lm["buckets"], engine_block_size=block)
         print(f"exported {name} (block {block}, chunk {lm['chunk']}, "
               f"{lm['slots']} slots, cache {lm['cache_len']}) in "
               f"{time.time() - t0:.1f}s")

@@ -314,12 +314,12 @@ def job_serve(args):
     Result lines:   {"id": ..., "tokens": [ids...], "finish_reason":
                      "eos"|"max_tokens", "ttft_ms": ..., "latency_ms": ...}
 
-    Paged-engine replicas additionally serve the fleet ops
+    Replicas additionally serve the fleet ops
     ``export_prefix`` / ``import_prefix`` (P/D disaggregation — see
     ``serving/replica.py`` for the wire).
 
     ``tenant``/``tier`` are optional: tier "latency" admits ahead of
-    "batch" (and may preempt batch work's blocks on a paged engine); a
+    "batch" (and may preempt batch work's blocks); a
     malformed tier is rejected with a counted reason and an error
     line, never a traceback. ``--tenant-budget acme=4096``
     (repeatable) caps a tenant's in-flight tokens — exhaustion queues.
@@ -364,13 +364,8 @@ def job_serve(args):
     except ValueError as e:
         print(f"serve: {e}", file=sys.stderr)
         return 1
-    if budgets:
-        if not hasattr(eng, "set_tenant_budget"):
-            print("serve: --tenant-budget needs a paged-engine "
-                  "artifact (format v4+)", file=sys.stderr)
-            return 1
-        for tenant, tokens in budgets.items():
-            eng.set_tenant_budget(tenant, tokens)
+    for tenant, tokens in budgets.items():
+        eng.set_tenant_budget(tenant, tokens)
     if args.ttft_slo_ms:
         from paddle_tpu.observe import SloConfig
         eng.configure_slo(SloConfig(
@@ -479,8 +474,8 @@ def job_route(args):
                 handles.append(_replica.SocketReplica(
                     f"replica{i}", (parts[0], int(parts[1])),
                     health_url))
-            # placement keying comes from the engines themselves: the
-            # paged /healthz reports block_size + chunk_tokens
+            # placement keying comes from the engines themselves:
+            # /healthz reports block_size + chunk_tokens
             bs, chunk = fleet_keying(handles)
             prefill = [h.name for h in
                        handles[:max(args.prefill_replicas, 0)]]
@@ -1026,8 +1021,9 @@ def main(argv=None):
     p.add_argument("--save_dir", default=None)
     p.add_argument("--init_model_path", default=None)
     p.add_argument("--model", default=None,
-                   help="merged-model artifact for job=infer / format-v3 "
-                        "lm_serving artifact for job=serve")
+                   help="merged-model artifact for job=infer / "
+                        "lm_serving artifact exported with "
+                        "engine_buckets= (format v4/v5) for job=serve")
     p.add_argument("--max_new", type=int, default=64,
                    help="default max_new for job=serve/route requests "
                         "that omit it")
@@ -1127,8 +1123,7 @@ def main(argv=None):
                    help="job=serve: cap TENANT's reserved tokens in "
                         "flight (prompt+max_new of live requests); "
                         "repeatable. Exhaustion queues the tenant's "
-                        "requests — it never rejects. Paged-engine "
-                        "artifacts only.")
+                        "requests — it never rejects.")
     p.add_argument("--tiers_dram_mb", type=float, default=0.0,
                    help="job=serve: host-DRAM spill tier budget in MB "
                         "(0 disables tiered spill). LRU-evicted prefix "

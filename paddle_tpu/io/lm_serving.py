@@ -30,13 +30,12 @@ from paddle_tpu.observe import trace as _trace
 from paddle_tpu.serving import blocks as _blocks
 
 FORMAT_VERSION = 5   # max supported; plain artifacts still save as v1,
-#                      int8-weight ones as v2; v3 adds the continuous-
-#                      batching engine modules (slot prefill per bucket +
-#                      vector-position decode with on-device sampling);
-#                      v4 replaces them with the PAGED engine modules
-#                      (chunked block-pool prefill per chunk bucket +
-#                      page-table decode — prefix caching and chunked
-#                      prefill are host-side scheduling over them);
+#                      int8-weight ones as v2; v4 adds the PAGED engine
+#                      modules (chunked block-pool prefill per chunk
+#                      bucket + page-table decode with on-device
+#                      sampling — prefix caching and chunked prefill
+#                      are host-side scheduling over them; v3 was the
+#                      row-arena engine's, no longer written or served);
 #                      v5 additionally stamps a DRAFT model for
 #                      speculative decoding (draft params + its chunk
 #                      prefill / fused k-step propose / batched verify
@@ -116,7 +115,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                      platforms: Optional[Sequence[str]] = None,
                      weights_int8: bool = False,
                      engine_buckets: Optional[Sequence[int]] = None,
-                     engine_paged: bool = False,
+                     engine_paged: bool = True,
                      engine_block_size: int = _blocks.DEFAULT_BLOCK_SIZE,
                      engine_num_blocks: Optional[int] = None,
                      engine_kv_dtype: Optional[str] = None,
@@ -139,24 +138,22 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     int8 (see quantize_lm_params) — the exported modules dequantize
     inline, so the loader and LMServer are unchanged.
     ``engine_buckets`` additionally exports the continuous-batching
-    engine programs (format v3): one slot-prefill module per prompt
-    bucket plus one per-slot-position decode module with on-device
-    greedy/temperature/top-k sampling; ``LMServer.engine()`` schedules
-    over them. ``batch`` doubles as the KV-arena slot count. v1/v2
-    artifacts keep loading into the legacy lockstep path unchanged.
-    ``engine_paged=True`` exports the PAGED engine instead (format v4):
-    ``engine_buckets`` become CHUNK buckets, one
+    engine's programs (format v4), with on-device
+    greedy/temperature/top-k sampling: the CHUNK buckets, one
     ``engine_prefill_paged_<C>_<P>.bin`` chunk-prefill module per
     (chunk bucket C, page-vector length P) pair on the fixed chunk grid
     (``max(engine_buckets)`` tokens — the context span a chunk attends
     over is encoded in its page-vector SHAPE), plus
-    one ``engine_decode_paged.bin`` page-table decode; the KV pool is
-    ``engine_num_blocks`` (default ``batch * cache_len/block_size``,
-    HBM parity with the v3 arena) blocks of ``engine_block_size``
-    tokens. ``LMServer.engine()`` then schedules a
+    one ``engine_decode_paged.bin`` page-table decode; ``batch`` is the
+    engine's slot count and the KV pool is
+    ``engine_num_blocks`` (default ``batch * cache_len/block_size``)
+    blocks of ``engine_block_size``
+    tokens. ``LMServer.engine()`` schedules a
     ``serving.PagedDecodeEngine`` (chunked prefill + prefix cache)
-    over them; v3 artifacts keep loading into the legacy slot engine.
-    ``engine_kv_dtype`` ("int8"/"int4", paged only) exports the engine
+    over them; without ``engine_buckets`` the artifact holds the
+    lockstep pair alone (v1/v2). ``engine_paged`` accepts only ``True``
+    (there is no other engine; the keyword waits on its two callers).
+    ``engine_kv_dtype`` ("int8"/"int4") exports the engine
     modules over a QUANTIZED pool (``transformer.init_block_pool``
     kv_dtype semantics: int8 / nibble-packed values + per-(position,
     head) fp32 scale tables): the stamp lands in
@@ -173,21 +170,25 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     if cache_len > cfg.max_len:
         raise ValueError(f"cache_len {cache_len} exceeds cfg.max_len "
                          f"{cfg.max_len}")
-    if engine_kv_dtype and not engine_paged:
-        # checked up front, NOT inside the engine-export branch: an
-        # export that silently dropped the requested quantized pool
-        # would only be discovered at serve time
-        raise ValueError("engine_kv_dtype needs engine_paged=True "
-                         "(the quantized pool is a paged-engine "
-                         "layout)")
+    if not engine_paged:
+        raise ValueError("engine_paged=False: the row-arena engine and "
+                         "its artifact format v3 are gone (PR 28) — "
+                         "engine_buckets= exports the paged engine's "
+                         "modules; drop the keyword")
+    if not engine_buckets:
+        # checked up front: an export that silently dropped the
+        # requested quantized pool or draft would only be discovered at
+        # serve time
+        for on, what in ((engine_kv_dtype, "engine_kv_dtype"),
+                         (engine_draft_params is not None,
+                          "engine_draft_params")):
+            if on:
+                raise ValueError(f"{what} needs engine_buckets= "
+                                 f"(the chunk buckets to export)")
     if (engine_draft_params is None) != (engine_draft_config is None):
         raise ValueError("engine_draft_params and engine_draft_config "
                          "come together (the draft model for "
                          "speculative decoding)")
-    if engine_draft_params is not None and not engine_paged:
-        raise ValueError("engine_draft_params needs engine_paged=True "
-                         "(speculative decoding rides the paged block "
-                         "table)")
     if engine_draft_config is not None \
             and engine_draft_config.vocab != cfg.vocab:
         raise ValueError(f"draft vocab {engine_draft_config.vocab} != "
@@ -197,13 +198,13 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     if hybrid:
         # the skeleton runs through the paged engine's programs alone
         # (bf16 weight leaves, the model's own KV width); the lockstep
-        # pair and the slot engine are not exported for it
+        # pair is not exported for it
         for on, what in ((weights_int8, "int8 weights"),
                          (engine_kv_dtype, "an int8 / int4 KV pool"),
                          (engine_draft_params is not None,
                           "speculative decoding"),
-                         (not engine_paged, "an artifact without "
-                          "engine_paged=True")):
+                         (not engine_buckets, "an artifact without "
+                          "engine_buckets")):
             if on:
                 transformer.require_gpt2(cfg, what)
     if weights_int8:
@@ -243,15 +244,10 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         lockstep = {"prefill.bin": exp_prefill.serialize(),
                     "decode.bin": exp_decode.serialize()}
 
-    # format-v3 engine programs: slot prefill per bucket + one vector-
-    # position decode step with the sampler fused in (token ids are the
-    # only host-bound output); format v4 swaps them for the PAGED pair
-    # (chunk prefill per chunk bucket + page-table decode)
+    # the engine's programs: chunk prefill per (chunk bucket, context
+    # span) + one page-table decode step, the sampler fused into both
+    # (token ids are the only host-bound output)
     engine_members = {}
-    engine_paged_meta = None
-    if engine_paged and not engine_buckets:
-        raise ValueError("engine_paged=True needs engine_buckets= "
-                         "(the chunk buckets to export)")
     if engine_buckets:
         from paddle_tpu.ops.pallas import policy as _pallas_policy
         from paddle_tpu.serving import sampling as _sampling
@@ -276,135 +272,119 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         def _vec(dt):
             return jax.ShapeDtypeStruct((batch,), dt)
 
-        def _eng_decode_args(kv_shapes, *extra):
-            # shared decode signature (tokens, pos, active, [pages,]
-            # temperature, top_k, seed) — one spot to extend for both
-            # the slot and paged exports
-            return (p_shapes, kv_shapes, _vec(jnp.int32),
-                    _vec(jnp.int32), _vec(jnp.bool_), *extra,
-                    _vec(jnp.float32), _vec(jnp.int32), i32)
-        if engine_paged:
-            bs = int(engine_block_size)
-            if bs < 1 or cache_len % bs:
-                raise ValueError(f"cache_len {cache_len} must be a "
-                                 f"positive multiple of "
-                                 f"engine_block_size {bs}")
-            pages = cache_len // bs
-            nb = int(engine_num_blocks if engine_num_blocks is not None
-                     else batch * pages)
-            chunk = max(buckets)        # the engine's prefill chunk grid
-            if chunk % bs or cache_len % chunk:
-                raise ValueError(
-                    f"paged export needs block_size {bs} | chunk "
-                    f"{chunk} | cache_len {cache_len} (each dividing "
-                    f"the next): the chunk grid anchors the exported "
-                    f"context spans")
-            engine_paged_meta = {"block_size": bs, "num_blocks": nb,
-                                 "pages_per_slot": pages,
-                                 "chunk_tokens": chunk,
-                                 "pallas": engine_pallas,
-                                 "kv_dtype": engine_kv_dtype or "none",
-                                 # the pool array layout the modules
-                                 # were shaped against — the loader
-                                 # refuses to schedule programs from a
-                                 # different layout generation (the
-                                 # pre-relayout slot-major pool)
-                                 "pool_layout":
-                                     transformer.POOL_LAYOUT}
-            eng_prefill, eng_decode = _sampling.paged_step_fns(
-                cfg, bs, dequant=dequant, pallas=engine_pallas)
-            pool_shapes = jax.tree_util.tree_map(
+        bs = int(engine_block_size)
+        if bs < 1 or cache_len % bs:
+            raise ValueError(f"cache_len {cache_len} must be a "
+                             f"positive multiple of "
+                             f"engine_block_size {bs}")
+        pages = cache_len // bs
+        nb = int(engine_num_blocks if engine_num_blocks is not None
+                 else batch * pages)
+        chunk = max(buckets)        # the engine's prefill chunk grid
+        if chunk % bs or cache_len % chunk:
+            raise ValueError(
+                f"paged export needs block_size {bs} | chunk "
+                f"{chunk} | cache_len {cache_len} (each dividing "
+                f"the next): the chunk grid anchors the exported "
+                f"context spans")
+        engine_paged_meta = {"block_size": bs, "num_blocks": nb,
+                             "pages_per_slot": pages,
+                             "chunk_tokens": chunk,
+                             "pallas": engine_pallas,
+                             "kv_dtype": engine_kv_dtype or "none",
+                             # the pool array layout the modules
+                             # were shaped against — the loader
+                             # refuses to schedule programs from a
+                             # different layout generation (the
+                             # pre-relayout slot-major pool)
+                             "pool_layout":
+                                 transformer.POOL_LAYOUT}
+        eng_prefill, eng_decode = _sampling.paged_step_fns(
+            cfg, bs, dequant=dequant, pallas=engine_pallas)
+        pool_shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            transformer.init_block_pool(
+                cfg, nb, bs, kv_dtype=engine_kv_dtype, slots=batch))
+        # one chunk-prefill module per (bucket, context span) the
+        # fixed chunk grid can reach: a chunk's context length is
+        # encoded in its page-vector SHAPE (span specialization —
+        # cold chunks attend over C tokens, not cache_len), so each
+        # (C, P) pair is its own AOT program
+        for ctx in range(0, cache_len, chunk):
+            for b in buckets:
+                pv = ctx // bs + -(-b // bs)
+                ep = jax.export.export(jax.jit(eng_prefill), **kw)(
+                    p_shapes, pool_shapes,
+                    jax.ShapeDtypeStruct((1, b), jnp.int32), i32,
+                    jax.ShapeDtypeStruct((pv,), jnp.int32),
+                    *((i32,) if hybrid else ()),    # the slot
+                    f32, i32, i32)
+                engine_members[
+                    f"engine_prefill_paged_{b}_{pv}.bin"] = \
+                    ep.serialize()
+        # the decode signature: tokens, pos, active, pages,
+        # temperature, top_k, seed
+        eng_decode_args = (
+            p_shapes, pool_shapes, _vec(jnp.int32), _vec(jnp.int32),
+            _vec(jnp.bool_),
+            jax.ShapeDtypeStruct((batch, pages), jnp.int32),
+            _vec(jnp.float32), _vec(jnp.int32), i32)
+        if engine_draft_params is not None:
+            # v5: the draft's program set — chunk prefill mirroring
+            # the target grid, fused k-step propose, the target's
+            # batched verify, and the draft-side forced-window
+            # write the preempt-resume replay needs
+            dcfg = engine_draft_config
+            k = int(engine_spec_k)
+            W = k + 1
+            spec = _sampling.paged_spec_fns(
+                cfg, dcfg, bs, k, dequant=dequant,
+                pallas=engine_pallas,
+                paths=eng_decode.kernel_paths)
+            dp_shapes = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a),
+                    a.dtype if hasattr(a, "dtype")
+                    else np.asarray(a).dtype), engine_draft_params)
+            dpool_shapes = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                transformer.init_block_pool(
-                    cfg, nb, bs, kv_dtype=engine_kv_dtype, slots=batch))
-            # one chunk-prefill module per (bucket, context span) the
-            # fixed chunk grid can reach: a chunk's context length is
-            # encoded in its page-vector SHAPE (span specialization —
-            # cold chunks attend over C tokens, not cache_len), so each
-            # (C, P) pair is its own AOT program
+                transformer.init_block_pool(dcfg, nb, bs))
             for ctx in range(0, cache_len, chunk):
                 for b in buckets:
                     pv = ctx // bs + -(-b // bs)
-                    ep = jax.export.export(jax.jit(eng_prefill), **kw)(
-                        p_shapes, pool_shapes,
-                        jax.ShapeDtypeStruct((1, b), jnp.int32), i32,
-                        jax.ShapeDtypeStruct((pv,), jnp.int32),
-                        *((i32,) if hybrid else ()),    # the slot
-                        f32, i32, i32)
+                    ep = jax.export.export(
+                        jax.jit(spec["draft_prefill"]), **kw)(
+                        dp_shapes, dpool_shapes,
+                        jax.ShapeDtypeStruct((1, b), jnp.int32),
+                        i32,
+                        jax.ShapeDtypeStruct((pv,), jnp.int32))
                     engine_members[
-                        f"engine_prefill_paged_{b}_{pv}.bin"] = \
+                        f"engine_draft_prefill_{b}_{pv}.bin"] = \
                         ep.serialize()
-            eng_decode_args = _eng_decode_args(
-                pool_shapes,
-                jax.ShapeDtypeStruct((batch, pages), jnp.int32))
-            eng_decode_member = "engine_decode_paged.bin"
-            if engine_draft_params is not None:
-                # v5: the draft's program set — chunk prefill mirroring
-                # the target grid, fused k-step propose, the target's
-                # batched verify, and the draft-side forced-window
-                # write the preempt-resume replay needs
-                dcfg = engine_draft_config
-                k = int(engine_spec_k)
-                W = k + 1
-                spec = _sampling.paged_spec_fns(
-                    cfg, dcfg, bs, k, dequant=dequant,
-                    pallas=engine_pallas,
-                    paths=eng_decode.kernel_paths)
-                dp_shapes = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(
-                        np.shape(a),
-                        a.dtype if hasattr(a, "dtype")
-                        else np.asarray(a).dtype), engine_draft_params)
-                dpool_shapes = jax.tree_util.tree_map(
-                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                    transformer.init_block_pool(dcfg, nb, bs))
-                for ctx in range(0, cache_len, chunk):
-                    for b in buckets:
-                        pv = ctx // bs + -(-b // bs)
-                        ep = jax.export.export(
-                            jax.jit(spec["draft_prefill"]), **kw)(
-                            dp_shapes, dpool_shapes,
-                            jax.ShapeDtypeStruct((1, b), jnp.int32),
-                            i32,
-                            jax.ShapeDtypeStruct((pv,), jnp.int32))
-                        engine_members[
-                            f"engine_draft_prefill_{b}_{pv}.bin"] = \
-                            ep.serialize()
-                pages_s = jax.ShapeDtypeStruct((batch, pages),
-                                               jnp.int32)
-                win_s = jax.ShapeDtypeStruct((batch, W), jnp.int32)
-                engine_members["engine_propose.bin"] = \
-                    jax.export.export(jax.jit(spec["propose"]), **kw)(
-                        dp_shapes, dpool_shapes, _vec(jnp.int32),
-                        _vec(jnp.int32), _vec(jnp.bool_),
-                        _vec(jnp.int32), pages_s).serialize()
-                jit_verify = jax.jit(spec["verify"])
-                verify_args = (p_shapes, pool_shapes, win_s,
-                               _vec(jnp.int32), _vec(jnp.int32),
-                               _vec(jnp.bool_), pages_s,
-                               _vec(jnp.float32), _vec(jnp.int32), i32)
-                engine_members["engine_verify.bin"] = \
-                    jax.export.export(jit_verify, **kw)(
-                        *verify_args).serialize()
-                engine_members["engine_draft_verify.bin"] = \
-                    jax.export.export(
-                        jax.jit(spec["draft_verify"]), **kw)(
-                        dp_shapes, dpool_shapes, win_s,
-                        _vec(jnp.int32), _vec(jnp.int32),
-                        _vec(jnp.bool_), pages_s).serialize()
-        else:
-            eng_prefill, eng_decode = _sampling.engine_step_fns(
-                cfg, dequant=dequant, pallas=engine_pallas)
-            for b in buckets:
-                ep = jax.export.export(jax.jit(eng_prefill), **kw)(
-                    p_shapes, cache_shapes,
-                    jax.ShapeDtypeStruct((1, b), jnp.int32),
-                    i32, i32, f32, i32, i32)
-                engine_members[f"engine_prefill_{b}.bin"] = ep.serialize()
-            eng_decode_args = _eng_decode_args(cache_shapes)
-            eng_decode_member = "engine_decode.bin"
+            pages_s = jax.ShapeDtypeStruct((batch, pages),
+                                           jnp.int32)
+            win_s = jax.ShapeDtypeStruct((batch, W), jnp.int32)
+            engine_members["engine_propose.bin"] = \
+                jax.export.export(jax.jit(spec["propose"]), **kw)(
+                    dp_shapes, dpool_shapes, _vec(jnp.int32),
+                    _vec(jnp.int32), _vec(jnp.bool_),
+                    _vec(jnp.int32), pages_s).serialize()
+            jit_verify = jax.jit(spec["verify"])
+            verify_args = (p_shapes, pool_shapes, win_s,
+                           _vec(jnp.int32), _vec(jnp.int32),
+                           _vec(jnp.bool_), pages_s,
+                           _vec(jnp.float32), _vec(jnp.int32), i32)
+            engine_members["engine_verify.bin"] = \
+                jax.export.export(jit_verify, **kw)(
+                    *verify_args).serialize()
+            engine_members["engine_draft_verify.bin"] = \
+                jax.export.export(
+                    jax.jit(spec["draft_verify"]), **kw)(
+                    dp_shapes, dpool_shapes, win_s,
+                    _vec(jnp.int32), _vec(jnp.int32),
+                    _vec(jnp.bool_), pages_s).serialize()
         jit_eng_decode = jax.jit(eng_decode)
-        engine_members[eng_decode_member] = jax.export.export(
+        engine_members["engine_decode_paged.bin"] = jax.export.export(
             jit_eng_decode, **kw)(*eng_decode_args).serialize()
 
     # per-phase cost accounting, stamped into the artifact at export
@@ -429,9 +409,9 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         # quantized artifacts carry nested {"q8","scale"} params — a v2
         # encoding; plain artifacts stay v1 for older loaders; engine
         # modules (whose member names older loaders would not recognise)
-        # bump to v3; paged engine modules to v4; a stamped draft to v5
-        "format_version": (5 if engine_draft_params is not None
-                           else 4 if engine_paged else 3)
+        # bump to v4 (3 was the row-arena engine's); a stamped draft to
+        # v5
+        "format_version": (5 if engine_draft_params is not None else 4)
         if engine_buckets else (2 if weights_int8 else 1),
         "batch": batch, "prompt_len": prompt_len, "cache_len": cache_len,
         "weights_int8": weights_int8, "config": _cfg_to_dict(cfg),
@@ -440,7 +420,6 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         meta["engine_buckets"] = buckets
         meta["engine_pallas"] = engine_pallas
         meta["engine_kernel_paths"] = eng_decode.kernel_paths
-    if engine_paged_meta:
         meta["engine_paged"] = engine_paged_meta
     draft_blob = None
     if engine_draft_params is not None:
@@ -504,9 +483,9 @@ class LMServer:
         # serves through engine() alone)
         self._prefill = prefill_bin and jax.export.deserialize(prefill_bin)
         self._decode = decode_bin and jax.export.deserialize(decode_bin)
-        # format-v3 continuous-batching modules (absent on v1/v2):
-        # deserialized lazily by engine() — lockstep-only consumers of a
-        # v3 artifact pay nothing for them
+        # the continuous-batching engine's modules (absent on v1/v2):
+        # deserialized lazily by engine() — lockstep-only consumers of
+        # the artifact pay nothing for them
         self._engine_bins = dict(engine_bins or {})
         self.engine_buckets = tuple(meta.get("engine_buckets", ()))
         reg = self.metrics = _metrics.Registry()
@@ -581,17 +560,17 @@ class LMServer:
                tracker=None, chunk_tokens: Optional[int] = None,
                tiers=None):
         """Continuous-batching engine over this artifact's modules:
-        a ``serving.PagedDecodeEngine`` for format-v4 artifacts (paged
-        block pool + chunked prefill + prefix cache; the chunk grid is
-        the artifact's — ``chunk_tokens`` may only restate it, the
-        prefill modules are span-specialized), the legacy
-        ``serving.DecodeEngine`` for format-v3 (whole-row arena).
-        Raises on v1/v2 artifacts — re-export with
-        ``engine_buckets=`` to serve continuously; ``generate()`` stays
-        the lockstep fallback either way."""
-        import jax.numpy as jnp
-        from paddle_tpu.serving.engine import (DecodeEngine,
-                                               PagedDecodeEngine)
+        a ``serving.PagedDecodeEngine`` (paged block pool + chunked
+        prefill + prefix cache; the chunk grid is the artifact's —
+        ``chunk_tokens`` may only restate it, the prefill modules are
+        span-specialized), a ``serving.SpecDecodeEngine`` when the
+        artifact stamps a draft (v5). Raises on v1/v2 artifacts and on
+        a v3 one (the row-arena engine's modules, which nothing runs
+        any more) — re-export with ``engine_buckets=`` to serve
+        continuously; ``generate()`` stays the lockstep fallback."""
+        from paddle_tpu.models import transformer
+        from paddle_tpu.serving.engine import (PagedDecodeEngine,
+                                               SpecDecodeEngine)
         if not self._engine_bins:
             raise ValueError(
                 f"artifact (format v{self.meta['format_version']}) has "
@@ -600,144 +579,114 @@ class LMServer:
                 f"continuous batching")
         cfg = self.cfg
         paged = self.meta.get("engine_paged")
-        if paged:
-            # layout fencing: the exported modules bake the pool array
-            # shapes, so a legacy slot-major artifact (pre-head-major
-            # relayout; no pool_layout stamp) cannot be scheduled over
-            # the pool this build constructs — the failure would
-            # otherwise surface as an opaque shape mismatch at the
-            # first prefill call
-            from paddle_tpu.models import transformer
-            stamped = paged.get("pool_layout", "slot_major")
-            if stamped != transformer.POOL_LAYOUT:
-                raise ValueError(
-                    f"artifact's paged-engine modules were exported "
-                    f"against a {stamped!r} KV pool but this build "
-                    f"uses {transformer.POOL_LAYOUT!r} — re-export "
-                    f"with save_lm_artifact(..., engine_paged=True) "
-                    f"to serve it")
-            meta_chunk = int(paged.get("chunk_tokens",
-                                       max(self.engine_buckets)))
-            if chunk_tokens is not None and int(chunk_tokens) != \
-                    meta_chunk:
-                raise ValueError(
-                    f"artifact exported on a chunk grid of "
-                    f"{meta_chunk} tokens (its prefill modules are "
-                    f"(bucket, context-span)-specialized); "
-                    f"chunk_tokens={chunk_tokens} has no programs — "
-                    f"re-export to change the grid")
-            prefills = {}
-            for name in self._engine_bins:
-                if not name.startswith("engine_prefill_paged_"):
-                    continue
-                b, pv = name[len("engine_prefill_paged_"):
-                             -len(".bin")].split("_")
-                prefills[(int(b), int(pv))] = self._engine_program(
-                    name, donate_pool=True)
-            decode = self._engine_program("engine_decode_paged.bin",
-                                          donate_pool=True)
-
-            def prefill(params, pool, tokens, length, pagevec, *rest):
-                key = (tokens.shape[1], pagevec.shape[0])
-                return prefills[key](params, pool, tokens, length,
-                                     pagevec, *rest)
-
-            # zero-filled block pool from the meta geometry + kv_dtype
-            # stamp, built by the SAME constructor the export shaped
-            # the modules against (one source of truth for the pool
-            # layout — the loader already imports the transformer
-            # module for TransformerConfig, so this adds no dependency)
-            from paddle_tpu.models import transformer
-            kvd = paged.get("kv_dtype", "none")
-            if kvd == "none":
-                kvd = None
-            pool = transformer.init_block_pool(
-                cfg, paged["num_blocks"], paged["block_size"],
-                kv_dtype=kvd, slots=self.meta["batch"])
-            eng_kw = dict(
-                batch=self.meta["batch"],
-                cache_len=self.meta["cache_len"],
-                block_size=paged["block_size"],
-                num_blocks=paged["num_blocks"],
-                chunk_tokens=meta_chunk,
-                chunk_buckets=self.engine_buckets, seed=seed,
-                registry=registry, tracker=tracker,
-                decode_flops=self.cost_analysis.get(
-                    "engine_decode", {}).get("flops"),
-                pallas_mode=self.meta.get("engine_pallas"),
-                kernel_paths=self.meta.get("engine_kernel_paths"),
-                kv_dtype=kvd, tiers=tiers)
-            spec = self.meta.get("engine_spec")
-            if spec:
-                # v5: schedule the SpecDecodeEngine over the stamped
-                # draft — its pool rebuilt from the draft config at
-                # the SAME block geometry (one page table, two pools)
-                from paddle_tpu.serving.engine import SpecDecodeEngine
-                dcfg = _cfg_from_dict(spec["draft_config"])
-                draft_pool = transformer.init_block_pool(
-                    dcfg, paged["num_blocks"], paged["block_size"])
-                dprefills = {}
-                for name in self._engine_bins:
-                    if not name.startswith("engine_draft_prefill_"):
-                        continue
-                    b, pv = name[len("engine_draft_prefill_"):
-                                 -len(".bin")].split("_")
-                    dprefills[(int(b), int(pv))] = \
-                        self._engine_program(name)
-
-                def draft_prefill(dp, dpool, tokens, length, pagevec):
-                    key = (tokens.shape[1], pagevec.shape[0])
-                    return dprefills[key](dp, dpool, tokens, length,
-                                          pagevec)
-
-                eng_kw["decode_flops"] = self.cost_analysis.get(
-                    "engine_verify", {}).get(
-                    "flops", eng_kw["decode_flops"])
-                return SpecDecodeEngine(
-                    prefill, decode, self.params, pool,
-                    draft_params=self.draft_params,
-                    draft_cache=draft_pool,
-                    draft_prefill=draft_prefill,
-                    propose=self._engine_program("engine_propose.bin"),
-                    verify=self._engine_program("engine_verify.bin"),
-                    draft_verify=self._engine_program(
-                        "engine_draft_verify.bin"),
-                    spec_k=spec["k"], **eng_kw)
-            return PagedDecodeEngine(
-                prefill, decode, self.params, pool, **eng_kw)
-        if chunk_tokens is not None:
+        if not paged:
             raise ValueError(
-                f"chunk_tokens={chunk_tokens}: this artifact (format "
-                f"v{self.meta['format_version']}) has no paged engine "
-                f"modules, so prefill cannot be chunked — re-export "
-                f"with save_lm_artifact(..., engine_paged=True)")
-        if tiers is not None:
+                f"artifact (format v{self.meta['format_version']}) "
+                f"holds the row-arena engine's modules, which this "
+                f"build no longer runs — re-export with "
+                f"save_lm_artifact(..., engine_buckets=(...)) to "
+                f"serve it")
+        # layout fencing: the exported modules bake the pool array
+        # shapes, so a legacy slot-major artifact (pre-head-major
+        # relayout; no pool_layout stamp) cannot be scheduled over
+        # the pool this build constructs — the failure would
+        # otherwise surface as an opaque shape mismatch at the
+        # first prefill call
+        stamped = paged.get("pool_layout", "slot_major")
+        if stamped != transformer.POOL_LAYOUT:
             raise ValueError(
-                "tiered spill (tiers=) needs a paged-engine artifact "
-                "— the row arena has no block pool to demote from")
-        prefills = {b: self._engine_program(f"engine_prefill_{b}.bin")
-                    for b in self.engine_buckets}
-        decode = self._engine_program("engine_decode.bin")
+                f"artifact's paged-engine modules were exported "
+                f"against a {stamped!r} KV pool but this build "
+                f"uses {transformer.POOL_LAYOUT!r} — re-export "
+                f"with save_lm_artifact(..., engine_buckets=(...)) "
+                f"to serve it")
+        meta_chunk = int(paged.get("chunk_tokens",
+                                   max(self.engine_buckets)))
+        if chunk_tokens is not None and int(chunk_tokens) != \
+                meta_chunk:
+            raise ValueError(
+                f"artifact exported on a chunk grid of "
+                f"{meta_chunk} tokens (its prefill modules are "
+                f"(bucket, context-span)-specialized); "
+                f"chunk_tokens={chunk_tokens} has no programs — "
+                f"re-export to change the grid")
+        prefills = {}
+        for name in self._engine_bins:
+            if not name.startswith("engine_prefill_paged_"):
+                continue
+            b, pv = name[len("engine_prefill_paged_"):
+                         -len(".bin")].split("_")
+            prefills[(int(b), int(pv))] = self._engine_program(
+                name, donate_pool=True)
+        decode = self._engine_program("engine_decode_paged.bin",
+                                      donate_pool=True)
 
-        def prefill(params, cache, tokens, *rest):
-            return prefills[tokens.shape[1]](params, cache, tokens,
-                                             *rest)
+        def prefill(params, pool, tokens, length, pagevec, *rest):
+            key = (tokens.shape[1], pagevec.shape[0])
+            return prefills[key](params, pool, tokens, length,
+                                 pagevec, *rest)
 
-        # zero-filled KV arena straight from the meta (no model code —
-        # the shape is determined by the config alone)
-        shape = (cfg.n_layers, self.meta["batch"], self.meta["cache_len"],
-                 cfg.kv_heads, cfg.head_dim)
-        cache = {"k": jnp.zeros(shape, cfg.dtype),
-                 "v": jnp.zeros(shape, cfg.dtype)}
-        return DecodeEngine(
-            prefill, decode, self.params, cache,
-            batch=self.meta["batch"], cache_len=self.meta["cache_len"],
-            buckets=self.engine_buckets, seed=seed, registry=registry,
-            tracker=tracker,
+        # zero-filled block pool from the meta geometry + kv_dtype
+        # stamp, built by the SAME constructor the export shaped
+        # the modules against (one source of truth for the pool
+        # layout — the loader already imports the transformer
+        # module for TransformerConfig, so this adds no dependency)
+        kvd = paged.get("kv_dtype", "none")
+        if kvd == "none":
+            kvd = None
+        pool = transformer.init_block_pool(
+            cfg, paged["num_blocks"], paged["block_size"],
+            kv_dtype=kvd, slots=self.meta["batch"])
+        eng_kw = dict(
+            batch=self.meta["batch"],
+            cache_len=self.meta["cache_len"],
+            block_size=paged["block_size"],
+            num_blocks=paged["num_blocks"],
+            chunk_tokens=meta_chunk,
+            chunk_buckets=self.engine_buckets, seed=seed,
+            registry=registry, tracker=tracker,
             decode_flops=self.cost_analysis.get(
                 "engine_decode", {}).get("flops"),
             pallas_mode=self.meta.get("engine_pallas"),
-            kernel_paths=self.meta.get("engine_kernel_paths"))
+            kernel_paths=self.meta.get("engine_kernel_paths"),
+            kv_dtype=kvd, tiers=tiers)
+        spec = self.meta.get("engine_spec")
+        if spec:
+            # v5: schedule the SpecDecodeEngine over the stamped
+            # draft — its pool rebuilt from the draft config at
+            # the SAME block geometry (one page table, two pools)
+            dcfg = _cfg_from_dict(spec["draft_config"])
+            draft_pool = transformer.init_block_pool(
+                dcfg, paged["num_blocks"], paged["block_size"])
+            dprefills = {}
+            for name in self._engine_bins:
+                if not name.startswith("engine_draft_prefill_"):
+                    continue
+                b, pv = name[len("engine_draft_prefill_"):
+                             -len(".bin")].split("_")
+                dprefills[(int(b), int(pv))] = \
+                    self._engine_program(name)
+
+            def draft_prefill(dp, dpool, tokens, length, pagevec):
+                key = (tokens.shape[1], pagevec.shape[0])
+                return dprefills[key](dp, dpool, tokens, length,
+                                      pagevec)
+
+            eng_kw["decode_flops"] = self.cost_analysis.get(
+                "engine_verify", {}).get(
+                "flops", eng_kw["decode_flops"])
+            return SpecDecodeEngine(
+                prefill, decode, self.params, pool,
+                draft_params=self.draft_params,
+                draft_cache=draft_pool,
+                draft_prefill=draft_prefill,
+                propose=self._engine_program("engine_propose.bin"),
+                verify=self._engine_program("engine_verify.bin"),
+                draft_verify=self._engine_program(
+                    "engine_draft_verify.bin"),
+                spec_k=spec["k"], **eng_kw)
+        return PagedDecodeEngine(
+            prefill, decode, self.params, pool, **eng_kw)
 
     def generate(self, prompt: np.ndarray, max_new: int,
                  temperature: float = 0.0,

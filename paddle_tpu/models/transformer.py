@@ -529,7 +529,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     [L, B, max_len, kv_heads, Dh] (kv_heads < n_heads under GQA)
     (the serving-side analog of the reference's recurrent generation
     machinery, trainer/tests/test_recurrent_machine_generation.cpp slot)."""
-    require_gpt2(cfg, "init_cache (the row-arena KV cache)")
+    require_gpt2(cfg, "init_cache (the lockstep KV cache)")
     shape = (cfg.n_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -775,139 +775,6 @@ def decode_step(params, cache, tokens: jax.Array, pos: jax.Array,
     return logits, {"k": kn, "v": vn}
 
 
-def prefill_into_slot(params, cache, tokens: jax.Array, length: jax.Array,
-                      slot: jax.Array, cfg: TransformerConfig, *,
-                      mesh: Optional[Mesh] = None):
-    """Prefill ONE request into arena row ``slot`` of a shared KV cache.
-
-    tokens [1, Tb] is the prompt right-padded to a bucket length Tb;
-    ``length`` (scalar int32, traced) is the true prompt length and
-    ``slot`` (scalar int32, traced) the arena row. Returns (logits at the
-    last real prompt position [1, vocab] fp32, updated cache). All shapes
-    are static, so the engine compiles ONCE per (bucket, arena) pair and
-    new requests join mid-flight without retracing.
-
-    Correctness of right-padding without a mask: KV projections are
-    per-position, and causal attention means padded positions only feed
-    their OWN outputs — the gathered position ``length - 1`` attends to
-    real tokens exclusively, so its logits are bitwise the unpadded
-    forward's. The padded rows' garbage KV lands at positions
-    ``length..Tb-1``, each of which is overwritten by a decode step
-    BEFORE any per-slot attention mask (``pos >= position``) can read it.
-    Rows other than ``slot`` are untouched (dynamic_update_slice writes a
-    1-row slab)."""
-    require_gpt2(cfg, "prefill_into_slot (the row-arena engine)")
-    if tokens.shape[0] != 1:
-        raise ValueError(f"prefill_into_slot takes one request "
-                         f"([1, Tb] tokens), got {tokens.shape}")
-    logits, (kc, vc) = _forward_impl(
-        params, tokens, cfg, mesh, None, True, head="gather",
-        gather_pos=jnp.reshape(length, (1,)) - 1)
-    slot = jnp.asarray(slot, jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    idx = (zero, slot, zero, zero, zero)
-    return logits[:, 0], {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], kc.astype(cache["k"].dtype), idx),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], vc.astype(cache["v"].dtype), idx)}
-
-
-def decode_step_slots(params, cache, tokens: jax.Array, pos: jax.Array,
-                      active: jax.Array, cfg: TransformerConfig):
-    """One incremental step with PER-SLOT positions: tokens [B] int32,
-    ``pos`` [B] int32 (each row's write/attend position) and ``active``
-    [B] bool → (logits [B, vocab] fp32, updated cache).
-
-    The continuous-batching variant of ``decode_step``: every arena row
-    advances independently, so requests of different lengths decode in
-    one compiled program. Inactive rows compute (harmlessly) but their
-    cache rows are NOT written — admission and recycling can't perturb
-    in-flight neighbours. For rows whose pos equals a lockstep call's
-    scalar pos, the arithmetic is elementwise identical to
-    ``decode_step``'s, so logits match bitwise (tested).
-
-    The block body deliberately mirrors ``decode_step``'s rather than
-    sharing it: the lockstep path keeps its cheaper scalar-index
-    ``dynamic_update_slice`` (and its exported v1/v2 artifact program),
-    while this variant needs per-row where-writes. The bitwise test in
-    tests/test_serving_engine.py pins the two against drifting.
-
-    ``params`` may carry int8 weights ({"q8","scale"} nodes from
-    ``io/lm_serving.quantize_lm_params``): they ride the layer scan as
-    int8 xs and dequantize inside the body (``_live_layer_weights``
-    anti-hoist defenses), so serving reads weights at 1 byte/elt."""
-    B = tokens.shape[0]
-    require_gpt2(cfg, "decode_step_slots (the row-arena engine)")
-    H, Dh = cfg.n_heads, cfg.head_dim
-    Hkv = cfg.kv_heads
-    kvd = Hkv * Dh
-    max_len = cache["k"].shape[2]
-    quantized = _blocks_quantized(params)
-    _pallas_policy.note_path("attention", _pallas_policy.PATH_XLA)
-    pos = jnp.asarray(pos, jnp.int32)
-    x = _embed_rows(params, tokens, cfg)
-    if not cfg.use_rope:
-        x = x + jnp.take(params["pos"], pos, axis=0).astype(cfg.dtype)
-    rope_tabs = _rope_tables(pos, Dh, cfg.rope_theta) \
-        if cfg.use_rope else None
-    # [B, max_len] one-hot write mask: row b writes position pos[b] only
-    # when active — a where() against the arena instead of
-    # dynamic_update_slice, because each row targets a different index
-    write = ((jnp.arange(max_len, dtype=jnp.int32)[None, :]
-              == pos[:, None]) & active[:, None])
-    attend = (jnp.arange(max_len, dtype=jnp.int32)[None, :]
-              <= pos[:, None])                          # [B, max_len]
-
-    def block(x, scanned):
-        w, li, kc, vc = scanned              # kc/vc [B, max_len, Hkv, Dh]
-        if quantized:
-            w = _live_layer_weights(w, li)
-        h = _layer_norm(x, w["ln1"], w["ln1_b"])
-        qkv = h @ w["qkv"].astype(h.dtype)   # [B, D + 2*kvd]
-        q, k, v = jnp.split(qkv, [H * Dh, H * Dh + kvd], axis=-1)
-        if cfg.use_rope:
-            q = _rope_rows(q.reshape(B, H, Dh), rope_tabs).reshape(
-                B, H * Dh)
-            k = _rope_rows(k.reshape(B, Hkv, Dh), rope_tabs).reshape(
-                B, kvd)
-        kc = jnp.where(write[:, :, None, None],
-                       k.reshape(B, 1, Hkv, Dh).astype(kc.dtype), kc)
-        vc = jnp.where(write[:, :, None, None],
-                       v.reshape(B, 1, Hkv, Dh).astype(vc.dtype), vc)
-        g = H // Hkv
-        q32 = q.reshape(B, Hkv, g, Dh).astype(jnp.float32)
-        s = jnp.einsum("bkgd,btkd->bkgt", q32,
-                       kc.astype(jnp.float32)) / math.sqrt(Dh)
-        s = jnp.where(attend[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bkgt,btkd->bkgd", p, vc.astype(jnp.float32))
-        attn = attn.reshape(B, cfg.d_model).astype(cfg.dtype)
-        x = x + attn @ w["attn_out"].astype(attn.dtype)
-        h2 = _layer_norm(x, w["ln2"], w["ln2_b"])
-        if cfg.moe_experts:
-            import dataclasses as _dc
-
-            from paddle_tpu.parallel import moe
-            mc = _dc.replace(cfg.moe_cfg(), capacity_factor=float(
-                cfg.moe_experts) / cfg.moe_top_k)
-            out, _ = moe.moe_ffn(
-                {"gate": w["gate"], "w_in": w["moe_w_in"],
-                 "w_out": w["moe_w_out"]}, h2, mc)
-            x = x + out.astype(x.dtype)
-        else:
-            ff = jax.nn.gelu(h2 @ w["mlp_in"].astype(h2.dtype))
-            x = x + ff @ w["mlp_out"].astype(ff.dtype)
-        return x, (kc, vc)
-
-    li = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    x, (kn, vn) = jax.lax.scan(block, x, (params["blocks"], li,
-                                          cache["k"], cache["v"]))
-    x = _layer_norm(x, params["ln_f"], params["ln_f_b"])
-    logits = _vocab_logits(x, params)
-    return logits, {"k": kn, "v": vn}
-
-
 def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
                       active: jax.Array, pages: jax.Array,
                       cfg: TransformerConfig, *, block_size: int,
@@ -917,8 +784,8 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     ``pos`` [B] int32, ``active`` [B] bool, ``pages`` [B, P] int32 block
     ids → (logits [B, vocab] fp32, updated pool).
 
-    The block-table variant of ``decode_step_slots``: the cache is the
-    head-major flat pool ``init_block_pool`` builds ([L, Hkv, M, Dh]
+    The block-table decode step, each row at its own position: the
+    cache is the head-major flat pool ``init_block_pool`` builds ([L, Hkv, M, Dh]
     with M = num_blocks·block_size) and each slot reads its KV through
     a gathered logical view ``[B, T]`` (T = P·block_size) built from
     its page vector — every shape static, so the engine still compiles
@@ -926,8 +793,7 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     k/v at the physical position ``pages[b, pos[b]//bs]·bs + pos[b]%bs``
     via a scatter whose inactive rows target an out-of-bounds index and
     are DROPPED (mode="drop") — admission/recycling can't perturb
-    in-flight neighbours, matching ``decode_step_slots``'s inactive-row
-    contract.
+    in-flight neighbours.
 
     THE POOL IS UPDATED IN PLACE. It rides the layer loop as the CARRY
     (never as scan ``xs``/``ys``: those are two buffers, and every
@@ -944,16 +810,17 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     A caller that DONATES the pool (the engine does, at ``jax.jit`` and
     around the exported call) gets the writes in its own buffer; the
     pool it passed is dead after the call. The gathered view
-    transposes to the ``[B, T, Hkv, Dh]`` shape the slot arena has, so
-    the attention arithmetic — and its bitwise contract against
-    ``decode_step_slots`` — is that of the slot path.
+    transposes to the ``[B, T, Hkv, Dh]`` shape the lockstep cache
+    (``init_cache``) has, so the attention arithmetic is that of
+    ``decode_step``.
 
-    For a slot whose pages tile a contiguous span (the identity mapping)
-    the gathered view IS the old arena row, T equals the arena's
-    cache_len, and every elementwise/reduction shape matches
-    ``decode_step_slots`` — logits and written cache values are bitwise
-    identical (pinned in tests/test_paged_engine.py), so the two decode
-    paths cannot drift.
+    For slots whose pages tile a contiguous span (the identity mapping)
+    and that share one position, the gathered view IS the lockstep
+    cache row, T equals its cache_len, and every elementwise/reduction
+    shape matches ``decode_step`` — active rows' logits and written
+    cache values are bitwise identical on the CPU (pinned in
+    tests/test_paged_engine.py), so the reference and the served path
+    cannot drift.
 
     ``pallas`` picks the attention engine through the package-wide
     ``PADDLE_TPU_PALLAS`` policy (explicit arg > env > auto): when it
@@ -1563,8 +1430,7 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
                 new_cache[n] = jax.lax.dynamic_update_slice(
                     new_cache[n], jnp.where(vmask, aj, old),
                     (0, 0, dst) + (0,) * (a.ndim - 3))
-    # only the last VALID chunk position feeds the vocab head (the
-    # gather-head discipline of prefill_into_slot)
+    # only the last VALID chunk position feeds the vocab head
     x = jnp.take(x, jnp.reshape(jnp.maximum(length - 1, 0), (1,)), axis=0)
     x = _layer_norm(x, params["ln_f"], params["ln_f_b"])
     logits = jnp.einsum("td,vd->tv", x.astype(jnp.float32),

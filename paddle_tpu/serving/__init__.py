@@ -2,14 +2,15 @@
 
 Public surface:
 
-- :class:`~paddle_tpu.serving.engine.PagedDecodeEngine` — the paged
+- :class:`~paddle_tpu.serving.engine.PagedDecodeEngine` — the
   engine (block-pool KV, chunked prefill interleaved with decode,
-  content-hash prefix cache with refcounted blocks + LRU eviction);
-  build via ``PagedDecodeEngine.from_params`` or a format-v4
-  artifact's ``LMServer.engine()``.
-- :class:`~paddle_tpu.serving.engine.DecodeEngine` — the legacy
-  row-per-request arena engine (FIFO admission, slot recycling,
-  bucketed whole-prompt prefill); format-v3 artifacts load here.
+  content-hash prefix cache with refcounted blocks + LRU eviction,
+  latency/batch tiers, tenant budgets, preempt-to-blocks); build via
+  ``PagedDecodeEngine.from_params`` or an artifact's
+  ``LMServer.engine()`` (formats v4/v5).
+- :class:`~paddle_tpu.serving.engine.SpecDecodeEngine` — the same
+  scheduler with a draft model's propose+verify round in place of the
+  decode step.
 - :class:`~paddle_tpu.serving.engine.EngineRequest` — per-request
   lifecycle record (tokens, TTFT, latency, finish reason,
   prefix_hit_tokens).
@@ -31,7 +32,6 @@ Public surface:
   handles and JSONL transports (stdio with graceful SIGTERM drain,
   TCP for multi-process fleets) the router fronts.
 - :func:`~paddle_tpu.serving.sampling.sample_tokens` /
-  :func:`~paddle_tpu.serving.sampling.engine_step_fns` /
   :func:`~paddle_tpu.serving.sampling.paged_step_fns` — the pure step
   programs (greedy / temperature / top-k inside the compiled step).
 """
@@ -39,8 +39,8 @@ Public surface:
 from paddle_tpu.serving.blocks import (  # noqa: F401
     BlockPool, chain_hash, prompt_block_hashes)
 from paddle_tpu.serving.engine import (  # noqa: F401
-    DEFAULT_PREFILL_BUCKETS, VALID_TIERS, DecodeEngine, EngineRequest,
-    PagedDecodeEngine, SpecDecodeEngine, default_chunk_buckets)
+    VALID_TIERS, EngineRequest, PagedDecodeEngine, SpecDecodeEngine,
+    default_chunk_buckets)
 from paddle_tpu.serving.replica import (  # noqa: F401
     EngineLoop, EngineReplica, ReplicaServer, SocketReplica,
     serve_stdio)
@@ -49,5 +49,5 @@ from paddle_tpu.serving.router import (  # noqa: F401
 from paddle_tpu.serving.tiers import (  # noqa: F401
     TieredStore)
 from paddle_tpu.serving.sampling import (  # noqa: F401
-    engine_step_fns, paged_spec_fns, paged_step_fns, sample_tokens,
-    spec_accept, spec_verify_tokens)
+    paged_spec_fns, paged_step_fns, sample_tokens, spec_accept,
+    spec_verify_tokens)

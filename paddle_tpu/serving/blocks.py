@@ -1,4 +1,4 @@
-"""Host-side block allocator + prefix cache for the paged KV arena.
+"""Host-side block allocator + prefix cache for the paged KV pool.
 
 The device side is a flat pool (``models/transformer.init_block_pool``,
 [L, num_blocks·block_size, Hkv, Dh]); this module owns the HOST

@@ -1,29 +1,40 @@
-"""Continuous-batching LM decode engine: slot scheduler over a KV arena.
+"""Continuous-batching LM decode engine: a slot scheduler over a
+paged KV pool.
 
 The lockstep serving surface (``io/lm_serving.LMServer.generate``)
 forces every request into one fixed-shape batch: shared prompt length,
-shared step count, host-side sampling. This engine replaces batch
-formation with SLOTS: the KV cache is a ``[L, B, cache_len, Hkv, Dh]``
-arena whose B rows are leased to requests independently. A request
+shared step count, host-side sampling. :class:`PagedDecodeEngine`
+replaces batch formation with SLOTS over a block-table KV layout: the
+KV cache is a pool of ``block_size``-token blocks
+(``models/transformer.init_block_pool``) and each of the engine's B
+slots maps its request's positions onto blocks through a page vector.
+A request
 
-1. queues (FIFO) until a slot frees,
-2. prefills into its slot via ``transformer.prefill_into_slot`` — the
-   prompt is right-padded to a bucket length (``core/ragged`` buckets),
-   so the engine compiles at most once per bucket,
+1. queues (latency tier ahead of batch tier, per-tenant token budgets)
+   until a slot is free and its worst-case block count reserves,
+2. prefills in ``chunk_tokens`` chunks
+   (``transformer.prefill_into_blocks``), one chunk per ``step()``
+   while anything decodes, its tail chunk right-padded to a chunk
+   bucket (``core/ragged`` buckets); full prompt blocks already in the
+   content-hash prefix cache are mapped, not recomputed,
 3. decodes in the shared per-slot-position step
-   (``transformer.decode_step_slots`` + on-device sampling) alongside
+   (``transformer.decode_step_paged`` + on-device sampling) alongside
    whatever else is in flight, each row at its own position,
-4. terminates on EOS / max_new and releases the slot to the next
-   queued request — mid-flight, no other row perturbed.
+4. terminates on EOS / max_new and releases the slot and its blocks to
+   the next queued request — mid-flight, no other row perturbed. A
+   batch-tier request may instead be preempted to blocks for a
+   latency-tier one: its pages re-publish into the prefix cache, so
+   resume is either a pure host re-mapping or a cache-hit chunked
+   prefill, bitwise either way.
 
-Every shape is static: one compile per prefill bucket + ONE for decode,
-verified by the observe compile tracker under the names
+Every shape is static: one compile per (chunk bucket, context span) +
+ONE for decode, verified by the observe compile tracker under the names
 ``serving_engine.prefill`` / ``serving_engine.decode``.
 
 The host loop only ever moves ``[B] int32`` token ids off device (the
 sampler runs inside the step); scheduling state (positions, active
-mask, per-slot temperature/top_k) lives in numpy and is re-uploaded as
-tiny vectors per step.
+mask, page table, per-slot temperature/top_k) lives in numpy and is
+re-uploaded as tiny vectors per step.
 
 Observability: each engine carries its own metrics ``Registry`` —
 queue-wait and time-to-first-token histograms, slot-occupancy and
@@ -31,19 +42,9 @@ queue-depth gauges, token/step counters, per-request goodput — and
 ``serve()`` exposes them on the standard ``/metrics`` + ``/healthz``
 endpoints (``observe/health.py``).
 
-:class:`PagedDecodeEngine` supersedes the row-per-request arena with a
-block-table KV layout (paged pool + per-slot page vectors, chunked
-prefill interleaved with decode, content-hash prefix cache with
-refcounted blocks and LRU eviction) and carries the multi-tenant
-scheduler: latency/batch tiers with strict-priority admission,
-per-tenant token budgets (exhaustion queues, never rejects), and
-preempt-to-blocks — a batch-tier victim's pages re-publish into the
-prefix cache so resume is either a pure host re-mapping or a
-cache-hit chunked prefill, bitwise either way.
 :class:`SpecDecodeEngine` adds speculative decoding on top (draft
 model sharing the block table, fused k-step propose, batched-window
-verify bitwise the decode step). :class:`DecodeEngine` remains the
-legacy whole-row engine that format-v3 artifacts load into.
+verify bitwise the decode step).
 """
 
 import dataclasses
@@ -67,10 +68,6 @@ from paddle_tpu.serving import blocks as _blocks
 # (``eng<N>.r<rid>``) so several engines' lifecycle events never
 # collide in one exported timeline
 _ENGINE_IDS = itertools.count()
-
-# prefill buckets: small powers of two keep compile count tiny while
-# wasting at most ~2x padded prefill compute on a mixed workload
-DEFAULT_PREFILL_BUCKETS = (16, 32, 64, 128, 256, 512)
 
 # decode steps run single-digit ms; prefill tens-to-hundreds (matches
 # io/lm_serving's serving-latency resolution)
@@ -147,10 +144,9 @@ class EngineRequest:
     tenant: str = "default"             # token-budget accounting key
     tier: str = "batch"                 # latency | batch (VALID_TIERS)
     # -- lifecycle (filled by the engine) --------------------------------
-    bucket: int = 0
     slot: int = -1
     prefix_hit_tokens: int = 0          # prompt tokens served from the
-    #                                     prefix cache (paged engine)
+    #                                     prefix cache
     block_hashes: Optional[List[bytes]] = None  # prompt block digests,
     #                                     memoized at first admission try
     tier_promote_done: bool = False     # spill-tier promotion attempted
@@ -162,8 +158,8 @@ class EngineRequest:
     #                                     admission labels them dram/
     #                                     disk hits, not hbm
     tokens: List[int] = dataclasses.field(default_factory=list)
-    status: str = "queued"              # queued | prefilling (paged,
-    #                                     mid-chunk) | running | done
+    status: str = "queued"              # queued | prefilling (mid-
+    #                                     chunk) | running | done
     finish_reason: Optional[str] = None  # eos | max_tokens
     submit_t: float = 0.0
     prefill_t: Optional[float] = None
@@ -175,7 +171,7 @@ class EngineRequest:
     #                                     request's lifecycle events
     decode_open: bool = False           # a "decode" trace slice is open
     preemptions: int = 0                # times preempted to blocks
-    # preempt-to-blocks resume state (paged engine): the host snapshot
+    # preempt-to-blocks resume state: the host snapshot
     # taken at preemption (block-chain digests + decode cursor), and —
     # on the eviction-fallback path — the already-emitted tokens the
     # replay force-feeds through the decode program without re-emitting
@@ -212,8 +208,7 @@ class EngineRequest:
     def prefill_stall_s(self) -> Optional[float]:
         """Admitted -> first token, minus own prefill device time:
         time parked behind OTHER requests' chunks and the decode steps
-        interleaved between them (near 0 on the row-arena engine,
-        whose prefill is monolithic)."""
+        interleaved between them."""
         if self.first_token_t is None or self.prefill_t is None:
             return None
         return max(self.first_token_t - self.prefill_t
@@ -231,757 +226,16 @@ class EngineRequest:
         return self.prefix_hit_tokens / max(int(self.prompt.size), 1)
 
 
-class DecodeEngine:
-    """Slot-based continuous-batching scheduler over compiled step fns.
-
-    ``prefill`` / ``decode`` follow the ``sampling.engine_step_fns``
-    signatures (params threaded explicitly, cache functional). Build one
-    with :meth:`from_params` (in-process jit) or
-    :meth:`io.lm_serving.LMServer.engine` (format-v3 AOT artifact).
-    """
-
-    def __init__(self, prefill: Callable, decode: Callable, params, cache,
-                 *, batch: int, cache_len: int,
-                 buckets: Sequence[int] = DEFAULT_PREFILL_BUCKETS,
-                 seed: Optional[int] = None,
-                 registry: Optional[_metrics.Registry] = None,
-                 tracker: Optional[_ct.CompileTracker] = None,
-                 slo: Optional[SloConfig] = None,
-                 decode_flops: Optional[float] = None,
-                 pallas_mode: Optional[str] = None,
-                 kernel_paths: Optional[Dict[str, Dict[str, str]]] = None):
-        import jax.numpy as jnp
-        self._jnp = jnp
-        self._prefill_fn = prefill
-        self._decode_fn = decode
-        self.params = params
-        self.cache = cache
-        self.batch = int(batch)
-        self.cache_len = int(cache_len)
-        # decode-MFU accounting (the PR-2 scoreboard): model FLOPs of
-        # one compiled decode step (from lowered cost analysis or the
-        # artifact's cost stamp) against the declared chip peak
-        self.decode_flops = decode_flops
-        self._peak_flops = _costs.device_peak_flops()
-        # the resolved PADDLE_TPU_PALLAS policy the programs were built
-        # under (None = unknown/legacy artifact)
-        self.pallas_mode = pallas_mode
-        # per compiled program, the path each kernel site ACTUALLY
-        # placed ("pallas" | "pallas_interpret" | "xla"): recorded by
-        # the step functions as they trace (``sampling._recorded``) or
-        # stamped into the artifact at export. A live dict — in-process
-        # programs appear as they first trace.
-        self.kernel_paths = kernel_paths if kernel_paths is not None \
-            else {}
-        self.buckets = tuple(sorted({int(b) for b in buckets
-                                     if int(b) <= cache_len}))
-        if not self.buckets:
-            raise ValueError(f"no prefill bucket fits cache_len="
-                             f"{cache_len} (buckets={tuple(buckets)})")
-        # engine-level "unseeded must not repeat": like the LMServer fix,
-        # None draws fresh OS entropy instead of collapsing to a constant
-        self._rng = np.random.RandomState(seed)
-        # per-engine tracker by default: a shared (global) tracker would
-        # have seen another engine's signatures already and mis-credit /
-        # swallow this engine's real compiles in compile_counts()
-        self._tracker = tracker or _ct.CompileTracker()
-        # -- host-side slot state (uploaded as [B] vectors per step) -----
-        B = self.batch
-        self._pos = np.zeros(B, np.int32)
-        self._active = np.zeros(B, bool)
-        self._last = np.zeros(B, np.int32)
-        self._temp = np.zeros(B, np.float32)
-        self._topk = np.zeros(B, np.int32)
-        self._slot_req: List[Optional[EngineRequest]] = [None] * B
-        self._free = deque(range(B))
-        self._queue: deque = deque()
-        self._ids = itertools.count()
-        # -- request-scoped observability --------------------------------
-        self._engine_id = next(_ENGINE_IDS)
-        # perf_counter -> wall-clock anchor: lifecycle events must land
-        # on the same epoch timeline as the trace-scope spans, but the
-        # engine's internal timestamps stay monotonic perf_counter
-        self._wall_anchor = time.time() - time.perf_counter()
-        self.request_log = _requests.RequestLog()
-        self.slo: Optional[SloConfig] = None
-        self._win_ttft: WindowedQuantiles = None  # set by configure_slo
-        self._win_tps: WindowedQuantiles = None
-        self.configure_slo(slo)
-        # -- metrics ------------------------------------------------------
-        reg = self.metrics = registry or _metrics.Registry()
-        self._m_requests = reg.counter(
-            "engine_requests_total", "requests submitted")
-        self._m_completed = reg.counter(
-            "engine_requests_completed_total",
-            "requests finished, by termination reason")
-        self._m_tokens = reg.counter(
-            "engine_tokens_total", "tokens emitted across all requests")
-        self._m_steps = reg.counter(
-            "engine_decode_steps_total", "batched decode steps executed")
-        self._m_prefills = reg.counter(
-            "engine_prefill_calls_total", "slot prefills executed")
-        self._m_queue = reg.gauge(
-            "engine_queue_depth", "requests waiting for a slot")
-        self._m_occupancy = reg.gauge(
-            "engine_slots_active", "arena slots currently decoding")
-        self._m_wait_s = reg.histogram(
-            "engine_queue_wait_seconds", "submit -> prefill-start wait",
-            buckets=_LATENCY_BUCKETS)
-        self._m_ttft_s = reg.histogram(
-            "engine_ttft_seconds", "submit -> first token (queue wait + "
-            "prefill)", buckets=_LATENCY_BUCKETS)
-        self._m_prefill_s = reg.histogram(
-            "engine_prefill_seconds", "slot-prefill device latency",
-            buckets=_LATENCY_BUCKETS)
-        self._m_step_s = reg.histogram(
-            "engine_decode_step_seconds", "batched decode-step latency "
-            "(device call + [B]-ids host sync)", buckets=_LATENCY_BUCKETS)
-        self._m_goodput = reg.histogram(
-            "engine_request_tokens_per_sec", "per-request goodput: "
-            "tokens emitted / (finish - submit)",
-            buckets=_GOODPUT_BUCKETS)
-        self._m_win_ttft = reg.gauge(
-            "engine_ttft_window_seconds", "rolling TTFT quantile over "
-            "the SLO window (label q = p50|p95|p99) — the cumulative "
-            "histogram cannot answer this once traffic has history")
-        self._m_win_tps = reg.gauge(
-            "engine_tokens_per_sec_window", "rolling per-request "
-            "goodput quantile over the SLO window (label q)")
-        self._m_burn = reg.gauge(
-            "engine_slo_burn_rate", "TTFT SLO burn rate: windowed "
-            "violation fraction / error budget (0 without a "
-            "configured SLO)")
-        self._m_rejected = reg.counter(
-            "engine_requests_rejected_total",
-            "submissions rejected at validation, by reason")
-        self._m_phase = {
-            name: reg.histogram(f"engine_{name}_seconds", help,
-                                buckets=_LATENCY_BUCKETS)
-            for name, help in _PHASES.items()}
-        self._m_slow_steps = reg.counter(
-            "engine_slow_steps_total", f"decode steps that completed "
-            f"more than {_SLOW_STEP_S:g} s after the one before, with "
-            f"decoders in flight throughout")
-        self._step_end: Optional[float] = None   # last decode step's,
-        #                                 while decoders stay in flight
-        self._tag = {"step": 0, "active": 0}     # span args of this step
-
-    # -- construction ------------------------------------------------------
-    @classmethod
-    def from_params(cls, params, cfg, *, batch: int, cache_len: int,
-                    buckets: Sequence[int] = DEFAULT_PREFILL_BUCKETS,
-                    seed: Optional[int] = None, pallas: Optional[str] = None,
-                    **kw):
-        """In-process engine: jit the step fns against live params (the
-        no-artifact path tests and benchmarks drive). ``pallas``
-        overrides the ``PADDLE_TPU_PALLAS`` policy for the step
-        programs (fused sampling epilogue on the slot engine)."""
-        import jax
-        from paddle_tpu.models import transformer
-        from paddle_tpu.ops.pallas import policy as _pallas_policy
-        from paddle_tpu.serving import sampling
-        if cache_len > cfg.max_len:
-            raise ValueError(f"cache_len {cache_len} exceeds cfg.max_len "
-                             f"{cfg.max_len}")
-        prefill_fn, decode_fn = sampling.engine_step_fns(cfg, pallas=pallas)
-        cache = transformer.init_cache(cfg, batch, cache_len)
-        jdf = jax.jit(decode_fn)
-        if "decode_flops" not in kw:    # the trace is not free — skip
-            kw["decode_flops"] = _decode_step_flops(  # it when supplied
-                jdf, params, cache, batch)
-        return cls(jax.jit(prefill_fn), jdf, params, cache,
-                   batch=batch, cache_len=cache_len, buckets=buckets,
-                   seed=seed, pallas_mode=_pallas_policy.pallas_mode(pallas),
-                   kernel_paths=decode_fn.kernel_paths, **kw)
-
-    # -- request-scoped observability --------------------------------------
-    def configure_slo(self, slo: Optional[SloConfig]):
-        """Install (or with ``None`` clear) the TTFT SLO this engine's
-        `/healthz` evaluates over its rolling window. Resets the window
-        estimators to the new window length — callable after
-        construction (the ``paddle_tpu serve --ttft_slo_ms`` path)."""
-        self.slo = slo
-        win = slo.window_s if slo is not None else 60.0
-        self._win_ttft = WindowedQuantiles(window_s=win)
-        self._win_tps = WindowedQuantiles(window_s=win)
-        # per-tier TTFT windows (created lazily as tiers appear) feed
-        # the {q, tier}-labelled gauge samples: the scheduler's whole
-        # point is per-tier p99 separation, which the aggregate window
-        # cannot show
-        self._win_ttft_tier: Dict[str, WindowedQuantiles] = {}
-        self._tier_window_s = win
-
-    def _tier_window(self, tier: str) -> WindowedQuantiles:
-        win = self._win_ttft_tier.get(tier)
-        if win is None:
-            win = self._win_ttft_tier[tier] = WindowedQuantiles(
-                window_s=self._tier_window_s)
-        return win
-
-    def _wall(self, perf_t: float) -> float:
-        return self._wall_anchor + perf_t
-
-    def _ev(self, req: EngineRequest, name: str, ph: str, perf_t: float,
-            **args):
-        """One lifecycle event on this request's async trace track."""
-        _chrome.record_event(name, self._wall(perf_t), ph, req.trace_id,
-                             args=args or None)
-
-    def phase(self, name: str, **args) -> _Phase:
-        """Context manager over one of the step's host phases
-        (``_PHASES``); ``args`` join the step's number and active-slot
-        count on the recorded span."""
-        return _Phase(self._m_phase[name], name,
-                      dict(self._tag, **args) if args else self._tag)
-
-    def _reject(self, rid: int, reason: str, msg: str) -> ValueError:
-        """Account + trace a rejected submission; returns (does not
-        raise) the ValueError so call sites read ``raise self._reject``."""
-        now = time.perf_counter()
-        self._m_rejected.inc(reason=reason)
-        _chrome.record_event(
-            "request_rejected", self._wall(now), "n",
-            f"eng{self._engine_id}.r{rid}",
-            args={"rid": rid, "reason": reason})
-        # a rejection leaves a record too (observe/requests.py promises
-        # one per finished OR rejected request): no measured components,
-        # so attribute() reports dominance "none" and slowest(by latency)
-        # skips it, but a rejection storm shows in summary()'s by_reason
-        rec = {"rid": rid, "engine": self._engine_id,
-               "trace_id": f"eng{self._engine_id}.r{rid}",
-               "submit_ts": round(self._wall(now), 6),
-               "finish_reason": f"rejected:{reason}",
-               "prompt_tokens": None, "tokens": 0,
-               "queue_wait_s": None, "prefill_own_s": None,
-               "prefill_stall_s": None, "decode_s": None,
-               "ttft_s": None, "latency_s": None, "cache_hit_frac": 0.0}
-        self.request_log.add(rec)
-        _requests.default_request_log().add(rec)
-        return ValueError(msg)
-
-    def _enqueue(self, req: EngineRequest) -> EngineRequest:
-        """Shared submit tail: queue the request and open its trace
-        track (async ``request`` slice + nested ``queued`` slice).
-        A caller-supplied trace id (``submit(trace=...)`` — the fleet
-        router propagating its fleet-unique context over the serve
-        wire) is adopted verbatim so the engine's lifecycle events join
-        the router's ``route``/``place`` spans in one merged timeline;
-        otherwise the engine mints its own per-process id."""
-        if not req.trace_id:
-            req.trace_id = f"eng{self._engine_id}.r{req.rid}"
-        self._queue.append(req)
-        self._m_requests.inc()
-        self._m_queue.set(len(self._queue))
-        self._ev(req, "request", "b", req.submit_t, rid=req.rid,
-                 prompt_tokens=int(req.prompt.size), max_new=req.max_new,
-                 tenant=req.tenant, tier=req.tier)
-        self._ev(req, "queued", "b", req.submit_t)
-        return req
-
-    def _record_request(self, req: EngineRequest):
-        """One flat record into the engine's bounded request ring AND
-        the process default (``observe.default_request_log()``)."""
-        def r6(v):
-            return round(v, 6) if v is not None else None
-
-        rec = {"rid": req.rid, "engine": self._engine_id,
-               "trace_id": req.trace_id,
-               "submit_ts": round(self._wall(req.submit_t), 6),
-               "finish_reason": req.finish_reason,
-               "tenant": req.tenant, "tier": req.tier,
-               "preemptions": req.preemptions,
-               "prompt_tokens": int(req.prompt.size),
-               "tokens": len(req.tokens),
-               "queue_wait_s": r6(req.queue_wait_s),
-               "prefill_own_s": r6(req.prefill_own_s),
-               "prefill_stall_s": r6(req.prefill_stall_s),
-               "decode_s": r6(req.decode_s),
-               "ttft_s": r6(req.ttft_s),
-               "latency_s": r6(req.latency_s),
-               "cache_hit_frac": round(req.cache_hit_frac, 4)}
-        self.request_log.add(rec)
-        _requests.default_request_log().add(rec)
-
-    def _slo_burn_rate(self) -> float:
-        if self.slo is None:
-            return 0.0
-        return self.slo.burn_rate(
-            self._win_ttft.fraction_over(self.slo.ttft_s))
-
-    def _update_window_gauges(self):
-        """Refresh the rolling-quantile gauges + burn rate. Called when
-        requests finish (request-grain, not step-grain, so the sort
-        stays off the per-token path) AND on every read of the gauges
-        (``health()`` / ``metrics_text()``): window samples expire with
-        time, so a gauge last written mid-breach would otherwise report
-        that breach forever once traffic stops, contradicting the
-        live-computed `/healthz`."""
-        ttft = self._win_ttft.quantiles((0.5, 0.95, 0.99))
-        tps = self._win_tps.quantiles((0.5, 0.95, 0.99))
-        for lbl, q in (("p50", 0.5), ("p95", 0.95), ("p99", 0.99)):
-            self._m_win_ttft.set(ttft[q], q=lbl)
-            self._m_win_tps.set(tps[q], q=lbl)
-        # per-tier split of the same gauge ({q, tier} samples): the
-        # scheduler's effect IS the separation between these series
-        for tier, win in self._win_ttft_tier.items():
-            tq = win.quantiles((0.5, 0.95, 0.99))
-            for lbl, q in (("p50", 0.5), ("p95", 0.95), ("p99", 0.99)):
-                self._m_win_ttft.set(tq[q], q=lbl, tier=tier)
-        self._m_burn.set(self._slo_burn_rate())
-
-    # -- request API -------------------------------------------------------
-    def _validate_submit(self, rid: int, prompt, max_new: int,
-                         tier: str):
-        """Shared submit validation (both engines): counted rejections,
-        never tracebacks, for the malformed-request classes a JSONL
-        wire can deliver."""
-        if prompt.size < 1:
-            raise self._reject(rid, "empty_prompt", "submit: empty prompt")
-        if max_new < 1:
-            raise self._reject(rid, "bad_max_new",
-                               f"submit: max_new must be >= 1, "
-                               f"got {max_new}")
-        if tier not in VALID_TIERS:
-            raise self._reject(rid, "bad_tier",
-                               f"submit: tier must be one of "
-                               f"{VALID_TIERS}, got {tier!r}")
-
-    def submit(self, prompt, max_new: int, *, temperature: float = 0.0,
-               top_k: int = 0, eos_id: Optional[int] = None,
-               tenant: str = "default", tier: str = "batch",
-               trace: Optional[str] = None) -> EngineRequest:
-        """Queue one request; returns its (live) EngineRequest record.
-        ``tenant``/``tier`` ride into the request log and trace events;
-        ``trace`` adopts a caller-provided trace id (fleet propagation)
-        instead of minting ``eng<N>.r<rid>``. The row-arena engine
-        schedules FIFO regardless (tiered admission and preemption live
-        in :class:`PagedDecodeEngine`)."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        rid = next(self._ids)
-        self._validate_submit(rid, prompt, max_new, tier)
-        from paddle_tpu.core import ragged
-        if prompt.size > self.buckets[-1]:
-            # beyond the largest bucket there is no compiled prefill
-            # program (AOT artifacts ship exactly one per bucket)
-            raise self._reject(
-                rid, "prompt_too_long",
-                f"submit: prompt length {prompt.size} exceeds the "
-                f"largest prefill bucket {self.buckets[-1]}")
-        bucket = ragged.bucket_length(prompt.size, self.buckets)
-        if prompt.size + max_new > self.cache_len:
-            raise self._reject(
-                rid, "exceeds_cache",
-                f"submit: {prompt.size} prompt + {max_new} new tokens "
-                f"exceed cache_len {self.cache_len}")
-        req = EngineRequest(
-            rid=rid, prompt=prompt, max_new=int(max_new),
-            temperature=float(temperature), top_k=int(top_k),
-            eos_id=eos_id, tenant=str(tenant), tier=str(tier),
-            bucket=bucket, submit_t=time.perf_counter(),
-            trace_id=str(trace) if trace else "")
-        return self._enqueue(req)
-
-    def abort_requests(self, reason: str = "replica_killed") -> int:
-        """Close every live request's open trace slices (``queued`` /
-        ``prefill`` / ``decode`` / ``request``) with an ``aborted``
-        marker and drop the work. This is the IN-PROCESS analogue of
-        the replica process dying: a real SIGKILL takes its span buffer
-        with it (the merged fleet trace simply never sees the dead
-        attempt), but an in-process fleet shares one buffer, so a kill
-        simulation must close what the dead attempt opened or the
-        joined trace shows unbalanced slices. Trace-level only — block
-        /slot accounting is abandoned, not released, exactly like a
-        dead process; do not reuse the engine afterwards."""
-        now = time.perf_counter()
-        aborted: List[EngineRequest] = []
-        for req in list(self._queue):
-            self._ev(req, "queued", "e", now)
-            aborted.append(req)
-        # preempted-to-blocks requests (paged engine) already closed
-        # their prefill/decode slices at preemption
-        aborted.extend(list(getattr(self, "_preempted", ())))
-        for slot, req in enumerate(self._slot_req):
-            if req is None:
-                continue
-            if req.first_token_t is None:
-                self._ev(req, "prefill", "e", now)
-            if req.decode_open:
-                self._ev(req, "decode", "e", now)
-                req.decode_open = False
-            self._active[slot] = False
-            self._slot_req[slot] = None
-            aborted.append(req)
-        for req in aborted:
-            req.status, req.finish_reason = "aborted", reason
-            self._ev(req, "aborted", "n", now, reason=reason)
-            self._ev(req, "request", "e", now)
-        self._queue.clear()
-        if hasattr(self, "_preempted"):
-            self._preempted.clear()
-        self._m_queue.set(0)
-        return len(aborted)
-
-    @property
-    def active_count(self) -> int:
-        return int(self._active.sum())
-
-    @property
-    def queue_depth(self) -> int:
-        return len(self._queue)
-
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    @property
-    def idle(self) -> bool:
-        return not self._queue and not self._active.any()
-
-    # -- scheduler ---------------------------------------------------------
-    def _seed(self) -> np.int32:
-        return np.int32(self._rng.randint(0, 2 ** 31 - 1))
-
-    def _finish(self, req: EngineRequest, reason: str, now: float):
-        req.status, req.finish_reason, req.finish_t = "done", reason, now
-        self._m_completed.inc(reason=reason)
-        if req.latency_s and req.latency_s > 0:
-            goodput = len(req.tokens) / req.latency_s
-            self._m_goodput.observe(goodput)
-            self._win_tps.observe(goodput)
-        slot = req.slot
-        if slot >= 0:
-            self._active[slot] = False
-            self._slot_req[slot] = None
-            self._free.append(slot)
-        if req.decode_open:
-            self._ev(req, "decode", "e", now)
-            req.decode_open = False
-        self._ev(req, "finished", "n", now, reason=reason,
-                 tokens=len(req.tokens))
-        self._ev(req, "request", "e", now)
-        self._record_request(req)
-        self._update_window_gauges()
-
-    def _emit(self, req: EngineRequest, tok: int, now: float) -> bool:
-        """Record one emitted token; True when the request finished."""
-        req.tokens.append(int(tok))
-        self._m_tokens.inc()
-        finishing = ((req.eos_id is not None and tok == req.eos_id)
-                     or len(req.tokens) >= req.max_new)
-        if req.first_token_t is None:
-            req.first_token_t = now
-            ttft = now - req.submit_t
-            self._m_ttft_s.observe(ttft)
-            self._win_ttft.observe(ttft)
-            self._tier_window(req.tier).observe(ttft)
-            self._ev(req, "prefill", "e", now)
-            self._ev(req, "first_token", "n", now,
-                     ttft_ms=round(1000 * ttft, 3))
-            if not finishing:
-                self._ev(req, "decode", "b", now)
-                req.decode_open = True
-        if req.eos_id is not None and tok == req.eos_id:
-            self._finish(req, "eos", now)
-            return True
-        if len(req.tokens) >= req.max_new:
-            self._finish(req, "max_tokens", now)
-            return True
-        return False
-
-    def _admit(self, finished: List[EngineRequest]):
-        jnp = self._jnp
-        while self._queue and self._free:
-            with self.phase("schedule") as sched:
-                req = self._queue.popleft()
-                slot = self._free.popleft()
-                now = sched.t0
-                req.prefill_t = now
-                self._m_wait_s.observe(now - req.submit_t)
-                self._ev(req, "queued", "e", now)
-                self._ev(req, "admitted", "n", now, slot=slot,
-                         queue_wait_ms=round(
-                             1000 * (now - req.submit_t), 3))
-                self._ev(req, "prefill", "b", now)
-                padded = np.zeros((1, req.bucket), np.int32)
-                padded[0, :req.prompt.size] = req.prompt
-            with self.phase("prefill_chunk", tokens=int(req.prompt.size),
-                            bucket=req.bucket) as chunk:
-                tok, self.cache = self._tracker.track_call(
-                    "serving_engine.prefill", self._prefill_fn,
-                    self.params, self.cache, jnp.asarray(padded),
-                    np.int32(req.prompt.size), np.int32(slot),
-                    np.float32(req.temperature), np.int32(req.top_k),
-                    self._seed())
-                tok = int(np.asarray(tok))
-            with self.phase("schedule"):
-                now = chunk.end
-                req.prefill_own_s = now - chunk.t0
-                self._m_prefill_s.observe(now - chunk.t0)
-                self._m_prefills.inc()
-                self._ev(req, "prefill_chunk", "n", now,
-                         tokens=int(req.prompt.size), bucket=req.bucket)
-                req.slot, req.status = slot, "running"
-                self._slot_req[slot] = req
-                if self._emit(req, tok, now):
-                    finished.append(req)    # one-token request: slot
-                    continue                # already recycled by _finish
-                self._active[slot] = True
-                self._pos[slot] = req.prompt.size
-                self._last[slot] = tok
-                self._temp[slot] = req.temperature
-                self._topk[slot] = req.top_k
-        self._m_queue.set(len(self._queue))
-
-    # hooks the paged subclass specializes -------------------------------
-    def _schedule(self, finished: List[EngineRequest]):
-        """Admission (and, for the paged engine, prefill-chunk) work
-        that runs before the decode step."""
-        self._admit(finished)
-
-    def _pre_decode(self):
-        """Host bookkeeping needed before a decode step may run (the
-        paged engine allocates write pages here)."""
-
-    def _decode_extra(self):
-        """Extra decode-program args inserted after ``active`` (the
-        paged engine's page table)."""
-        return ()
-
-    def _consume_forced(self, slot: int) -> bool:
-        """True when this slot is replaying already-emitted history
-        after a preempt-to-blocks resume (paged engine): the decode
-        step's sampled id is discarded, the known token advances the
-        cursor, and nothing re-emits. The row-arena engine never
-        preempts."""
-        return False
-
-    def _step_ids(self, out: np.ndarray) -> np.ndarray:
-        """The [B] sampled ids of a decode program's host-side result
-        (the paged engine's programs may append counts to them)."""
-        return out
-
-    def _update_gauges(self):
-        self._m_occupancy.set(self.active_count)
-
-    def step(self) -> List[EngineRequest]:
-        """One scheduler iteration: admit waiting requests into free
-        slots, run one batched decode step for everything in flight.
-        Returns the requests that finished during this step."""
-        finished: List[EngineRequest] = []
-        self._open_step()
-        self._schedule(finished)
-        if self._active.any():
-            with self.phase("decode_stage") as stage:
-                self._pre_decode()
-                staged = self._stage_decode(self._seed())
-            with self.phase("decode_dispatch"):
-                nxt = self._call_decode(*staged)
-            with self.phase("decode_sync") as sync:
-                # the only device->host transfer: [B] int32 ids
-                nxt = np.asarray(nxt)
-            now = self._close_decode(stage, sync)
-            with self.phase("emit"):
-                nxt = self._step_ids(nxt)
-                for slot in np.flatnonzero(self._active):
-                    if self._consume_forced(slot):
-                        continue
-                    req = self._slot_req[slot]
-                    tok = int(nxt[slot])
-                    self._pos[slot] += 1
-                    self._last[slot] = tok
-                    if self._emit(req, tok, now):
-                        finished.append(req)
-        self._close_step()
-        return finished
-
-    def _open_step(self):
-        self._tag = {"step": int(self._m_steps.value()),
-                     "active": self.active_count}
-
-    def _close_decode(self, stage: _Phase, sync: _Phase) -> float:
-        """Account one completed decode step (stage + dispatch + sync);
-        returns its completion time."""
-        now = sync.end
-        self._m_step_s.observe(now - stage.t0)
-        self._m_steps.inc()
-        if self._step_end is not None \
-                and now - self._step_end > _SLOW_STEP_S:
-            self._m_slow_steps.inc()
-        self._step_end = now
-        return now
-
-    def _close_step(self):
-        if not self._active.any():
-            self._step_end = None       # no decoder in flight: the next
-            #                             interval is not a step's
-        self._update_gauges()
-
-    def _stage_decode(self, seed):
-        """The decode program's arguments over the current slot state
-        (the [B] vectors uploaded) and their compile-tracker signature."""
-        jnp = self._jnp
-        args = (self.params, self.cache, jnp.asarray(self._last),
-                jnp.asarray(self._pos), jnp.asarray(self._active),
-                *self._decode_extra(),
-                jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
-        return args, _ct.arg_signature(args, {})
-
-    def _call_decode(self, args, sig):
-        """One batched decode program over staged arguments; returns
-        the sampled ids (still on device)."""
-        nxt, self.cache = self._tracker.call_signed(
-            "serving_engine.decode", sig, self._decode_fn, *args)
-        return nxt
-
-    def precompile(self) -> Dict[str, int]:
-        """Run every program this engine can dispatch once, on inputs
-        that change nothing a request will read (no active row; a
-        prompt the first real prefill overwrites), so each is compiled
-        — and has executed on the device — before the engine reports
-        ready. A kernel the compiler refuses raises HERE, with the
-        compiler's message, instead of in the middle of traffic; later
-        dispatches hit the jit cache. Only valid on an idle engine.
-        Returns :meth:`compile_counts`."""
-        if not self.idle:
-            raise RuntimeError("precompile() needs an idle engine")
-        with _trace.trace_scope("precompile/prefill"):
-            self._precompile_prefill()
-        with _trace.trace_scope("precompile/decode"):
-            self._precompile_decode()     # ends in a host read: all done
-        return self.compile_counts()
-
-    def _precompile_prefill(self):
-        jnp = self._jnp
-        for b in self.buckets:
-            # lands in free slot 0's row; the slot's next real prefill
-            # rewrites every position a mask could expose
-            _, self.cache = self._tracker.track_call(
-                "serving_engine.prefill", self._prefill_fn,
-                self.params, self.cache, jnp.zeros((1, b), jnp.int32),
-                np.int32(1), np.int32(0), np.float32(0.0), np.int32(0),
-                np.int32(0))
-
-    def _precompile_decode(self):
-        # no active row: every cache write of the step is dropped
-        np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
-
-    def run_until_idle(self, max_steps: int = 100_000
-                       ) -> List[EngineRequest]:
-        """Drive ``step()`` until queue and arena drain; returns every
-        request finished along the way (submission order not guaranteed
-        — requests terminate independently)."""
-        done: List[EngineRequest] = []
-        for _ in range(max_steps):
-            if self.idle:
-                return done
-            done.extend(self.step())
-        raise RuntimeError(f"engine did not drain in {max_steps} steps "
-                           f"({self.queue_depth} queued, "
-                           f"{self.active_count} active)")
-
-    # -- observability -----------------------------------------------------
-    def decode_mfu(self) -> Optional[float]:
-        """Mean decode-step MFU over this engine's lifetime: decode
-        FLOPs / (mean step seconds × chip peak). None until a step ran
-        or when FLOPs/peak are unknown — the figure ``serving_bench``
-        reports (XLA's cost model over host time: no share of a chip)."""
-        cell = self._m_step_s._peek({})
-        if cell is None or not cell.count:
-            return None
-        return _costs.mfu(self.decode_flops, cell.sum / cell.count,
-                          self._peak_flops)
-
-    def health(self) -> dict:
-        doc = {"requests": int(self._m_requests.value()),
-               "completed": sum(
-                   int(self._m_completed.value(reason=r))
-                   for r in ("eos", "max_tokens")),
-               "tokens": int(self._m_tokens.value()),
-               "decode_steps": int(self._m_steps.value()),
-               "queue_depth": self.queue_depth,
-               "slots_active": self.active_count,
-               "slots_total": self.batch,
-               "cache_len": self.cache_len,
-               "pallas": self.pallas_mode,
-               "kernel_paths": self.kernel_paths,
-               "prefill_buckets": list(self.buckets)}
-        self._update_window_gauges()
-        ttft = self._win_ttft.quantiles((0.5, 0.95, 0.99))
-        doc["window"] = {
-            "window_s": self._win_ttft.window_s,
-            "requests": self._win_ttft.count(),
-            "ttft_p50_s": round(ttft[0.5], 6),
-            "ttft_p95_s": round(ttft[0.95], 6),
-            "ttft_p99_s": round(ttft[0.99], 6),
-            "tokens_per_sec_p50": round(self._win_tps.quantile(0.5), 3),
-            # raw windowed TTFT samples in clock-free [age_s, value]
-            # form (newest 512): the fleet aggregator POOLS these for
-            # its fleet quantiles — per-replica quantiles cannot be
-            # averaged (see WindowedQuantiles.samples)
-            "ttft_samples": [[round(a, 4), round(v, 6)] for a, v in
-                             self._win_ttft.export_samples()[-512:]]}
-        if self._win_ttft_tier:
-            doc["window"]["tiers"] = {
-                tier: {"requests": win.count(),
-                       "ttft_p50_s": round(win.quantile(0.5), 6),
-                       "ttft_p99_s": round(win.quantile(0.99), 6)}
-                for tier, win in sorted(self._win_ttft_tier.items())}
-        if self.slo is not None:
-            burn = self._slo_burn_rate()
-            doc["slo"] = {"ttft_s": self.slo.ttft_s,
-                          "target": self.slo.target,
-                          "window_s": self.slo.window_s,
-                          "burn_threshold": self.slo.burn_threshold,
-                          "ttft_burn_rate": round(burn, 4)}
-            if burn > self.slo.burn_threshold:
-                # degraded, NOT unhealthy: /healthz stays 200 (load
-                # balancers keep routing) while the reason is machine-
-                # readable — the hook the SLO-aware scheduler steers on
-                doc["status"] = "degraded"
-                doc["degraded_reason"] = (
-                    f"ttft_slo_burn_rate {burn:.2f} > "
-                    f"{self.slo.burn_threshold} (p99 "
-                    f"{ttft[0.99]:.4f}s vs slo {self.slo.ttft_s}s over "
-                    f"{self._win_ttft.count()} requests)")
-        return doc
-
-    def requests_doc(self, k: int = 10) -> dict:
-        """The `/requests` section: aggregate summary + top-k slowest
-        with attributed latency components."""
-        doc = self.request_log.summary()
-        doc["slowest_by_ttft"] = self.request_log.slowest(k, by="ttft_s")
-        return doc
-
-    def metrics_text(self) -> str:
-        self._update_window_gauges()   # expire-on-read: see the docstring
-        return self.metrics.render_prometheus()
-
-    def serve(self, host: str = "127.0.0.1", port: int = 0):
-        """/metrics + /healthz + /requests over this engine's registry;
-        caller owns ``close()``."""
-        from paddle_tpu.observe.health import HealthServer
-        return HealthServer(registry=self.metrics, health_fn=self.health,
-                            host=host, port=port,
-                            requests_fn=self.requests_doc,
-                            metrics_fn=self.metrics_text)
-
-    def compile_counts(self) -> Dict[str, int]:
-        """Compilations the tracker charged to this engine's two
-        programs — the "one per bucket + one for decode" invariant."""
-        return {"prefill": self._tracker.count("serving_engine.prefill"),
-                "decode": self._tracker.count("serving_engine.decode")}
-
-
-def _decode_step_flops(decode_fn, params, cache, batch, *extra):
+def _decode_step_flops(decode_fn, params, pool, batch, pages):
     """Model FLOPs of one compiled decode step from the lowered HLO
     cost model (None when unavailable) — the ``decode_mfu()``
-    numerator the in-process engines derive themselves; AOT artifacts
+    numerator an in-process engine derives itself; AOT artifacts
     carry it stamped in ``meta.cost_analysis`` instead."""
     vec_i = np.zeros(batch, np.int32)
     vec_f = np.zeros(batch, np.float32)
     vec_b = np.zeros(batch, bool)
     cost = _costs.lowered_cost(
-        decode_fn, params, cache, vec_i, vec_i, vec_b, *extra,
+        decode_fn, params, pool, vec_i, vec_i, vec_b, pages,
         vec_f, vec_i, np.int32(0))
     return (cost or {}).get("flops")
 
@@ -997,23 +251,26 @@ def default_chunk_buckets(chunk_tokens: int) -> tuple:
     return tuple(sorted(out))
 
 
-class PagedDecodeEngine(DecodeEngine):
+class PagedDecodeEngine:
     """Block-table continuous batching: paged KV, chunked prefill,
-    prefix cache.
+    prefix cache, tiers and tenant budgets.
 
-    Replaces the row-per-request arena with a block POOL
+    ``prefill`` / ``decode`` follow the ``sampling.paged_step_fns``
+    signatures (params threaded explicitly, the pool donated and
+    rebound from every result). Build one with :meth:`from_params`
+    (in-process jit) or :meth:`io.lm_serving.LMServer.engine` (AOT
+    artifact). The KV cache is a block POOL
     (``models/transformer.init_block_pool``): HBM is committed per
     ``block_size``-token block actually written — a request holds
-    ``ceil((Tp + max_new)/block_size)`` blocks instead of a whole
+    ``ceil((Tp + max_new)/block_size)`` blocks, not a whole
     ``cache_len`` row — and the pool can be sized independently of
     ``batch``. On top of the pool:
 
     - **chunked prefill** — prompts are admitted in ``chunk_tokens``
       chunks (``transformer.prefill_into_blocks``), ONE chunk per
       ``step()`` interleaved with the batched decode step, so a long
-      prompt no longer stalls in-flight decoders for its full duration,
-      and any prompt with ``Tp + max_new <= cache_len`` is accepted (no
-      largest-bucket rejection);
+      prompt does not stall in-flight decoders for its full duration,
+      and any prompt with ``Tp + max_new <= cache_len`` is accepted;
     - **prefix cache** — full prompt blocks are published under
       content-chain hashes (``serving/blocks``); a later prompt sharing
       the prefix maps the cached blocks into its page table with a
@@ -1069,20 +326,132 @@ class PagedDecodeEngine(DecodeEngine):
         if chunk_buckets is None:
             chunk_buckets = default_chunk_buckets(chunk_tokens)
         if tracker is None:
-            # the paged engine LEGITIMATELY compiles one prefill program
-            # per reachable (chunk bucket, context span) pair — raise
-            # the default tracker's storm threshold past that ceiling so
-            # normal chunk-grid traffic doesn't read as a recompile
-            # storm (a caller-supplied tracker keeps its own threshold)
+            # per-engine tracker by default: a shared (global) tracker
+            # would have seen another engine's signatures already and
+            # mis-credit / swallow this engine's real compiles in
+            # compile_counts(). The engine LEGITIMATELY compiles one
+            # prefill program per reachable (chunk bucket, context
+            # span) pair — raise the default tracker's storm threshold
+            # past that ceiling so normal chunk-grid traffic doesn't
+            # read as a recompile storm (a caller-supplied tracker
+            # keeps its own threshold)
             spans = max(1, int(cache_len) // chunk_tokens)
             tracker = _ct.CompileTracker(
                 storm_threshold=spans * len(tuple(chunk_buckets)) + 2)
-        super().__init__(prefill, decode, params, cache, batch=batch,
-                         cache_len=cache_len, buckets=chunk_buckets,
-                         seed=seed, registry=registry, tracker=tracker,
-                         slo=slo, decode_flops=decode_flops,
-                         pallas_mode=pallas_mode,
-                         kernel_paths=kernel_paths)
+        import jax.numpy as jnp
+        self._jnp = jnp
+        self._prefill_fn = prefill
+        self._decode_fn = decode
+        self.params = params
+        self.cache = cache
+        self.batch = int(batch)
+        self.cache_len = int(cache_len)
+        # decode-MFU accounting (the PR-2 scoreboard): model FLOPs of
+        # one compiled decode step (from lowered cost analysis or the
+        # artifact's cost stamp) against the declared chip peak
+        self.decode_flops = decode_flops
+        self._peak_flops = _costs.device_peak_flops()
+        # the resolved PADDLE_TPU_PALLAS policy the programs were built
+        # under (None = unknown: an artifact without the stamp)
+        self.pallas_mode = pallas_mode
+        # per compiled program, the path each kernel site ACTUALLY
+        # placed ("pallas" | "pallas_interpret" | "xla"): recorded by
+        # the step functions as they trace (``sampling._recorded``) or
+        # stamped into the artifact at export. A live dict — in-process
+        # programs appear as they first trace.
+        self.kernel_paths = kernel_paths if kernel_paths is not None \
+            else {}
+        self.buckets = tuple(sorted({int(b) for b in chunk_buckets
+                                     if int(b) <= cache_len}))
+        if not self.buckets:
+            raise ValueError(f"no chunk bucket fits cache_len="
+                             f"{cache_len} (chunk_buckets="
+                             f"{tuple(chunk_buckets)})")
+        # engine-level "unseeded must not repeat": like the LMServer fix,
+        # None draws fresh OS entropy instead of collapsing to a constant
+        self._rng = np.random.RandomState(seed)
+        self._tracker = tracker
+        # -- host-side slot state (uploaded as [B] vectors per step) -----
+        B = self.batch
+        self._pos = np.zeros(B, np.int32)
+        self._active = np.zeros(B, bool)
+        self._last = np.zeros(B, np.int32)
+        self._temp = np.zeros(B, np.float32)
+        self._topk = np.zeros(B, np.int32)
+        self._slot_req: List[Optional[EngineRequest]] = [None] * B
+        self._free = deque(range(B))
+        self._queue: deque = deque()
+        self._ids = itertools.count()
+        # -- request-scoped observability --------------------------------
+        self._engine_id = next(_ENGINE_IDS)
+        # perf_counter -> wall-clock anchor: lifecycle events must land
+        # on the same epoch timeline as the trace-scope spans, but the
+        # engine's internal timestamps stay monotonic perf_counter
+        self._wall_anchor = time.time() - time.perf_counter()
+        self.request_log = _requests.RequestLog()
+        self.slo: Optional[SloConfig] = None
+        self._win_ttft: WindowedQuantiles = None  # set by configure_slo
+        self._win_tps: WindowedQuantiles = None
+        self.configure_slo(slo)
+        # -- metrics ------------------------------------------------------
+        reg = self.metrics = registry or _metrics.Registry()
+        self._m_requests = reg.counter(
+            "engine_requests_total", "requests submitted")
+        self._m_completed = reg.counter(
+            "engine_requests_completed_total",
+            "requests finished, by termination reason")
+        self._m_tokens = reg.counter(
+            "engine_tokens_total", "tokens emitted across all requests")
+        self._m_steps = reg.counter(
+            "engine_decode_steps_total", "batched decode steps executed")
+        self._m_prefills = reg.counter(
+            "engine_prefill_calls_total", "slot prefills executed")
+        self._m_queue = reg.gauge(
+            "engine_queue_depth", "requests waiting for a slot")
+        self._m_occupancy = reg.gauge(
+            "engine_slots_active", "slots currently decoding")
+        self._m_wait_s = reg.histogram(
+            "engine_queue_wait_seconds", "submit -> prefill-start wait",
+            buckets=_LATENCY_BUCKETS)
+        self._m_ttft_s = reg.histogram(
+            "engine_ttft_seconds", "submit -> first token (queue wait + "
+            "prefill)", buckets=_LATENCY_BUCKETS)
+        self._m_prefill_s = reg.histogram(
+            "engine_prefill_seconds", "slot-prefill device latency",
+            buckets=_LATENCY_BUCKETS)
+        self._m_step_s = reg.histogram(
+            "engine_decode_step_seconds", "batched decode-step latency "
+            "(device call + [B]-ids host sync)", buckets=_LATENCY_BUCKETS)
+        self._m_goodput = reg.histogram(
+            "engine_request_tokens_per_sec", "per-request goodput: "
+            "tokens emitted / (finish - submit)",
+            buckets=_GOODPUT_BUCKETS)
+        self._m_win_ttft = reg.gauge(
+            "engine_ttft_window_seconds", "rolling TTFT quantile over "
+            "the SLO window (label q = p50|p95|p99) — the cumulative "
+            "histogram cannot answer this once traffic has history")
+        self._m_win_tps = reg.gauge(
+            "engine_tokens_per_sec_window", "rolling per-request "
+            "goodput quantile over the SLO window (label q)")
+        self._m_burn = reg.gauge(
+            "engine_slo_burn_rate", "TTFT SLO burn rate: windowed "
+            "violation fraction / error budget (0 without a "
+            "configured SLO)")
+        self._m_rejected = reg.counter(
+            "engine_requests_rejected_total",
+            "submissions rejected at validation, by reason")
+        self._m_phase = {
+            name: reg.histogram(f"engine_{name}_seconds", help,
+                                buckets=_LATENCY_BUCKETS)
+            for name, help in _PHASES.items()}
+        self._m_slow_steps = reg.counter(
+            "engine_slow_steps_total", f"decode steps that completed "
+            f"more than {_SLOW_STEP_S:g} s after the one before, with "
+            f"decoders in flight throughout")
+        self._step_end: Optional[float] = None   # last decode step's,
+        #                                 while decoders stay in flight
+        self._tag = {"step": 0, "active": 0}     # span args of this step
+        # -- block pool and page table -----------------------------------
         self.block_size = bs
         self.pages_per_slot = cache_len // bs
         self.num_blocks = int(num_blocks if num_blocks is not None
@@ -1121,7 +490,6 @@ class PagedDecodeEngine(DecodeEngine):
         # the expert layer's three counters, made when a step program
         # first returns counts after its ids (``_moe_counters``)
         self._m_moe = None
-        B = self.batch
         # page table uploaded on change (most decode steps reuse the
         # cached device copy); unallocated entries stay 0 and are only
         # ever read under the attend mask
@@ -1143,7 +511,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._tenant_used: Dict[str, int] = {}
         self._preempted: deque = deque()    # preempted reqs awaiting resume
         self._slot_forced: List[deque] = [deque() for _ in range(B)]
-        reg = self.metrics
         self._m_preempts = reg.counter(
             "engine_preemptions_total", "batch-tier victims preempted "
             "to blocks (pages re-published to the prefix cache) so a "
@@ -1281,6 +648,122 @@ class PagedDecodeEngine(DecodeEngine):
                    pallas_mode=_pallas_policy.pallas_mode(pallas),
                    kernel_paths=decode_fn.kernel_paths, **kw)
 
+    # -- request-scoped observability --------------------------------------
+    def configure_slo(self, slo: Optional[SloConfig]):
+        """Install (or with ``None`` clear) the TTFT SLO this engine's
+        `/healthz` evaluates over its rolling window. Resets the window
+        estimators to the new window length — callable after
+        construction (the ``paddle_tpu serve --ttft_slo_ms`` path)."""
+        self.slo = slo
+        win = slo.window_s if slo is not None else 60.0
+        self._win_ttft = WindowedQuantiles(window_s=win)
+        self._win_tps = WindowedQuantiles(window_s=win)
+        # per-tier TTFT windows (created lazily as tiers appear) feed
+        # the {q, tier}-labelled gauge samples: the scheduler's whole
+        # point is per-tier p99 separation, which the aggregate window
+        # cannot show
+        self._win_ttft_tier: Dict[str, WindowedQuantiles] = {}
+        self._tier_window_s = win
+
+    def _tier_window(self, tier: str) -> WindowedQuantiles:
+        win = self._win_ttft_tier.get(tier)
+        if win is None:
+            win = self._win_ttft_tier[tier] = WindowedQuantiles(
+                window_s=self._tier_window_s)
+        return win
+
+    def _wall(self, perf_t: float) -> float:
+        return self._wall_anchor + perf_t
+
+    def _ev(self, req: EngineRequest, name: str, ph: str, perf_t: float,
+            **args):
+        """One lifecycle event on this request's async trace track."""
+        _chrome.record_event(name, self._wall(perf_t), ph, req.trace_id,
+                             args=args or None)
+
+    def phase(self, name: str, **args) -> _Phase:
+        """Context manager over one of the step's host phases
+        (``_PHASES``); ``args`` join the step's number and active-slot
+        count on the recorded span."""
+        return _Phase(self._m_phase[name], name,
+                      dict(self._tag, **args) if args else self._tag)
+
+    def _reject(self, rid: int, reason: str, msg: str) -> ValueError:
+        """Account + trace a rejected submission; returns (does not
+        raise) the ValueError so call sites read ``raise self._reject``."""
+        now = time.perf_counter()
+        self._m_rejected.inc(reason=reason)
+        _chrome.record_event(
+            "request_rejected", self._wall(now), "n",
+            f"eng{self._engine_id}.r{rid}",
+            args={"rid": rid, "reason": reason})
+        # a rejection leaves a record too (observe/requests.py promises
+        # one per finished OR rejected request): no measured components,
+        # so attribute() reports dominance "none" and slowest(by latency)
+        # skips it, but a rejection storm shows in summary()'s by_reason
+        rec = {"rid": rid, "engine": self._engine_id,
+               "trace_id": f"eng{self._engine_id}.r{rid}",
+               "submit_ts": round(self._wall(now), 6),
+               "finish_reason": f"rejected:{reason}",
+               "prompt_tokens": None, "tokens": 0,
+               "queue_wait_s": None, "prefill_own_s": None,
+               "prefill_stall_s": None, "decode_s": None,
+               "ttft_s": None, "latency_s": None, "cache_hit_frac": 0.0}
+        self.request_log.add(rec)
+        _requests.default_request_log().add(rec)
+        return ValueError(msg)
+
+    def _record_request(self, req: EngineRequest):
+        """One flat record into the engine's bounded request ring AND
+        the process default (``observe.default_request_log()``)."""
+        def r6(v):
+            return round(v, 6) if v is not None else None
+
+        rec = {"rid": req.rid, "engine": self._engine_id,
+               "trace_id": req.trace_id,
+               "submit_ts": round(self._wall(req.submit_t), 6),
+               "finish_reason": req.finish_reason,
+               "tenant": req.tenant, "tier": req.tier,
+               "preemptions": req.preemptions,
+               "prompt_tokens": int(req.prompt.size),
+               "tokens": len(req.tokens),
+               "queue_wait_s": r6(req.queue_wait_s),
+               "prefill_own_s": r6(req.prefill_own_s),
+               "prefill_stall_s": r6(req.prefill_stall_s),
+               "decode_s": r6(req.decode_s),
+               "ttft_s": r6(req.ttft_s),
+               "latency_s": r6(req.latency_s),
+               "cache_hit_frac": round(req.cache_hit_frac, 4)}
+        self.request_log.add(rec)
+        _requests.default_request_log().add(rec)
+
+    def _slo_burn_rate(self) -> float:
+        if self.slo is None:
+            return 0.0
+        return self.slo.burn_rate(
+            self._win_ttft.fraction_over(self.slo.ttft_s))
+
+    def _update_window_gauges(self):
+        """Refresh the rolling-quantile gauges + burn rate. Called when
+        requests finish (request-grain, not step-grain, so the sort
+        stays off the per-token path) AND on every read of the gauges
+        (``health()`` / ``metrics_text()``): window samples expire with
+        time, so a gauge last written mid-breach would otherwise report
+        that breach forever once traffic stops, contradicting the
+        live-computed `/healthz`."""
+        ttft = self._win_ttft.quantiles((0.5, 0.95, 0.99))
+        tps = self._win_tps.quantiles((0.5, 0.95, 0.99))
+        for lbl, q in (("p50", 0.5), ("p95", 0.95), ("p99", 0.99)):
+            self._m_win_ttft.set(ttft[q], q=lbl)
+            self._m_win_tps.set(tps[q], q=lbl)
+        # per-tier split of the same gauge ({q, tier} samples): the
+        # scheduler's effect IS the separation between these series
+        for tier, win in self._win_ttft_tier.items():
+            tq = win.quantiles((0.5, 0.95, 0.99))
+            for lbl, q in (("p50", 0.5), ("p95", 0.95), ("p99", 0.99)):
+                self._m_win_ttft.set(tq[q], q=lbl, tier=tier)
+        self._m_burn.set(self._slo_burn_rate())
+
     # -- request API -------------------------------------------------------
     def set_tenant_budget(self, tenant: str, tokens: Optional[int]):
         """Cap (or with ``None`` uncap) ``tenant``'s reserved tokens in
@@ -1303,16 +786,32 @@ class PagedDecodeEngine(DecodeEngine):
                top_k: int = 0, eos_id: Optional[int] = None,
                tenant: str = "default", tier: str = "batch",
                trace: Optional[str] = None) -> EngineRequest:
-        """Queue one request. Unlike the row-arena engine there is no
-        largest-bucket rejection: any prompt with
-        ``len(prompt) + max_new <= cache_len`` is accepted and prefilled
-        in chunks. ``tier="latency"`` admits ahead of batch-tier work
-        and may preempt a batch victim's blocks under pool pressure;
-        ``tenant`` charges the request's worst-case tokens against that
-        tenant's budget (exhaustion queues, never rejects)."""
+        """Queue one request; returns its (live) EngineRequest record.
+        Any prompt with ``len(prompt) + max_new <= cache_len`` is
+        accepted and prefilled in chunks. ``tier="latency"`` admits
+        ahead of batch-tier work and may preempt a batch victim's
+        blocks under pool pressure; ``tenant`` charges the request's
+        worst-case tokens against that tenant's budget (exhaustion
+        queues, never rejects). ``trace`` adopts a caller-provided
+        trace id verbatim (the fleet router propagating its
+        fleet-unique context over the serve wire, so the engine's
+        lifecycle events join the router's ``route``/``place`` spans in
+        one merged timeline) instead of minting the per-process
+        ``eng<N>.r<rid>``. A malformed request — the classes a JSONL
+        wire can deliver — is a counted rejection, never a
+        traceback."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         rid = next(self._ids)
-        self._validate_submit(rid, prompt, max_new, tier)
+        if prompt.size < 1:
+            raise self._reject(rid, "empty_prompt", "submit: empty prompt")
+        if max_new < 1:
+            raise self._reject(rid, "bad_max_new",
+                               f"submit: max_new must be >= 1, "
+                               f"got {max_new}")
+        if tier not in VALID_TIERS:
+            raise self._reject(rid, "bad_tier",
+                               f"submit: tier must be one of "
+                               f"{VALID_TIERS}, got {tier!r}")
         if prompt.size + max_new > self.cache_len:
             raise self._reject(
                 rid, "exceeds_cache",
@@ -1342,9 +841,80 @@ class PagedDecodeEngine(DecodeEngine):
             rid=rid, prompt=prompt, max_new=int(max_new),
             temperature=float(temperature), top_k=int(top_k),
             eos_id=eos_id, tenant=str(tenant), tier=str(tier),
-            bucket=0, submit_t=time.perf_counter(),
-            trace_id=str(trace) if trace else "")
-        return self._enqueue(req)
+            submit_t=time.perf_counter(),
+            trace_id=str(trace) if trace
+            else f"eng{self._engine_id}.r{rid}")
+        self._queue.append(req)
+        self._m_requests.inc()
+        self._m_queue.set(len(self._queue))
+        # the request's trace track: async ``request`` slice + nested
+        # ``queued`` slice
+        self._ev(req, "request", "b", req.submit_t, rid=rid,
+                 prompt_tokens=int(prompt.size), max_new=req.max_new,
+                 tenant=req.tenant, tier=req.tier)
+        self._ev(req, "queued", "b", req.submit_t)
+        return req
+
+    def abort_requests(self, reason: str = "replica_killed") -> int:
+        """Close every live request's open trace slices (``queued`` /
+        ``prefill`` / ``decode`` / ``request``) with an ``aborted``
+        marker and drop the work. This is the IN-PROCESS analogue of
+        the replica process dying: a real SIGKILL takes its span buffer
+        with it (the merged fleet trace simply never sees the dead
+        attempt), but an in-process fleet shares one buffer, so a kill
+        simulation must close what the dead attempt opened or the
+        joined trace shows unbalanced slices. Trace-level only — block
+        /slot accounting is abandoned, not released, exactly like a
+        dead process; do not reuse the engine afterwards."""
+        now = time.perf_counter()
+        aborted: List[EngineRequest] = []
+        # a preempted-to-blocks request closed its prefill/decode slices
+        # at preemption and waits under a fresh "queued" one, like the
+        # arrival queue's
+        for req in itertools.chain(self._queue, self._preempted):
+            self._ev(req, "queued", "e", now)
+            aborted.append(req)
+        for slot, req in enumerate(self._slot_req):
+            if req is None:
+                continue
+            if req.first_token_t is None:
+                self._ev(req, "prefill", "e", now)
+            if req.decode_open:
+                self._ev(req, "decode", "e", now)
+                req.decode_open = False
+            self._active[slot] = False
+            self._slot_req[slot] = None
+            aborted.append(req)
+        for req in aborted:
+            req.status, req.finish_reason = "aborted", reason
+            self._ev(req, "aborted", "n", now, reason=reason)
+            self._ev(req, "request", "e", now)
+        self._queue.clear()
+        self._preempted.clear()
+        self._m_queue.set(0)
+        return len(aborted)
+
+    @property
+    def active_count(self) -> int:
+        return int(self._active.sum())
+
+    @property
+    def queue_depth(self) -> int:
+        return len(self._queue)
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    @property
+    def preempted_count(self) -> int:
+        """Preempted requests parked awaiting resume."""
+        return len(self._preempted)
+
+    @property
+    def idle(self) -> bool:
+        return (not self._queue and not self._preempted
+                and not self._prefilling and not self._active.any())
 
     # -- P/D disaggregation (KV transfer over the fleet wire) -------------
     def prefix_digests(self, prompt) -> List[bytes]:
@@ -1576,17 +1146,10 @@ class PagedDecodeEngine(DecodeEngine):
             self._ev(req, "tier_promote", "n", time.perf_counter(),
                      blocks=promoted)
 
-    @property
-    def preempted_count(self) -> int:
-        """Preempted requests parked awaiting resume."""
-        return len(self._preempted)
-
-    @property
-    def idle(self) -> bool:
-        return (not self._queue and not self._preempted
-                and not self._prefilling and not self._active.any())
-
     # -- scheduler ---------------------------------------------------------
+    def _seed(self) -> np.int32:
+        return np.int32(self._rng.randint(0, 2 ** 31 - 1))
+
     def _alloc_page(self, slot: int):
         b = self.pool.alloc()
         self._pages[slot, self._nalloc[slot]] = b
@@ -2072,17 +1635,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._draft_chunk_hook(slot, padded, c, npages)
         return tok
 
-    def _precompile_prefill(self):
-        # every (chunk bucket, context span) program of the chunk grid,
-        # each on a zero-length chunk: its span write is fully masked,
-        # so the pool keeps its bytes
-        bs = self.block_size
-        for ctx in range(0, self.cache_len, self.chunk_tokens):
-            for b in self.buckets:
-                np.asarray(self._dispatch_chunk(
-                    0, np.zeros((1, b), np.int32), 0,
-                    ctx // bs + -(-b // bs), 0.0, 0, np.int32(0)))
-
     def _prefill_chunk(self, finished: List[EngineRequest]):
         from paddle_tpu.core import ragged
         with self.phase("schedule"):
@@ -2127,9 +1679,9 @@ class PagedDecodeEngine(DecodeEngine):
         and on the prompt's final chunk hand the slot to decode."""
         now = chunk.end
         # accumulate per-chunk device time; the histogram observes one
-        # per-request total at the final chunk so its semantics match
-        # the row-arena engine's (chunk-grain timing lives in
-        # engine_prefill_chunk_seconds and the stall histogram)
+        # per-request total at the final chunk (chunk-grain timing
+        # lives in engine_prefill_chunk_seconds and the stall
+        # histogram)
         self._slot_prefill_s[slot] += now - chunk.t0
         self._m_chunks.inc()
         if stalled:
@@ -2187,18 +1739,6 @@ class PagedDecodeEngine(DecodeEngine):
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
 
-    def _consume_forced(self, slot: int) -> bool:
-        forced = self._slot_forced[slot]
-        if not forced:
-            return False
-        # replay: the decode step ran at the right (pos, last) and its
-        # pool write is what matters; the sampled id re-derives the
-        # known next token (bitwise under greedy), which advances the
-        # cursor WITHOUT re-emitting — the caller already holds it
-        self._pos[slot] += 1
-        self._last[slot] = forced.popleft()
-        return True
-
     def _finish(self, req: EngineRequest, reason: str, now: float):
         slot = req.slot
         if slot >= 0:
@@ -2213,17 +1753,61 @@ class PagedDecodeEngine(DecodeEngine):
             self._pages_dev = None
             self._slot_forced[slot] = deque()
             self._uncharge_tenant(req)
-        super()._finish(req, reason, now)
+            self._active[slot] = False
+            self._slot_req[slot] = None
+            self._free.append(slot)
+        req.status, req.finish_reason, req.finish_t = "done", reason, now
+        self._m_completed.inc(reason=reason)
+        if req.latency_s and req.latency_s > 0:
+            goodput = len(req.tokens) / req.latency_s
+            self._m_goodput.observe(goodput)
+            self._win_tps.observe(goodput)
+        if req.decode_open:
+            self._ev(req, "decode", "e", now)
+            req.decode_open = False
+        self._ev(req, "finished", "n", now, reason=reason,
+                 tokens=len(req.tokens))
+        self._ev(req, "request", "e", now)
+        self._record_request(req)
+        self._update_window_gauges()
+
+    def _emit(self, req: EngineRequest, tok: int, now: float) -> bool:
+        """Record one emitted token; True when the request finished."""
+        req.tokens.append(int(tok))
+        self._m_tokens.inc()
+        finishing = ((req.eos_id is not None and tok == req.eos_id)
+                     or len(req.tokens) >= req.max_new)
+        if req.first_token_t is None:
+            req.first_token_t = now
+            ttft = now - req.submit_t
+            self._m_ttft_s.observe(ttft)
+            self._win_ttft.observe(ttft)
+            self._tier_window(req.tier).observe(ttft)
+            self._ev(req, "prefill", "e", now)
+            self._ev(req, "first_token", "n", now,
+                     ttft_ms=round(1000 * ttft, 3))
+            if not finishing:
+                self._ev(req, "decode", "b", now)
+                req.decode_open = True
+        if req.eos_id is not None and tok == req.eos_id:
+            self._finish(req, "eos", now)
+            return True
+        if len(req.tokens) >= req.max_new:
+            self._finish(req, "max_tokens", now)
+            return True
+        return False
 
     def _schedule(self, finished: List[EngineRequest]):
+        """Admission and prefill-chunk work, ahead of the decode
+        step."""
         with self.phase("schedule"):
             self._admit(finished)
         # With decoders in flight, at most ONE chunk runs per step —
         # the stall a prefill inflicts on them is bounded by a single
         # chunk program. With NOTHING decoding there is nobody to
         # stall: drain chunks back-to-back (a burst of arrivals reaches
-        # its first tokens as fast as the row engine's monolithic
-        # prefill would) until a finished prompt activates a decoder.
+        # its first tokens as fast as the chunk programs run) until a
+        # finished prompt activates a decoder.
         while self._prefilling:
             self._prefill_chunk(finished)
             if finished:
@@ -2234,6 +1818,7 @@ class PagedDecodeEngine(DecodeEngine):
                 break
 
     def _pre_decode(self):
+        """Host bookkeeping a decode step needs first."""
         # lazily allocate the page each active row is about to write
         # (reservation at admission guarantees this never fails)
         for slot in np.flatnonzero(self._active):
@@ -2241,6 +1826,8 @@ class PagedDecodeEngine(DecodeEngine):
                 self._alloc_page(slot)
 
     def _decode_extra(self):
+        """The decode programs' page-table argument, uploaded when it
+        changed."""
         if self._pages_dev is None:
             self._pages_dev = self._jnp.asarray(self._pages)
         return (self._pages_dev,)
@@ -2270,20 +1857,71 @@ class PagedDecodeEngine(DecodeEngine):
                     "calls made by decode steps"))
         return self._m_moe
 
-    def _precompile_decode(self):
-        out = np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
-        if out.size > self.batch:   # counts after the ids, none counted
-            self._moe_counters()
+    def step(self) -> List[EngineRequest]:
+        """One scheduler iteration: admit waiting requests, run the
+        prefill chunks due (one while anything decodes), then one
+        batched decode step for everything in flight. Returns the
+        requests that finished during this step."""
+        finished: List[EngineRequest] = []
+        self._open_step()
+        self._schedule(finished)
+        if self._active.any():
+            with self.phase("decode_stage") as stage:
+                self._pre_decode()
+                staged = self._stage_decode(self._seed())
+            with self.phase("decode_dispatch"):
+                nxt = self._call_decode(*staged)
+            with self.phase("decode_sync") as sync:
+                # the only device->host transfer: [B] int32 ids (and,
+                # after them, an expert layer's three counts)
+                nxt = np.asarray(nxt)
+            now = self._close_decode(stage, sync)
+            with self.phase("emit"):
+                if nxt.size > self.batch:
+                    for m, n in zip(self._moe_counters(),
+                                    nxt[self.batch:]):
+                        m.inc(int(n))
+                for slot in np.flatnonzero(self._active):
+                    self._pos[slot] += 1
+                    forced = self._slot_forced[slot]
+                    if forced:
+                        # replay after a preempt-to-blocks resume: the
+                        # step ran at the right (pos, last) and its
+                        # pool write is what matters; the sampled id
+                        # re-derives the known next token (bitwise
+                        # under greedy), which advances the cursor
+                        # WITHOUT re-emitting — the caller holds it
+                        self._last[slot] = forced.popleft()
+                        continue
+                    req = self._slot_req[slot]
+                    tok = int(nxt[slot])
+                    self._last[slot] = tok
+                    if self._emit(req, tok, now):
+                        finished.append(req)
+        self._close_step()
+        return finished
 
-    def _step_ids(self, out: np.ndarray) -> np.ndarray:
-        B = self.batch
-        if out.size > B:
-            for m, n in zip(self._moe_counters(), out[B:]):
-                m.inc(int(n))
-        return out[:B]
+    def _open_step(self):
+        self._tag = {"step": int(self._m_steps.value()),
+                     "active": self.active_count}
 
-    def _update_gauges(self):
-        super()._update_gauges()
+    def _close_decode(self, stage: _Phase, sync: _Phase) -> float:
+        """Account one completed decode step (stage + dispatch + sync);
+        returns its completion time."""
+        now = sync.end
+        self._m_step_s.observe(now - stage.t0)
+        self._m_steps.inc()
+        if self._step_end is not None \
+                and now - self._step_end > _SLOW_STEP_S:
+            self._m_slow_steps.inc()
+        self._step_end = now
+        return now
+
+    def _close_step(self):
+        if not self._active.any():
+            self._step_end = None       # no decoder in flight: the next
+            #                             interval is not a step's
+        self._m_occupancy.set(self.active_count)
         pool = self.pool
         self._m_blocks_in_use.set(pool.in_use)
         self._m_blocks_free.set(pool.free_count)
@@ -2292,21 +1930,108 @@ class PagedDecodeEngine(DecodeEngine):
             self._m_evictions.inc(pool.evictions - self._evictions_seen)
             self._evictions_seen = pool.evictions
 
+    def _stage_decode(self, seed):
+        """The decode program's arguments over the current slot state
+        (the [B] vectors and the page table uploaded) and their
+        compile-tracker signature."""
+        jnp = self._jnp
+        args = (self.params, self.cache, jnp.asarray(self._last),
+                jnp.asarray(self._pos), jnp.asarray(self._active),
+                *self._decode_extra(),
+                jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
+        return args, _ct.arg_signature(args, {})
+
+    def _call_decode(self, args, sig):
+        """One batched decode program over staged arguments; returns
+        the sampled ids (still on device)."""
+        nxt, self.cache = self._tracker.call_signed(
+            "serving_engine.decode", sig, self._decode_fn, *args)
+        return nxt
+
+    def precompile(self) -> Dict[str, int]:
+        """Run every program this engine can dispatch once, on inputs
+        that change nothing a request will read (no active row;
+        zero-length chunks), so each is compiled — and has executed on
+        the device — before the engine reports
+        ready. A kernel the compiler refuses raises HERE, with the
+        compiler's message, instead of in the middle of traffic; later
+        dispatches hit the jit cache. Only valid on an idle engine.
+        Returns :meth:`compile_counts`."""
+        if not self.idle:
+            raise RuntimeError("precompile() needs an idle engine")
+        with _trace.trace_scope("precompile/prefill"):
+            # every (chunk bucket, context span) program of the chunk
+            # grid, each on a zero-length chunk: its span write is
+            # fully masked, so the pool keeps its bytes
+            bs = self.block_size
+            for ctx in range(0, self.cache_len, self.chunk_tokens):
+                for b in self.buckets:
+                    np.asarray(self._dispatch_chunk(
+                        0, np.zeros((1, b), np.int32), 0,
+                        ctx // bs + -(-b // bs), 0.0, 0, np.int32(0)))
+        with _trace.trace_scope("precompile/decode"):
+            self._precompile_decode()     # ends in a host read: all done
+        return self.compile_counts()
+
+    def _precompile_decode(self):
+        # no active row: every cache write of the step is dropped
+        out = np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
+        if out.size > self.batch:   # counts after the ids, none counted
+            self._moe_counters()
+
+    def run_until_idle(self, max_steps: int = 100_000
+                       ) -> List[EngineRequest]:
+        """Drive ``step()`` until queue and slots drain; returns every
+        request finished along the way (submission order not guaranteed
+        — requests terminate independently)."""
+        done: List[EngineRequest] = []
+        for _ in range(max_steps):
+            if self.idle:
+                return done
+            done.extend(self.step())
+        raise RuntimeError(f"engine did not drain in {max_steps} steps "
+                           f"({self.queue_depth} queued, "
+                           f"{self.active_count} active)")
+
     # -- observability -----------------------------------------------------
+    def decode_mfu(self) -> Optional[float]:
+        """Mean decode-step MFU over this engine's lifetime: decode
+        FLOPs / (mean step seconds × chip peak). None until a step ran
+        or when FLOPs/peak are unknown — the figure ``serving_bench``
+        reports (XLA's cost model over host time: no share of a chip)."""
+        cell = self._m_step_s._peek({})
+        if cell is None or not cell.count:
+            return None
+        return _costs.mfu(self.decode_flops, cell.sum / cell.count,
+                          self._peak_flops)
+
     def health(self) -> dict:
-        doc = super().health()
-        doc.update({"block_size": self.block_size,
-                    "blocks_total": self.num_blocks,
-                    "blocks_in_use": self.pool.in_use,
-                    "blocks_cached": self.pool.cached_free_count,
-                    "prefix_cache_entries": self.pool.cached_count,
-                    "chunk_tokens": self.chunk_tokens,
-                    "kv_dtype": self.kv_dtype,
-                    "kv_bytes_per_token": self.kv_bytes_per_token,
-                    "pool_bytes": self.pool_bytes,
-                    "recurrent_state_bytes": self.recurrent_state_bytes,
-                    "preempted_queued": len(self._preempted),
-                    "preemptions": int(self._m_preempts.value())})
+        doc = {"requests": int(self._m_requests.value()),
+               "completed": sum(
+                   int(self._m_completed.value(reason=r))
+                   for r in ("eos", "max_tokens")),
+               "tokens": int(self._m_tokens.value()),
+               "decode_steps": int(self._m_steps.value()),
+               "queue_depth": self.queue_depth,
+               "slots_active": self.active_count,
+               "slots_total": self.batch,
+               "cache_len": self.cache_len,
+               "pallas": self.pallas_mode,
+               "kernel_paths": self.kernel_paths,
+               "prefill_buckets": list(self.buckets),
+               "block_size": self.block_size,
+               "blocks_total": self.num_blocks,
+               "blocks_in_use": self.pool.in_use,
+               "blocks_cached": self.pool.cached_free_count,
+               "prefix_cache_entries": self.pool.cached_count,
+               "chunk_tokens": self.chunk_tokens,
+               "kv_dtype": self.kv_dtype,
+               "kv_bytes_per_token": self.kv_bytes_per_token,
+               "pool_bytes": self.pool_bytes,
+               "recurrent_state_bytes": self.recurrent_state_bytes,
+               "preempted_queued": len(self._preempted),
+               "preemptions": int(self._m_preempts.value())}
+        self._update_window_gauges()
         # per-token decode FLOPs: the recompute cost the fleet router's
         # fetch-vs-recompute crossover weighs against kv_bytes_per_token
         if self.decode_flops:
@@ -2328,7 +2053,71 @@ class PagedDecodeEngine(DecodeEngine):
                 t: {"tokens_in_flight": self._tenant_used.get(t, 0),
                     "budget": self.tenant_budgets.get(t)}
                 for t in tenants}
+        ttft = self._win_ttft.quantiles((0.5, 0.95, 0.99))
+        doc["window"] = {
+            "window_s": self._win_ttft.window_s,
+            "requests": self._win_ttft.count(),
+            "ttft_p50_s": round(ttft[0.5], 6),
+            "ttft_p95_s": round(ttft[0.95], 6),
+            "ttft_p99_s": round(ttft[0.99], 6),
+            "tokens_per_sec_p50": round(self._win_tps.quantile(0.5), 3),
+            # raw windowed TTFT samples in clock-free [age_s, value]
+            # form (newest 512): the fleet aggregator POOLS these for
+            # its fleet quantiles — per-replica quantiles cannot be
+            # averaged (see WindowedQuantiles.samples)
+            "ttft_samples": [[round(a, 4), round(v, 6)] for a, v in
+                             self._win_ttft.export_samples()[-512:]]}
+        if self._win_ttft_tier:
+            doc["window"]["tiers"] = {
+                tier: {"requests": win.count(),
+                       "ttft_p50_s": round(win.quantile(0.5), 6),
+                       "ttft_p99_s": round(win.quantile(0.99), 6)}
+                for tier, win in sorted(self._win_ttft_tier.items())}
+        if self.slo is not None:
+            burn = self._slo_burn_rate()
+            doc["slo"] = {"ttft_s": self.slo.ttft_s,
+                          "target": self.slo.target,
+                          "window_s": self.slo.window_s,
+                          "burn_threshold": self.slo.burn_threshold,
+                          "ttft_burn_rate": round(burn, 4)}
+            if burn > self.slo.burn_threshold:
+                # degraded, NOT unhealthy: /healthz stays 200 (load
+                # balancers keep routing) while the reason is machine-
+                # readable — the hook the SLO-aware scheduler steers on
+                doc["status"] = "degraded"
+                doc["degraded_reason"] = (
+                    f"ttft_slo_burn_rate {burn:.2f} > "
+                    f"{self.slo.burn_threshold} (p99 "
+                    f"{ttft[0.99]:.4f}s vs slo {self.slo.ttft_s}s over "
+                    f"{self._win_ttft.count()} requests)")
         return doc
+
+    def requests_doc(self, k: int = 10) -> dict:
+        """The `/requests` section: aggregate summary + top-k slowest
+        with attributed latency components."""
+        doc = self.request_log.summary()
+        doc["slowest_by_ttft"] = self.request_log.slowest(k, by="ttft_s")
+        return doc
+
+    def metrics_text(self) -> str:
+        self._update_window_gauges()   # expire-on-read: see the docstring
+        return self.metrics.render_prometheus()
+
+    def serve(self, host: str = "127.0.0.1", port: int = 0):
+        """/metrics + /healthz + /requests over this engine's registry;
+        caller owns ``close()``."""
+        from paddle_tpu.observe.health import HealthServer
+        return HealthServer(registry=self.metrics, health_fn=self.health,
+                            host=host, port=port,
+                            requests_fn=self.requests_doc,
+                            metrics_fn=self.metrics_text)
+
+    def compile_counts(self) -> Dict[str, int]:
+        """Compilations the tracker charged to this engine's two
+        programs — the "one per bucket + one for decode" invariant."""
+        return {"prefill": self._tracker.count("serving_engine.prefill"),
+                "decode": self._tracker.count("serving_engine.decode")}
+
 
 
 class SpecDecodeEngine(PagedDecodeEngine):

@@ -204,8 +204,6 @@ class EngineLoop:
 
     def _op_export(self, r: dict, reply):
         eng = self.eng
-        if not hasattr(eng, "export_prefix"):
-            raise ValueError("export_prefix needs a paged engine")
         prompt = np.asarray(r["prompt"], np.int32).reshape(-1)
         xid = r.get("id")
         digests = eng.prefix_digests(prompt)
@@ -254,10 +252,7 @@ class EngineLoop:
                 "blocks": int(blocks)}
 
     def _op_import(self, r: dict, reply):
-        eng = self.eng
-        if not hasattr(eng, "import_prefix"):
-            raise ValueError("import_prefix needs a paged engine")
-        n = eng.import_prefix(base64.b64decode(r["payload"]))
+        n = self.eng.import_prefix(base64.b64decode(r["payload"]))
         reply.write(self._stamp({"id": r.get("id"), "op": "import_prefix",
                                  "imported": int(n)}))
 
@@ -501,8 +496,7 @@ class EngineReplica:
         The router's requeue path sees exactly what a dead socket
         shows it."""
         self._killed = True
-        if hasattr(self.eng, "abort_requests"):
-            self.eng.abort_requests()
+        self.eng.abort_requests()
 
     def metrics_snapshot(self) -> Optional[dict]:
         """The engine registry's snapshot dict — the fleet aggregator's

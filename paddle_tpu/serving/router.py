@@ -1300,7 +1300,7 @@ class Router:
             "top_k": req.top_k, "eos_id": req.eos_id,
             "tenant": req.tenant, "tier": req.tier}
         if req.trace_id:
-            # the replica engine ADOPTS this id (its _enqueue only
+            # the replica engine ADOPTS this id (its submit only
             # mints one when the wire didn't carry one), so its
             # queued/prefill/decode spans join this very track
             spec["trace"] = req.trace_id

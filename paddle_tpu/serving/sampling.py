@@ -159,61 +159,6 @@ def _recorded(fn, paths, name):
     return wrapped
 
 
-def engine_step_fns(cfg, dequant=None, pallas=None):
-    """(prefill_fn, decode_fn) closures over a TransformerConfig — the
-    two programs the engine compiles (once per prefill bucket, once for
-    decode) and ``save_lm_artifact`` exports as the format-v3 modules.
-
-    ``dequant`` optionally maps the stored param tree to live weights
-    for PREFILL (the weights_int8 artifact path); the decode step
-    consumes {"q8","scale"} trees natively (in-scan dequant — 1-byte
-    weight reads per token) and needs no dequant either way.
-    ``pallas`` resolves the package-wide ``PADDLE_TPU_PALLAS`` policy
-    (explicit arg > env > auto): when the kernels are on, the decode
-    sampling tail runs the Pallas ``fused_sample`` epilogue. The slot
-    arena's attention itself stays XLA — the flash-decode kernel
-    targets the paged pool layout (``paged_step_fns``).
-
-    prefill_fn(params, cache, tokens [1, Tb], length (), slot (),
-               temperature (), top_k (), seed ()) → (token (), cache)
-    decode_fn(params, cache, tokens [B], pos [B], active [B] bool,
-              temperature [B], top_k [B], seed ()) → (tokens [B], cache)
-
-    Sampling happens inside both programs, so each call returns int32
-    ids only — no logits cross the host boundary. ``seed`` is a fresh
-    per-call int32; any randomness derives inside the program, keeping
-    the exported signature plain-integer.
-    """
-    from paddle_tpu.models import transformer
-    from paddle_tpu.ops.pallas import policy as _pallas_policy
-
-    mode = _pallas_policy.pallas_mode(pallas)
-    _live = _prefill_live(dequant)
-    _live_d = _decode_live(dequant)
-    tail = _epilogue(mode)
-
-    def prefill_fn(params, cache, tokens, length, slot, temperature,
-                   top_k, seed):
-        logits, cache = transformer.prefill_into_slot(
-            _live(params), cache, tokens, length, slot, cfg)
-        tok = tail(logits, seed, jnp.reshape(temperature, (1,)),
-                   jnp.reshape(top_k, (1,)))
-        return tok[0], cache
-
-    def decode_fn(params, cache, tokens, pos, active, temperature,
-                  top_k, seed):
-        logits, cache = transformer.decode_step_slots(
-            _live_d(params), cache, tokens, pos, active, cfg)
-        return tail(logits, seed, temperature, top_k), cache
-
-    paths = {}
-    prefill_fn = _recorded(
-        prefill_fn, paths, lambda p, c, tokens, *_: f"prefill_{tokens.shape[1]}")
-    decode_fn = _recorded(decode_fn, paths, lambda *_: "decode")
-    prefill_fn.kernel_paths = decode_fn.kernel_paths = paths
-    return prefill_fn, decode_fn
-
-
 def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
     """(prefill_chunk_fn, decode_fn) for the PAGED block-pool engine —
     compiled once per chunk bucket / once for decode, and exported by

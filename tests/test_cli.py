@@ -109,10 +109,11 @@ class TestCliServe:
             vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
             d_ff=32, max_len=32, dtype=jnp.float32, use_rope=True)
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        model = str(tmp_path / "lm_v3.tar")
+        model = str(tmp_path / "lm_v4.tar")
         lm_serving.save_lm_artifact(model, params, cfg, batch=2,
                                     prompt_len=4, cache_len=24,
-                                    engine_buckets=(8,))
+                                    engine_buckets=(8,),
+                                    engine_block_size=8)
         rng = np.random.RandomState(0)
         prompts = [rng.randint(0, 40, n).tolist() for n in (4, 7)]
         lines = [json.dumps({"prompt": p, "max_new": 5})
@@ -175,7 +176,6 @@ class TestCliServe:
         lm_serving.save_lm_artifact(model, params, cfg, batch=2,
                                     prompt_len=4, cache_len=32,
                                     engine_buckets=(8,),
-                                    engine_paged=True,
                                     engine_block_size=8)
         rng = np.random.RandomState(0)
         lines = [
@@ -215,7 +215,6 @@ class TestCliServe:
         lm_serving.save_lm_artifact(model, params, cfg, batch=2,
                                     prompt_len=4, cache_len=32,
                                     engine_buckets=(8,),
-                                    engine_paged=True,
                                     engine_block_size=8)
         rc = cli.main(["serve", f"--model={model}",
                        "--tenant-budget", "acme"])
@@ -239,10 +238,11 @@ class TestCliServe:
             vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
             d_ff=32, max_len=32, dtype=jnp.float32, use_rope=True)
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        model = str(tmp_path / "lm_v3.tar")
+        model = str(tmp_path / "lm_v4.tar")
         lm_serving.save_lm_artifact(model, params, cfg, batch=2,
                                     prompt_len=4, cache_len=24,
-                                    engine_buckets=(8,))
+                                    engine_buckets=(8,),
+                                    engine_block_size=8)
         p = subprocess.Popen(
             [_sys.executable, "-m", "paddle_tpu", "serve",
              f"--model={model}"],
@@ -296,10 +296,11 @@ class TestCliServe:
             vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
             d_ff=32, max_len=64, dtype=jnp.float32, use_rope=True)
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        model = str(tmp_path / "lm_v3_drain.tar")
+        model = str(tmp_path / "lm_v4_drain.tar")
         lm_serving.save_lm_artifact(model, params, cfg, batch=2,
                                     prompt_len=4, cache_len=64,
-                                    engine_buckets=(8,))
+                                    engine_buckets=(8,),
+                                    engine_block_size=8)
         p = subprocess.Popen(
             [_sys.executable, "-m", "paddle_tpu", "serve",
              f"--model={model}", "--health_port=0"],

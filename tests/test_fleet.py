@@ -68,6 +68,17 @@ def _mk_engine(lm, *, batch=2, num_blocks=None, kv_dtype=None):
         decode_flops=None, pallas_mode="off", kv_dtype=kv_dtype)
 
 
+def _mk_spec_engine(lm):
+    """A spec engine on the module's geometry; the target drafts for
+    itself (what is under test here is the wire, not acceptance)."""
+    from paddle_tpu.serving import SpecDecodeEngine
+    params, cfg = lm
+    return SpecDecodeEngine.from_params(
+        params, cfg, params, cfg, spec_k=2, batch=2, cache_len=64,
+        block_size=8, chunk_tokens=16, num_blocks=16, seed=0,
+        pallas="off", decode_flops=None)
+
+
 def _ref_outputs(lm, prompts, max_new):
     """Colocated single-engine reference outputs (greedy)."""
     eng = _mk_engine(lm)
@@ -315,6 +326,56 @@ class TestEngineLoop:
         assert D.run() == 0
         by_id = {d["id"]: d for d in ds.docs}
         assert by_id[1]["imported"] == 4
+        np.testing.assert_array_equal(
+            np.concatenate([prompt, by_id[2]["tokens"]]), want)
+
+    def test_spec_replica_serves_the_export_op(self, lm):
+        """The loop asks no engine which one it is: a spec replica
+        serves the export op like any other — the wire carries target
+        blocks, a target-only replica adopts them and decodes the
+        colocated tokens."""
+        prompt = np.random.RandomState(4).randint(
+            0, 40, 37).astype(np.int32)
+        want = _ref_outputs(lm, [prompt], 6)[0]
+        S, D = EngineLoop(_mk_spec_engine(lm)), EngineLoop(_mk_engine(lm))
+        ss, ds = ListReply(), ListReply()
+        S.feed({"id": 0, "op": "export_prefix",
+                "prompt": prompt.tolist()}, ss)
+        S.feed_eof()
+        assert S.run() == 0
+        (exp,) = ss.docs
+        assert exp["op"] == "export_prefix" and exp["blocks"] == 4
+        D.feed({"id": 1, "op": "import_prefix",
+                "payload": exp["payload"]}, ds)
+        D.feed({"id": 2, "prompt": prompt.tolist(), "max_new": 6}, ds)
+        D.feed_eof()
+        assert D.run() == 0
+        by_id = {d["id"]: d for d in ds.docs}
+        assert by_id[1]["imported"] == 4
+        np.testing.assert_array_equal(
+            np.concatenate([prompt, by_id[2]["tokens"]]), want)
+
+    def test_spec_replica_import_op_is_the_engines_refusal(self, lm):
+        """The import op against a spec replica comes back as the
+        engine's OWN refusal on an error line (no draft rows travel on
+        the wire) — not a check in the loop — and the loop keeps
+        serving."""
+        prompt = np.random.RandomState(4).randint(
+            0, 40, 37).astype(np.int32)
+        want = _ref_outputs(lm, [prompt], 6)[0]
+        P, ps = EngineLoop(_mk_engine(lm)), ListReply()
+        P.feed({"id": 0, "op": "export_prefix",
+                "prompt": prompt.tolist()}, ps)
+        P.feed_eof()
+        assert P.run() == 0
+        S, ss = EngineLoop(_mk_spec_engine(lm)), ListReply()
+        S.feed({"id": 1, "op": "import_prefix",
+                "payload": ps.docs[0]["payload"]}, ss)
+        S.feed({"id": 2, "prompt": prompt.tolist(), "max_new": 6}, ss)
+        S.feed_eof()
+        assert S.run() == 0
+        by_id = {d["id"]: d for d in ss.docs}
+        assert "SpecDecodeEngine cannot adopt" in by_id[1]["error"]
         np.testing.assert_array_equal(
             np.concatenate([prompt, by_id[2]["tokens"]]), want)
 
@@ -624,12 +685,13 @@ class TestRouterPlacement:
         assert 'router_replica_state{replica="r0"} 3' in text
 
     def test_export_refusal_falls_back_colocated(self):
-        """P/D mode: the prefill replica REFUSES the export (non-paged
-        artifact, budget rejection) — not a request failure; the
+        """P/D mode: the prefill replica REFUSES the export (a budget
+        rejection of the warm-up request) — not a request failure; the
         request completes colocated and the refusal is counted."""
         pf, dc = FakeReplica("pf"), FakeReplica("dc")
-        pf.export_reply = {"error": "export_prefix needs a paged "
-                                    "engine"}
+        pf.export_reply = {"error": "submit: 16 prompt + 1 new tokens "
+                                    "exceed tenant 'default's budget "
+                                    "of 8"}
         router = Router([pf, dc], block_size=4, chunk_tokens=8,
                         prefill=["pf"], max_in_flight=8,
                         health_poll_s=60.0)

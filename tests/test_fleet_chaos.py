@@ -40,7 +40,7 @@ def fleet_model(tmp_path_factory):
     lm_serving.save_lm_artifact(path, params, cfg, batch=2,
                                 prompt_len=6, cache_len=96,
                                 engine_buckets=(8, 16),
-                                engine_paged=True, engine_block_size=8)
+                                engine_block_size=8)
     return path, params, cfg
 
 

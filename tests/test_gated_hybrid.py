@@ -302,7 +302,7 @@ def test_artifact_round_trip(tmp_path):
     path = str(tmp_path / "lm.tar")
     lm_serving.save_lm_artifact(
         path, params, cfg, batch=2, prompt_len=8, cache_len=128,
-        engine_buckets=(16, 64), engine_paged=True, engine_block_size=BS,
+        engine_buckets=(16, 64), engine_block_size=BS,
         engine_num_blocks=16)
     srv = lm_serving.load_lm_artifact(path)
     assert srv.cfg == cfg
@@ -338,10 +338,6 @@ def test_refusals():
     toks = jnp.zeros((2,), jnp.int32)
     calls = {
         "decode_step": lambda: tr.decode_step(params, pool, toks, 0, cfg),
-        "decode_step_slots": lambda: tr.decode_step_slots(
-            params, pool, toks, toks, toks > 0, cfg),
-        "prefill_into_slot": lambda: tr.prefill_into_slot(
-            params, pool, toks[None], 1, 0, cfg),
         "verify_step_paged": lambda: tr.verify_step_paged(
             params, pool, toks[:, None], toks, toks, toks > 0,
             jnp.zeros((2, 4), jnp.int32), cfg, block_size=BS),
@@ -377,10 +373,9 @@ def test_refusals():
         assert what
     from paddle_tpu.io import lm_serving
     for kw in ({"weights_int8": True}, {"engine_kv_dtype": "int8"},
-               {"engine_paged": False}):
+               {"engine_buckets": None}):
         args = dict(batch=2, prompt_len=8, cache_len=64,
-                    engine_buckets=(16,), engine_paged=True,
-                    engine_block_size=BS)
+                    engine_buckets=(16,), engine_block_size=BS)
         args.update(kw)
         with pytest.raises(NotImplementedError, match="gated_hybrid"):
             lm_serving.save_lm_artifact("/nonexistent/x.tar", params, cfg,

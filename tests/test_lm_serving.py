@@ -221,34 +221,64 @@ def test_generate_eos_early_exit(tmp_path, rng):
 
 
 def test_engine_artifact_v3_roundtrip(tmp_path, rng):
-    """Format v3: engine modules ride the artifact; the continuous-
-    batching engine serves bitwise the same greedy tokens as the legacy
-    lockstep path, and v3 still loads into LMServer.generate."""
-    from paddle_tpu.observe.compile_tracker import CompileTracker
+    """Format v3 held the row-arena engine's modules, which nothing
+    runs any more: an artifact from an older export is outside input,
+    ``engine()`` refuses it with a one-line re-export hint (as it does
+    a stale pool layout), and its lockstep pair still serves."""
+    import io as _io
+    import json
+    import tarfile
+
+    import pytest
     params = transformer.init_params(jax.random.PRNGKey(0), CFG)
     B, Tp, new = 2, 6, 8
     prompt = rng.randint(0, 40, (B, Tp)).astype(np.int32)
-    path = str(tmp_path / "lm_v3.tar")
+    path = str(tmp_path / "lm_v4.tar")
     lm_serving.save_lm_artifact(path, params, CFG, batch=B,
-                                prompt_len=Tp, cache_len=Tp + new,
-                                engine_buckets=(8,))
-    srv = lm_serving.load_lm_artifact(path)
+                                prompt_len=Tp, cache_len=16,
+                                engine_buckets=(8,), engine_block_size=8)
+    # what an older export left: format 3, engine_buckets, the arena's
+    # two member names, no engine_paged section
+    old = str(tmp_path / "lm_v3.tar")
+    with tarfile.open(path) as src, tarfile.open(old, "w") as dst:
+        for m in src.getmembers():
+            blob, name = src.extractfile(m).read(), m.name
+            if name == "meta.json":
+                meta = json.loads(blob)
+                assert meta["format_version"] == 4
+                meta["format_version"] = 3
+                del meta["engine_paged"]
+                blob = json.dumps(meta).encode()
+            elif name.startswith("engine_prefill_paged_"):
+                name = "engine_prefill_8.bin"
+            elif name == "engine_decode_paged.bin":
+                name = "engine_decode.bin"
+            info = tarfile.TarInfo(name)
+            info.size = len(blob)
+            dst.addfile(info, _io.BytesIO(blob))
+    srv = lm_serving.load_lm_artifact(old)
     assert srv.meta["format_version"] == 3
     assert srv.engine_buckets == (8,)
-    assert srv.cost_analysis["engine_decode"]["flops"] > 0
-    # legacy lockstep path unchanged on a v3 artifact
+    with pytest.raises(ValueError, match="format v3.*re-export"):
+        srv.engine(seed=0)
     got = srv.generate(prompt, max_new=new)
     want = np.asarray(transformer.generate(
         params, jnp.asarray(prompt), CFG, max_new=new))
     np.testing.assert_array_equal(got, want)
-    # engine path: same tokens per request, one compile per program
-    tracker = CompileTracker()
-    eng = srv.engine(seed=0, tracker=tracker)
-    reqs = [eng.submit(prompt[i], max_new=new) for i in range(B)]
-    eng.run_until_idle()
-    for i, r in enumerate(reqs):
-        np.testing.assert_array_equal(r.output, want[i])
-    assert eng.compile_counts() == {"prefill": 1, "decode": 1}
+
+
+def test_engine_paged_false_refused(tmp_path):
+    """``engine_paged`` waits on its two callers to drop the keyword:
+    it accepts only ``True``. ``False`` asked for the row-arena
+    modules, which are gone, and says what to do instead."""
+    import pytest
+    params = transformer.init_params(jax.random.PRNGKey(0), CFG)
+    with pytest.raises(ValueError, match="PR 28.*drop the keyword"):
+        lm_serving.save_lm_artifact(
+            str(tmp_path / "bad.tar"), params, CFG, batch=2,
+            prompt_len=6, cache_len=32, engine_buckets=(8,),
+            engine_paged=False)
+    assert not (tmp_path / "bad.tar").exists()
 
 
 def test_engine_artifact_v4_paged_roundtrip(tmp_path, rng):
@@ -266,7 +296,7 @@ def test_engine_artifact_v4_paged_roundtrip(tmp_path, rng):
     lm_serving.save_lm_artifact(path, params, CFG, batch=B,
                                 prompt_len=Tp, cache_len=32,
                                 engine_buckets=(8, 16),
-                                engine_paged=True, engine_block_size=8)
+                                engine_block_size=8)
     from paddle_tpu.ops.pallas import policy as pallas_policy
     srv = lm_serving.load_lm_artifact(path)
     assert srv.meta["format_version"] == 4
@@ -337,7 +367,7 @@ def test_engine_artifact_legacy_pool_layout_hint(tmp_path, rng):
     lm_serving.save_lm_artifact(path, params, CFG, batch=2,
                                 prompt_len=6, cache_len=32,
                                 engine_buckets=(8, 16),
-                                engine_paged=True, engine_block_size=8)
+                                engine_block_size=8)
     # simulate a pre-relayout artifact: strip the pool_layout stamp
     # (absent == slot_major, the legacy default)
     legacy = str(tmp_path / "lm_v4_slotmajor.tar")
@@ -375,7 +405,7 @@ def test_engine_artifact_v4_int8_roundtrip(tmp_path, rng):
     lm_serving.save_lm_artifact(path, params, CFG, batch=2,
                                 prompt_len=6, cache_len=32,
                                 engine_buckets=(8, 16),
-                                engine_paged=True, engine_block_size=8,
+                                engine_block_size=8,
                                 weights_int8=True)
     srv = lm_serving.load_lm_artifact(path)
     assert srv.meta["format_version"] == 4
@@ -410,7 +440,7 @@ def test_engine_artifact_v4_kv_int8_roundtrip(tmp_path, rng):
     lm_serving.save_lm_artifact(path, params, CFG, batch=2,
                                 prompt_len=6, cache_len=32,
                                 engine_buckets=(8, 16),
-                                engine_paged=True, engine_block_size=8,
+                                engine_block_size=8,
                                 engine_kv_dtype="int8")
     srv = lm_serving.load_lm_artifact(path)
     assert srv.meta["engine_paged"]["kv_dtype"] == "int8"
@@ -431,15 +461,11 @@ def test_engine_artifact_v4_kv_int8_roundtrip(tmp_path, rng):
     h = eng.health()
     assert h["kv_dtype"] == "int8"
     assert h["kv_bytes_per_token"] == ref.kv_bytes_per_token
-    # the quantized pool is a paged layout — the slot-arena export
-    # cannot carry it, and an export with NO engine at all must raise
-    # too rather than silently dropping the requested quantization
-    with pytest.raises(ValueError, match="engine_paged"):
-        lm_serving.save_lm_artifact(
-            str(tmp_path / "bad.tar"), params, CFG, batch=2,
-            prompt_len=6, cache_len=32, engine_buckets=(8,),
-            engine_kv_dtype="int8")
-    with pytest.raises(ValueError, match="engine_paged"):
+    # the quantized pool is the engine's: an export with NO engine
+    # modules must raise rather than silently dropping the requested
+    # quantization
+    with pytest.raises(ValueError,
+                       match="engine_kv_dtype needs engine_buckets"):
         lm_serving.save_lm_artifact(
             str(tmp_path / "bad2.tar"), params, CFG, batch=2,
             prompt_len=6, cache_len=32, engine_kv_dtype="int8")
@@ -495,7 +521,7 @@ def test_engine_pallas_resolves_from_the_export_target(tmp_path,
     cfg = dataclasses.replace(CFG, max_len=128)
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     kw = dict(batch=2, prompt_len=4, cache_len=128,
-              engine_buckets=(128,), engine_paged=True)
+              engine_buckets=(128,))
     tpu = str(tmp_path / "tpu.tar")
     with policy.compile_target("TPU v5 lite"):
         lm_serving.save_lm_artifact(tpu, params, cfg,

@@ -364,6 +364,44 @@ class TestTierObservability:
             assert b == e_, (phase, b, e_, [
                 (e["name"], e["ph"]) for e in evs])
 
+    def test_abort_closes_queued_preempted_and_running(self, rng):
+        """``abort_requests`` (an in-process replica kill) reaches every
+        place a live request can be — the queue, the preempted line, a
+        slot — closes exactly the slices each has open, and leaves
+        nothing to schedule."""
+        from paddle_tpu import observe
+        buf = observe.default_buffer()
+        if not buf.enabled or buf.capacity < 4096:
+            buf = observe.set_trace_capacity(8192)
+        buf.clear()
+        eng = _paged(num_blocks=4)
+        prompt = rng.randint(0, 40, 8).astype(np.int32)
+        victim = eng.submit(prompt, max_new=16, tier="batch")
+        for _ in range(6):
+            eng.step()
+        lat = eng.submit(prompt, max_new=8, tier="latency")
+        eng.step()
+        waiting = eng.submit(prompt, max_new=16, tier="batch")
+        assert victim.status == "preempted" and eng.preempted_count == 1
+        assert lat.status in ("prefilling", "running")
+        assert waiting.status == "queued"
+        assert eng.abort_requests("replica_killed") == 3
+        assert eng.idle and eng.preempted_count == 0
+        assert eng.metrics.get("engine_queue_depth").value() == 0
+        events = observe.trace_export()["traceEvents"]
+        for r in (victim, lat, waiting):
+            assert (r.status, r.finish_reason) == \
+                ("aborted", "replica_killed")
+            evs = [e for e in events if e.get("id") == r.trace_id]
+            assert "aborted" in [e["name"] for e in evs]
+            for phase in ("request", "queued", "prefill", "decode"):
+                b = sum(1 for e in evs
+                        if e["name"] == phase and e["ph"] == "b")
+                e_ = sum(1 for e in evs
+                         if e["name"] == phase and e["ph"] == "e")
+                assert b == e_, (r.tier, phase, b, e_)
+
+
 
 class TestPoolUnpublish:
     def test_unpublish_drops_cache_entry_and_frees_lru(self):

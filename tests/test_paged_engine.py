@@ -1,5 +1,6 @@
 """Paged KV engine: block-table decode must be bitwise-faithful to the
-slot arena, chunked prefill must reproduce monolithic prefill, the
+lockstep decode step, chunked prefill must reproduce monolithic
+prefill, the
 block pool must never leak or double-free, prefix-cache hits must serve
 bitwise the cold-prefill tokens, and the engine still compiles once per
 chunk bucket + once for decode."""
@@ -25,8 +26,8 @@ BS = 8          # block size shared by the kernel contracts below
 
 
 def _pool_from_arena(cache, cfg):
-    """Arena [L, B, T, Hkv, Dh] -> head-major flat pool [L, Hkv, M, Dh]
-    with the identity paging (slot b's pages tile its contiguous
+    """Lockstep cache [L, B, T, Hkv, Dh] -> head-major flat pool
+    [L, Hkv, M, Dh] with the identity paging (slot b's pages tile its contiguous
     span)."""
     L, B, T = cache["k"].shape[:3]
     pool = {k: jnp.moveaxis(jnp.reshape(
@@ -48,24 +49,30 @@ class TestPagedKernels:
     @pytest.mark.parametrize("cfg", [CFG, CFG_ABS],
                              ids=["rope", "learned-pos"])
     def test_paged_decode_bitwise_matches_slots(self, cfg, rng):
-        """Identity paging: decode_step_paged == decode_step_slots
-        bitwise (logits AND written cache), both position encodings."""
+        """Identity paging, one shared position: the active slots of
+        decode_step_paged == the lockstep decode_step, bitwise on the
+        CPU (logits AND written cache; the gathered view has the
+        lockstep cache's shape, so the reductions are the same), both
+        position encodings; the inactive slot's rows keep their
+        bytes."""
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
         B, Tp, T = 3, 6, 32
         prompt = jnp.asarray(rng.randint(0, 40, (B, Tp)), jnp.int32)
         logits, cache = transformer.prefill(params, prompt, cfg, T)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        pos = jnp.asarray([6, 3, 9], jnp.int32)
+        l_lock, c_lock = transformer.decode_step(
+            params, cache, tok, jnp.asarray(Tp, jnp.int32), cfg)
+        on = [0, 2]
         active = jnp.asarray([True, False, True])
-        l_slot, c_slot = transformer.decode_step_slots(
-            params, cache, tok, pos, active, cfg)
         pool, pages = _pool_from_arena(cache, cfg)
         l_paged, c_paged = transformer.decode_step_paged(
-            params, pool, tok, pos, active, pages, cfg, block_size=BS)
-        np.testing.assert_array_equal(np.asarray(l_slot),
-                                      np.asarray(l_paged))
+            params, pool, tok, jnp.full((B,), Tp, jnp.int32), active,
+            pages, cfg, block_size=BS)
+        np.testing.assert_array_equal(np.asarray(l_lock)[on],
+                                      np.asarray(l_paged)[on])
         for leaf in ("k", "v"):
-            a = np.asarray(c_slot[leaf])            # [L, B, T, Hkv, Dh]
+            a = np.asarray(c_lock[leaf]).copy()     # [L, B, T, Hkv, Dh]
+            a[:, 1] = np.asarray(cache[leaf])[:, 1]     # never written
             want = np.moveaxis(a.reshape(
                 (a.shape[0], -1) + a.shape[3:]), 1, 2)
             np.testing.assert_array_equal(want, np.asarray(c_paged[leaf]))
@@ -175,23 +182,29 @@ class TestPagedKernels:
                 assert (got[:, :, w] != want).any(axis=-1).all()
 
     def test_prefill_into_blocks_matches_slot_prefill(self, rng):
-        """Block prefill reproduces prefill_into_slot's gathered-head
-        logits (tolerance contract: the two trace different einsum
-        shapes) and leaves unmapped blocks zero."""
+        """Block prefill of a right-padded chunk reproduces the
+        lockstep prefill's last-position logits and its KV rows, and
+        leaves unmapped blocks zero. Tolerance, not bitwise: the chunk
+        program attends over concat(context, chunk) at the bucket's
+        length, the lockstep pass over the unpadded prompt — programs
+        of different shapes round differently (ROADMAP Queue 3, the
+        bitwise contract)."""
         Tp, T = 6, 24
         prompt = jnp.asarray(rng.randint(0, 40, (1, Tp)), jnp.int32)
-        arena = transformer.init_cache(CFG, 1, T)
+        lg_lock, c_lock = transformer.prefill(PARAMS, prompt, CFG, T)
         padded = jnp.pad(prompt, ((0, 0), (0, 2)))          # bucket 8
-        lg_slot, _ = transformer.prefill_into_slot(
-            PARAMS, arena, padded, jnp.asarray(Tp, jnp.int32),
-            jnp.asarray(0, jnp.int32), CFG)
         pool = transformer.init_block_pool(CFG, 6, BS)
         pages = jnp.asarray([3], jnp.int32)     # one scrambled page:
         lg, pool = transformer.prefill_into_blocks(  # ctx 0, bucket 8
             PARAMS, pool, padded, jnp.asarray(Tp, jnp.int32), pages,
             CFG, block_size=BS)
-        np.testing.assert_allclose(np.asarray(lg_slot), np.asarray(lg),
+        np.testing.assert_allclose(np.asarray(lg_lock), np.asarray(lg),
                                    rtol=1e-5, atol=1e-6)
+        for leaf in ("k", "v"):
+            np.testing.assert_allclose(
+                np.moveaxis(np.asarray(c_lock[leaf])[:, 0, :Tp], 1, 2),
+                np.asarray(pool[leaf])[:, :, 3 * BS:3 * BS + Tp],
+                rtol=1e-5, atol=1e-6)
         k = np.asarray(pool["k"])
         for b in (0, 1, 2, 4, 5):                            # unmapped
             np.testing.assert_array_equal(
@@ -269,10 +282,32 @@ class TestPagedEngineScheduling:
             np.testing.assert_array_equal(r.output, want)
             assert r.finish_reason == "max_tokens"
 
+    def test_chunk_buckets_are_the_one_bucket_list(self, rng):
+        """``chunk_buckets`` is the engine's only bucket list: entries
+        beyond ``cache_len`` have no program and are dropped, a list
+        with none left is refused at construction, and ``/healthz``
+        reports what is left under ``prefill_buckets``."""
+        def make(buckets):
+            return PagedDecodeEngine.from_params(
+                PARAMS, CFG, batch=2, cache_len=32, block_size=8,
+                chunk_tokens=8, chunk_buckets=buckets, seed=0,
+                tracker=CompileTracker(), decode_flops=None)
+
+        eng = make((8, 4, 64))
+        assert eng.buckets == (4, 8)
+        assert eng.health()["prefill_buckets"] == [4, 8]
+        r = eng.submit(rng.randint(0, 40, 11).astype(np.int32),
+                       max_new=3)       # chunks of 8 and 3 -> bucket 4
+        eng.run_until_idle()
+        assert r.finish_reason == "max_tokens"
+        assert eng.compile_counts() == {"prefill": 2, "decode": 1}
+        with pytest.raises(ValueError, match="no chunk bucket fits"):
+            make((64,))
+
     def test_long_prompt_chunked_no_bucket_rejection(self, rng):
-        """A prompt far beyond chunk_tokens is admitted (the v3
-        largest-bucket rejection is gone) and decodes correctly through
-        chunked prefill."""
+        """A prompt far beyond chunk_tokens is admitted (no bucket
+        bounds a prompt) and decodes correctly through chunked
+        prefill."""
         eng = _paged(cache_len=32, chunk_tokens=8)
         p = rng.randint(0, 40, 26).astype(np.int32)
         r = eng.submit(p, max_new=6)         # 26 > chunk max 8

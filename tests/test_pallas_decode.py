@@ -1,7 +1,7 @@
 """Flash-decode Pallas kernel over the paged KV pool + fused sampling
 epilogue + the PADDLE_TPU_PALLAS dispatch policy + int8-weight serving.
 
-Contracts (ISSUE 10, mirroring how decode_step_slots was pinned):
+Contracts (ISSUE 10):
 - interpret-mode kernel bitwise-identical to the XLA paged path on
   aligned fp32 shapes, page-scramble invariance included;
 - tolerance-bounded under bf16;

@@ -433,21 +433,6 @@ class TestEngineLifecycle:
             "engine_ttft_window_seconds").value(q="p99") == 0.0
         assert eng.health().get("status") is None     # breach gone
 
-    def test_slot_engine_lifecycle_joined_too(self, rng):
-        eng = _slot_engine()
-        r = eng.submit(rng.randint(0, 40, 6).astype(np.int32), max_new=3)
-        eng.run_until_idle()
-        evs = _lifecycle_events(r.trace_id)
-        names = {e["name"] for e in evs}
-        assert {"request", "queued", "admitted", "prefill",
-                "prefill_chunk", "first_token", "finished"} <= names
-        assert sum(1 for e in evs if e["ph"] == "b") == \
-            sum(1 for e in evs if e["ph"] == "e")
-        rec = eng.request_log.records()[0]
-        assert rec["prefill_own_s"] > 0
-        # monolithic prefill: stall is measurement slack, not a phase
-        assert rec["prefill_stall_s"] < rec["ttft_s"]
-
 
 # -- the host's phases of an engine step (PR 24) ---------------------------
 
@@ -523,14 +508,9 @@ class TestEnginePhases:
         assert step_s["count"] == steps
         assert step_s["sum"] == pytest.approx(three, rel=0.1)
 
-    @pytest.mark.parametrize("engine", ["paged", "slot", "spec"])
+    @pytest.mark.parametrize("engine", ["paged", "spec"])
     def test_every_engine_steps_through_the_phases(self, engine, rng):
-        if engine == "paged":
-            eng = _paged_engine()
-        elif engine == "slot":
-            eng = _slot_engine()
-        else:
-            eng = _spec_engine()
+        eng = _paged_engine() if engine == "paged" else _spec_engine()
         for n in (5, 9):
             eng.submit(rng.randint(0, 40, n).astype(np.int32), max_new=4)
         eng.run_until_idle()
@@ -564,22 +544,6 @@ class TestEnginePhases:
         assert len(calls) >= 5
         assert eng.metrics.get("engine_slow_steps_total").value() == slow
         assert "engine_slow_steps_total" in eng.metrics_text()
-
-
-def _slot_engine():
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import transformer
-    from paddle_tpu.observe.compile_tracker import CompileTracker
-    from paddle_tpu.serving import DecodeEngine
-    cfg = transformer.TransformerConfig(
-        vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2,
-        d_ff=32, max_len=64, dtype=jnp.float32, use_rope=True)
-    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-    return DecodeEngine.from_params(params, cfg, batch=2, cache_len=64,
-                                    buckets=(8, 16), seed=0,
-                                    tracker=CompileTracker())
 
 
 def _spec_engine():
@@ -663,7 +627,7 @@ class TestRegressionSentinel:
         return mod
 
     def _write(self, path, speedup, tps, ttft, mtime):
-        doc = {"serving_paged_speedup": speedup,
+        doc = {"serving_int8_speedup": speedup,
                "throughput": {"engine_paged": {"tokens_per_sec": tps}},
                "latency": {"engine_paged": {"ttft_p99_s": ttft}}}
         with open(path, "w") as f:
@@ -688,7 +652,7 @@ class TestRegressionSentinel:
                     0.9, 235.0, 0.56, 3000)
         assert mod.main(["--dir", d]) == 1
         out = capsys.readouterr().out
-        assert "serving_paged_speedup: REGRESSED" in out
+        assert "serving_int8_speedup: REGRESSED" in out
         assert "SENTINEL: REGRESSED" in out
 
     def test_missing_figure_skips(self, tmp_path, capsys):

@@ -1,7 +1,7 @@
-"""Continuous-batching decode engine: per-slot positions must be
-bitwise-faithful to lockstep decode, admission/recycling must not
-perturb in-flight slots, sampling runs on device, and the whole engine
-compiles once per prefill bucket + once for decode."""
+"""Continuous-batching decode engine: admission/recycling must not
+perturb in-flight slots, whichever loop steps them (the paged engine's
+decode step or the spec engine's propose+verify round), sampling runs
+on device, and malformed submissions are refused."""
 
 import jax
 import jax.numpy as jnp
@@ -10,85 +10,32 @@ import pytest
 
 from paddle_tpu.models import transformer
 from paddle_tpu.observe.compile_tracker import CompileTracker
-from paddle_tpu.serving import DecodeEngine, sample_tokens
+from paddle_tpu.serving import (PagedDecodeEngine, SpecDecodeEngine,
+                                sample_tokens)
 
 CFG = transformer.TransformerConfig(
     vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=2, d_ff=32,
     max_len=64, dtype=jnp.float32, use_rope=True)
-CFG_ABS = transformer.TransformerConfig(
-    vocab=40, d_model=16, n_heads=2, n_layers=2, d_ff=32,
-    max_len=64, dtype=jnp.float32, use_rope=False)
 PARAMS = transformer.init_params(jax.random.PRNGKey(0), CFG)
 
 
-def _engine(batch=2, cache_len=32, buckets=(8, 16), seed=0,
-            params=PARAMS, cfg=CFG):
-    return DecodeEngine.from_params(
-        params, cfg, batch=batch, cache_len=cache_len, buckets=buckets,
-        seed=seed, tracker=CompileTracker())
+DRAFT_CFG = transformer.TransformerConfig(
+    vocab=40, d_model=16, n_heads=2, n_kv_heads=1, n_layers=1, d_ff=32,
+    max_len=64, dtype=jnp.float32, use_rope=True)
+DRAFT_PARAMS = transformer.init_params(jax.random.PRNGKey(7), DRAFT_CFG)
+
+# the two loops that step a slot: PagedDecodeEngine.step and
+# SpecDecodeEngine.step (its own copy, a propose+verify round)
+ENGINES = ("paged", "spec")
 
 
-class TestSlotDecodeKernels:
-    @pytest.mark.parametrize("cfg", [CFG, CFG_ABS],
-                             ids=["rope", "learned-pos"])
-    def test_vector_pos_decode_bitwise_matches_lockstep(self, cfg, rng):
-        """Aligned positions: decode_step_slots == decode_step bitwise
-        (logits AND cache), for both position encodings."""
-        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
-        B, Tp = 3, 6
-        prompt = jnp.asarray(rng.randint(0, 40, (B, Tp)), jnp.int32)
-        logits, cache = transformer.prefill(params, prompt, cfg, 20)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        l_lock, c_lock = transformer.decode_step(
-            params, cache, tok, jnp.asarray(Tp, jnp.int32), cfg)
-        l_slot, c_slot = transformer.decode_step_slots(
-            params, cache, tok, jnp.full((B,), Tp, jnp.int32),
-            jnp.ones((B,), bool), cfg)
-        np.testing.assert_array_equal(np.asarray(l_lock),
-                                      np.asarray(l_slot))
-        for leaf in ("k", "v"):
-            np.testing.assert_array_equal(np.asarray(c_lock[leaf]),
-                                          np.asarray(c_slot[leaf]))
-
-    def test_inactive_slots_not_written(self, rng):
-        """active=False rows keep their cache bitwise intact and rows
-        never cross-write (each row targets its own position)."""
-        B, Tp = 3, 6
-        prompt = jnp.asarray(rng.randint(0, 40, (B, Tp)), jnp.int32)
-        _, cache = transformer.prefill(PARAMS, prompt, CFG, 20)
-        tok = jnp.zeros((B,), jnp.int32)
-        active = jnp.asarray([True, False, True])
-        _, c2 = transformer.decode_step_slots(
-            PARAMS, cache, tok, jnp.asarray([6, 3, 9], jnp.int32),
-            active, CFG)
-        # row 1 untouched everywhere
-        np.testing.assert_array_equal(np.asarray(cache["k"][:, 1]),
-                                      np.asarray(c2["k"][:, 1]))
-        # row 0 wrote position 6 only; row 2 wrote position 9 only
-        k0, k2 = np.asarray(c2["k"][:, 0]), np.asarray(c2["k"][:, 2])
-        k0_ref = np.asarray(cache["k"][:, 0])
-        assert not np.array_equal(k0[:, 6], k0_ref[:, 6])
-        np.testing.assert_array_equal(k0[:, 7:], k0_ref[:, 7:])
-        np.testing.assert_array_equal(k2[:, 6:9],
-                                      np.asarray(cache["k"][:, 2, 6:9]))
-        assert not np.array_equal(k2[:, 9],
-                                  np.asarray(cache["k"][:, 2, 9]))
-
-    def test_prefill_into_slot_matches_batched_prefill(self, rng):
-        """Right-padded slot prefill reproduces the unpadded lockstep
-        prefill logits bitwise and leaves other arena rows zero."""
-        B, Tp, cache_len = 3, 6, 24
-        prompt = jnp.asarray(rng.randint(0, 40, (B, Tp)), jnp.int32)
-        logits, _ = transformer.prefill(PARAMS, prompt, CFG, cache_len)
-        arena = transformer.init_cache(CFG, B, cache_len)
-        padded = jnp.pad(prompt[1:2], ((0, 0), (0, 2)))   # bucket 8
-        lg, arena = transformer.prefill_into_slot(
-            PARAMS, arena, padded, jnp.asarray(Tp, jnp.int32),
-            jnp.asarray(1, jnp.int32), CFG)
-        np.testing.assert_array_equal(np.asarray(lg[0]),
-                                      np.asarray(logits[1]))
-        np.testing.assert_array_equal(np.asarray(arena["k"][:, 0]), 0.0)
-        np.testing.assert_array_equal(np.asarray(arena["k"][:, 2]), 0.0)
+def _engine(kind="paged", batch=2, cache_len=32, seed=0):
+    kw = dict(batch=batch, cache_len=cache_len, block_size=8,
+              chunk_tokens=8, seed=seed, tracker=CompileTracker())
+    if kind == "spec":
+        return SpecDecodeEngine.from_params(
+            PARAMS, CFG, DRAFT_PARAMS, DRAFT_CFG, spec_k=3, **kw)
+    return PagedDecodeEngine.from_params(PARAMS, CFG, **kw)
 
 
 class TestOnDeviceSampling:
@@ -118,36 +65,23 @@ class TestOnDeviceSampling:
         assert out[0] == np.asarray(logits[0]).argmax()
 
 
+@pytest.mark.parametrize("kind", ENGINES)
 class TestEngineScheduling:
-    def test_engine_matches_lockstep_generate(self, rng):
-        """Greedy engine output == transformer.generate per request,
-        with mixed prompt lengths sharing the arena."""
-        eng = _engine()
-        prompts = [rng.randint(0, 40, n).astype(np.int32)
-                   for n in (5, 9, 3)]
-        reqs = [eng.submit(p, max_new=6) for p in prompts]
-        done = eng.run_until_idle()
-        assert len(done) == 3
-        for r, p in zip(reqs, prompts):
-            want = np.asarray(transformer.generate(
-                PARAMS, jnp.asarray(p[None]), CFG, max_new=6))[0]
-            np.testing.assert_array_equal(r.output, want)
-            assert r.finish_reason == "max_tokens"
-
-    def test_mid_flight_admission_does_not_perturb(self, rng):
+    def test_mid_flight_admission_does_not_perturb(self, kind, rng):
         """The continuous-batching invariant: a request admitted into a
         free slot changes NOTHING for its in-flight neighbour."""
         pa = rng.randint(0, 40, 5).astype(np.int32)
         pb = rng.randint(0, 40, 9).astype(np.int32)
-        solo = _engine()
+        solo = _engine(kind)
         ra_solo = solo.submit(pa, max_new=8)
         solo.run_until_idle()
 
-        eng = _engine()
+        eng = _engine(kind)
         ra = eng.submit(pa, max_new=8)
-        for _ in range(3):
-            eng.step()              # A mid-flight with 4 tokens
-        assert len(ra.tokens) == 4
+        for _ in range(2):
+            eng.step()              # A mid-flight: first token, then a
+        #                             decode step or a verify round
+        assert 2 <= len(ra.tokens) < 8
         rb = eng.submit(pb, max_new=6)   # joins slot 1 mid-flight
         eng.run_until_idle()
         np.testing.assert_array_equal(ra.output, ra_solo.output)
@@ -155,12 +89,12 @@ class TestEngineScheduling:
             PARAMS, jnp.asarray(pb[None]), CFG, max_new=6))[0]
         np.testing.assert_array_equal(rb.output, want_b)
 
-    def test_eos_recycles_slot_for_queued_request(self, rng):
-        """EOS termination frees the slot; the queued request fills it
-        and decodes correctly in the recycled row."""
+    def test_eos_recycles_slot_for_queued_request(self, kind, rng):
+        """EOS termination frees the slot and its blocks; the queued
+        request fills it and decodes correctly in the recycled row."""
         pa = rng.randint(0, 40, 5).astype(np.int32)
         pc = rng.randint(0, 40, 7).astype(np.int32)
-        probe = _engine(batch=1)
+        probe = _engine(kind, batch=1)
         ra = probe.submit(pa, max_new=8)
         probe.run_until_idle()
         # pick an eos that first appears mid-stream (greedy stream is
@@ -169,12 +103,12 @@ class TestEngineScheduling:
                    if ra.tokens[i] not in ra.tokens[:i])
         eos = ra.tokens[idx]
 
-        eng = _engine(batch=1)      # one slot: C must wait for A's EOS
+        eng = _engine(kind, batch=1)   # one slot: C waits for A's EOS
         ra2 = eng.submit(pa, max_new=8, eos_id=eos)
         rc = eng.submit(pc, max_new=4)
         assert eng.queue_depth == 2          # admission happens in step()
         eng.step()
-        assert rc.status == "queued"         # arena full until A's EOS
+        assert rc.status == "queued"         # no slot until A's EOS
         eng.run_until_idle()
         assert ra2.finish_reason == "eos"
         assert ra2.tokens == ra.tokens[:idx + 1]  # stops AT the eos
@@ -182,32 +116,36 @@ class TestEngineScheduling:
         want_c = np.asarray(transformer.generate(
             PARAMS, jnp.asarray(pc[None]), CFG, max_new=4))[0]
         np.testing.assert_array_equal(rc.output, want_c)
+        assert eng.pool.idle
 
-    def test_compile_once_per_bucket_plus_decode(self, rng):
-        """The static-shape contract: N distinct prompt buckets compile
-        N prefills; every decode step shares ONE compilation."""
-        eng = _engine(batch=2, buckets=(8, 16, 32))
-        for n in (3, 5, 12, 7, 15, 2):      # buckets 8 and 16 only
-            eng.submit(rng.randint(0, 40, n).astype(np.int32),
-                       max_new=4)
-        eng.run_until_idle()
-        assert eng.compile_counts() == {"prefill": 2, "decode": 1}
-
-    def test_submit_guards(self, rng):
-        eng = _engine(cache_len=16, buckets=(8,))
+    def test_submit_guards(self, kind, rng):
+        eng = _engine(kind, cache_len=16)
         with pytest.raises(ValueError, match="exceed cache_len"):
             eng.submit(rng.randint(0, 40, 8), max_new=16)
-        with pytest.raises(ValueError, match="largest prefill bucket"):
-            eng.submit(rng.randint(0, 40, 12), max_new=2)
         with pytest.raises(ValueError, match="max_new"):
             eng.submit(rng.randint(0, 40, 4), max_new=0)
+        with pytest.raises(ValueError, match="empty prompt"):
+            eng.submit(np.zeros(0, np.int32), max_new=2)
+        with pytest.raises(ValueError, match="tier"):
+            eng.submit(rng.randint(0, 40, 4), max_new=2, tier="gold")
+        eng.set_tenant_budget("acme", 8)
+        with pytest.raises(ValueError, match="budget"):
+            eng.submit(rng.randint(0, 40, 6), max_new=4, tenant="acme")
+        # counted, never queued; a prompt longer than one chunk is no
+        # refusal (chunked prefill)
+        assert eng.queue_depth == 0
+        assert eng.metrics.get("engine_requests_rejected_total").value(
+            reason="exceeds_cache") == 1
+        r = eng.submit(rng.randint(0, 40, 12), max_new=2)
+        eng.run_until_idle()
+        assert r.finish_reason == "max_tokens"
 
-    def test_unseeded_engines_differ(self, rng):
+    def test_unseeded_engines_differ(self, kind, rng):
         """seed=None engines must not replay one sampling stream."""
         prompt = rng.randint(0, 40, 5).astype(np.int32)
         outs = []
         for _ in range(2):
-            eng = _engine(seed=None)
+            eng = _engine(kind, seed=None)
             r = eng.submit(prompt, max_new=12, temperature=100.0)
             eng.run_until_idle()
             outs.append(list(r.tokens))
@@ -247,11 +185,10 @@ class TestEngineObservability:
 
 class TestServingBenchSmoke:
     def test_bench_smoke_engine_beats_nothing_but_runs(self):
-        """Tier-1 exercise of the full bench path (--smoke): all three
-        variants (paged / row-arena / lockstep) produce sane numbers on
-        a shared-prefix + long-prompt-adversarial trace and the compile
-        invariants (asserted inside the runners) hold. The paged-wins
-        throughput/TTFT claims are the full-size run's, not the toy's."""
+        """Tier-1 exercise of the full bench path (--smoke): both
+        variants (engine / lockstep) produce sane numbers on a
+        shared-prefix + long-prompt-adversarial trace and the compile
+        invariants (asserted inside the runners) hold."""
         import importlib.util
         import os
         spec = importlib.util.spec_from_file_location(
@@ -275,17 +212,13 @@ class TestServingBenchSmoke:
         assert lat["engine_paged"]["requests"] == 7
         for phase in (tp, lat):
             assert phase["engine_paged"]["tokens"] == \
-                phase["engine_slots"]["tokens"] == \
                 phase["lockstep"]["tokens"]
             assert phase["engine_paged"]["tokens_per_sec"] > 0
             assert phase["engine_paged"]["compiles"]["decode"] == 1
-            assert phase["engine_slots"]["compiles"]["decode"] == 1
             # the shared-prefix half of the trace hit the prefix cache
             assert phase["engine_paged"]["prefix_hit_blocks"] >= 1
             assert phase["engine_paged"]["blocks_in_use_peak"] <= \
                 phase["engine_paged"]["blocks_total"]
-        assert results["serving_paged_speedup"] > 0
-        assert results["serving_paged_ttft_p99_ratio"] > 0
         # flash-decode-era fields: decode MFU reported per engine, the
         # int8 variant rode the throughput phase token-for-token, and
         # the interpret-mode kernel matched the XLA engine's ids

@@ -377,7 +377,7 @@ class TestSpecArtifactV5:
         path = str(tmp_path / "m.tar")
         lm_serving.save_lm_artifact(
             path, PARAMS, CFG, batch=3, prompt_len=8, cache_len=32,
-            engine_buckets=(8,), engine_paged=True, engine_block_size=BS,
+            engine_buckets=(8,), engine_block_size=BS,
             engine_draft_params=DRAFT_PARAMS,
             engine_draft_config=DRAFT_CFG, engine_spec_k=3)
         srv = lm_serving.load_lm_artifact(path)
@@ -399,9 +399,11 @@ class TestSpecArtifactV5:
 
         from paddle_tpu.io import lm_serving
         with tempfile.TemporaryDirectory() as d:
-            with pytest.raises(ValueError, match="engine_paged"):
+            with pytest.raises(
+                    ValueError,
+                    match="engine_draft_params needs engine_buckets"):
                 lm_serving.save_lm_artifact(
                     f"{d}/m.tar", PARAMS, CFG, batch=2, prompt_len=8,
-                    cache_len=32, engine_buckets=(8,),
+                    cache_len=32,
                     engine_draft_params=DRAFT_PARAMS,
                     engine_draft_config=DRAFT_CFG)
