@@ -522,6 +522,7 @@ def phase_export(lm, args):
     cfg = lm_config(lm)
     params = transformer.init_params(jax.random.PRNGKey(SEED), cfg)
     block = lm["block_size"] or DEFAULT_BLOCK_SIZE
+    check_cast_once(params, cfg, lm["slots"], block)
     for name, mode in (("lm_kernel.tar",
                         "interpret" if args.rehearsal else None),
                        ("lm_off.tar", "off")):
@@ -538,6 +539,35 @@ def phase_export(lm, args):
               f"{lm['slots']} slots, cache {lm['cache_len']}) in "
               f"{time.time() - t0:.1f}s")
     return 0
+
+
+def check_cast_once(params, cfg, slots: int, block: int):
+    """On THIS device: a paged decode step over the tree an artifact
+    stores (block matrices cast to the compute dtype once) gives the
+    logits, bit for bit, of the step over the float32 tree, which casts
+    them itself. Called in a child, after ``start_child``."""
+    import jax
+    import numpy as np
+    from paddle_tpu.models import transformer
+    cast = transformer.compute_dtype_params(params, cfg)
+    if cast["blocks"]["qkv"].dtype != cfg.dtype:
+        fail("compute_dtype_params left qkv in "
+             f"{cast['blocks']['qkv'].dtype}")
+    rng = np.random.RandomState(SEED)
+    pool = transformer.init_block_pool(cfg, 2 * slots, block)
+    pool = {n: jax.numpy.asarray(rng.standard_normal(t.shape), t.dtype)
+            for n, t in pool.items()}
+    pages = np.arange(2 * slots, dtype=np.int32).reshape(slots, 2)
+    tok = rng.randint(0, cfg.vocab, slots).astype(np.int32)
+    pos = rng.randint(0, 2 * block, slots).astype(np.int32)
+    step = jax.jit(lambda p: transformer.decode_step_paged(
+        p, pool, tok, pos, np.ones(slots, bool), pages, cfg,
+        block_size=block, pallas="off")[0])
+    a, b = np.asarray(step(params)), np.asarray(step(cast))
+    if not np.array_equal(a, b):
+        fail(f"decode logits over the cast-once tree differ from the "
+             f"float32 tree's by up to {np.abs(a - b).max()}")
+    print(f"cast once == cast every step: {a.shape} logits bit-equal")
 
 
 def train_resnet(lm, args, mesh_chips: int = 0):

@@ -13,6 +13,7 @@ or temperature sampling happens host-side between compiled calls.
 """
 
 import dataclasses
+import functools
 import io as _io
 import json
 import os
@@ -134,6 +135,16 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     what each module placed is stamped in ``meta.engine_kernel_paths``.
     Exporting compiled kernels from a host without the chip needs the
     target named: ``ops.pallas.policy.compile_target(device_kind)``.
+    DTYPES: the four stacked block matrices (``qkv``, ``attn_out``,
+    ``mlp_in``, ``mlp_out``) are stored, and taken by every exported
+    program (lockstep pair, paged prefill and decode, the speculative
+    members and their draft tree), in ``cfg.dtype``
+    (``transformer.compute_dtype_params``; a bf16 leaf is a
+    ``<path>@bfloat16`` member of the ``.npz``), every other leaf as
+    handed in: a decode step reads each weight byte once instead of
+    casting float32 leaves every step. The engine of
+    ``LMServer.engine()`` conforms whatever ``params`` it is handed to
+    the programs' input dtypes, once.
     ``weights_int8`` stores the big matmul weights as per-output-channel
     int8 (see quantize_lm_params) — the exported modules dequantize
     inline, so the loader and LMServer are unchanged.
@@ -208,6 +219,8 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
             if on:
                 transformer.require_gpt2(cfg, what)
     if weights_int8:
+        # from the leaves as handed in: int8 of a float32 value, not of
+        # its bfloat16 rounding (the helper below passes q8 nodes on)
         params = quantize_lm_params(params)
 
         def _p(p):
@@ -215,6 +228,13 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
     else:
         def _p(p):
             return p
+    # the block matrices in the compute dtype BEFORE the programs are
+    # typed and the leaves written: every exported program takes them
+    # as it multiplies by them, and the artifact stores them so
+    params = transformer.compute_dtype_params(params, cfg)
+    if engine_draft_params is not None:
+        engine_draft_params = transformer.compute_dtype_params(
+            engine_draft_params, engine_draft_config)
 
     def prefill_fn(p, tokens):
         return transformer.prefill(_p(p), tokens, cfg, cache_len)
@@ -427,7 +447,7 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
             "k": int(engine_spec_k),
             "draft_config": _cfg_to_dict(engine_draft_config)}
         dbuf = _io.BytesIO()
-        np.savez(dbuf, **_flatten(engine_draft_params))
+        np.savez(dbuf, **_npz_leaves(_flatten(engine_draft_params)))
         draft_blob = dbuf.getvalue()
     with tarfile.open(path, "w") as tar:
         _add(tar, "meta.json", json.dumps(meta).encode())
@@ -535,26 +555,11 @@ class LMServer:
         return HealthServer(registry=self.metrics, health_fn=self.health,
                             host=host, port=port)
 
-    def _engine_program(self, member: str, donate_pool: bool = False):
-        """One of the engine's exported modules, deserialised.
-        ``donate_pool`` donates argument 1 (the KV pool) at the call,
-        so the program's pool writes land in the caller's buffer: the
-        paged decode and prefill programs, whose caller rebinds the
-        pool from every result."""
-        import jax
+    def _engine_exported(self, member: str):
+        """One of the engine's exported modules, deserialised."""
         import jax.export
         with _trace.trace_scope("artifact/programs"):
-            exported = jax.export.deserialize(self._engine_bins[member])
-        if not donate_pool:
-            return exported.call
-
-        # the name is the traced module's: a bare ``exported.call``
-        # runs as ``jit_call_exported`` and the benchmark's readers
-        # find the decode program by that name
-        def call_exported(*args):
-            return exported.call(*args)
-
-        return jax.jit(call_exported, donate_argnums=(1,))
+            return jax.export.deserialize(self._engine_bins[member])
 
     def engine(self, *, seed: Optional[int] = None, registry=None,
                tracker=None, chunk_tokens: Optional[int] = None,
@@ -564,7 +569,12 @@ class LMServer:
         prefill + prefix cache; the chunk grid is the artifact's —
         ``chunk_tokens`` may only restate it, the prefill modules are
         span-specialized), a ``serving.SpecDecodeEngine`` when the
-        artifact stamps a draft (v5). Raises on v1/v2 artifacts and on
+        artifact stamps a draft (v5). The engine conforms
+        ``self.params`` ONCE to what the programs take (:func:`_conform`
+        as its ``conform``: float32 training weights set on the server
+        become the bf16 matrices of the exported signature) and
+        ``self.params`` is rebound to the result, the draft's likewise.
+        Raises on v1/v2 artifacts and on
         a v3 one (the row-arena engine's modules, which nothing runs
         any more) — re-export with ``engine_buckets=`` to serve
         continuously; ``generate()`` stays the lockstep fallback."""
@@ -616,10 +626,10 @@ class LMServer:
                 continue
             b, pv = name[len("engine_prefill_paged_"):
                          -len(".bin")].split("_")
-            prefills[(int(b), int(pv))] = self._engine_program(
-                name, donate_pool=True)
-        decode = self._engine_program("engine_decode_paged.bin",
-                                      donate_pool=True)
+            prefills[(int(b), int(pv))] = _program(
+                self._engine_exported(name), donate_pool=True)
+        decode_exported = self._engine_exported("engine_decode_paged.bin")
+        decode = _program(decode_exported, donate_pool=True)
 
         def prefill(params, pool, tokens, length, pagevec, *rest):
             key = (tokens.shape[1], pagevec.shape[0])
@@ -649,7 +659,11 @@ class LMServer:
                 "engine_decode", {}).get("flops"),
             pallas_mode=self.meta.get("engine_pallas"),
             kernel_paths=self.meta.get("engine_kernel_paths"),
-            kv_dtype=kvd, tiers=tiers)
+            kv_dtype=kvd, tiers=tiers,
+            # whatever the engine is handed (training weights, the
+            # seed's float32 tree of a benchmark) is cast ONCE to what
+            # the programs take, and not by every decode step
+            conform=functools.partial(_conform, exported=decode_exported))
         spec = self.meta.get("engine_spec")
         if spec:
             # v5: schedule the SpecDecodeEngine over the stamped
@@ -664,8 +678,8 @@ class LMServer:
                     continue
                 b, pv = name[len("engine_draft_prefill_"):
                              -len(".bin")].split("_")
-                dprefills[(int(b), int(pv))] = \
-                    self._engine_program(name)
+                dprefills[(int(b), int(pv))] = _program(
+                    self._engine_exported(name))
 
             def draft_prefill(dp, dpool, tokens, length, pagevec):
                 key = (tokens.shape[1], pagevec.shape[0])
@@ -675,18 +689,26 @@ class LMServer:
             eng_kw["decode_flops"] = self.cost_analysis.get(
                 "engine_verify", {}).get(
                 "flops", eng_kw["decode_flops"])
-            return SpecDecodeEngine(
+            propose_exported = self._engine_exported("engine_propose.bin")
+            self.draft_params = _conform(self.draft_params,
+                                         propose_exported)
+            eng = SpecDecodeEngine(
                 prefill, decode, self.params, pool,
                 draft_params=self.draft_params,
                 draft_cache=draft_pool,
                 draft_prefill=draft_prefill,
-                propose=self._engine_program("engine_propose.bin"),
-                verify=self._engine_program("engine_verify.bin"),
-                draft_verify=self._engine_program(
-                    "engine_draft_verify.bin"),
+                propose=_program(propose_exported),
+                verify=_program(
+                    self._engine_exported("engine_verify.bin")),
+                draft_verify=_program(
+                    self._engine_exported("engine_draft_verify.bin")),
                 spec_k=spec["k"], **eng_kw)
-        return PagedDecodeEngine(
-            prefill, decode, self.params, pool, **eng_kw)
+        else:
+            eng = PagedDecodeEngine(
+                prefill, decode, self.params, pool, **eng_kw)
+        # no float32 copy of the matrices outlives the call
+        self.params = eng.params
+        return eng
 
     def generate(self, prompt: np.ndarray, max_new: int,
                  temperature: float = 0.0,
@@ -769,6 +791,54 @@ class LMServer:
                     self._m_mfu.set(mfu)
         return np.concatenate([prompt,
                                np.stack(toks, axis=1)], axis=1)
+
+
+def _program(exported, donate_pool: bool = False):
+    """The call of one of the engine's exported modules.
+    ``donate_pool`` donates argument 1 (the KV pool) at the call, so
+    the program's pool writes land in the caller's buffer: the paged
+    decode and prefill programs, whose caller rebinds the pool from
+    every result."""
+    import jax
+    if not donate_pool:
+        return exported.call
+
+    # the name is the traced module's: a bare ``exported.call``
+    # runs as ``jit_call_exported`` and the benchmark's readers
+    # find the decode program by that name
+    def call_exported(*args):
+        return exported.call(*args)
+
+    return jax.jit(call_exported, donate_argnums=(1,))
+
+
+def _conform(params, exported):
+    """``params`` as ``exported`` (a step program, the tree its first
+    argument) takes them: every leaf a device array in the dtype of
+    the program's own input aval. Leaves in another dtype are cast in
+    ONE jitted call (seconds in ``artifact/conform``), a host leaf in
+    the right dtype is put on the device, a device array in the right
+    dtype is the one handed in (``is``): for a float32 config, an int8
+    tree, leaves that come from the artifact itself or programs
+    exported from float32 leaves there is nothing to cast. A tree of
+    another structure than the program's raises."""
+    import jax
+    (want, *_), _ = jax.tree_util.tree_unflatten(exported.in_tree,
+                                                 exported.in_avals)
+    with _trace.trace_scope("artifact/conform"):
+        avals, treedef = jax.tree_util.tree_flatten(want)
+        leaves = treedef.flatten_up_to(params)
+        off = [i for i, (x, a) in enumerate(zip(leaves, avals))
+               if x.dtype != a.dtype]
+        if off:
+            dtypes = [avals[i].dtype for i in off]
+            cast = jax.jit(lambda xs: [x.astype(d)
+                                       for x, d in zip(xs, dtypes)])
+            for i, x in zip(off, cast([leaves[i] for i in off])):
+                leaves[i] = x
+        leaves = [x if isinstance(x, jax.Array) else jax.device_put(x)
+                  for x in leaves]
+        return jax.block_until_ready(treedef.unflatten(leaves))
 
 
 _BF16 = "@bfloat16"     # numpy's .npy format has no bfloat16: such a

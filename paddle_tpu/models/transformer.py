@@ -227,6 +227,38 @@ def require_gpt2(cfg, what: str):
         gated_hybrid.refuse(what)
 
 
+# the stacked matrices the GPT-2 block consumes ONLY as
+# ``w[name].astype(<activation dtype>)`` (every block body below);
+# ``moe_w_in`` / ``moe_w_out`` are not among them: ``moe.moe_ffn``
+# multiplies by them in float32
+COMPUTE_DTYPE_LEAVES = ("qkv", "attn_out", "mlp_in", "mlp_out")
+
+
+def compute_dtype_params(params, cfg: TransformerConfig):
+    """``params`` with the block matrices (``COMPUTE_DTYPE_LEAVES``) in
+    ``cfg.dtype``: what a SERVING program is handed, so that the cast
+    the block makes of them (``w["qkv"].astype(h.dtype)`` ...) is a
+    no-op and a decode step reads each weight byte once, where float32
+    leaves are read at 4 bytes, written at 2 and read again, every
+    step. The value is the one the block cast every time. Every other
+    leaf (embedding, positions, LayerNorm, a router's gate, an expert
+    stack, ``{"q8", "scale"}`` nodes of an int8 tree) comes back as it
+    is, and so does the whole tree (``is``) when nothing is to cast: a
+    float32 config, leaves already in the compute dtype, the
+    ``gated_hybrid`` skeleton (bf16 leaves since it exists). The
+    trainer keeps float32 master weights and runs the same block."""
+    if cfg.skeleton != "gpt2":
+        return params
+    dtype = jnp.dtype(cfg.dtype)
+    blocks = params["blocks"]
+    cast = {n: blocks[n].astype(dtype) for n in COMPUTE_DTYPE_LEAVES
+            if n in blocks and not isinstance(blocks[n], dict)
+            and blocks[n].dtype != dtype}
+    if not cast:
+        return params
+    return {**params, "blocks": {**blocks, **cast}}
+
+
 def _layer_norm(x, g, b):
     return ops_norm.layer_norm(x, g, b).astype(x.dtype)
 

@@ -48,6 +48,7 @@ verify bitwise the decode step).
 """
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -240,6 +241,11 @@ def _decode_step_flops(decode_fn, params, pool, batch, pages):
     return (cost or {}).get("flops")
 
 
+def _tree_nbytes(tree) -> int:
+    import jax
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
 def default_chunk_buckets(chunk_tokens: int) -> tuple:
     """Power-of-two chunk buckets up to ``chunk_tokens`` (which is
     always included): a prompt's tail chunk pads to the smallest
@@ -304,7 +310,8 @@ class PagedDecodeEngine:
                  kernel_paths: Optional[Dict[str, Dict[str, str]]] = None,
                  kv_dtype: Optional[str] = None,
                  tenant_budgets: Optional[Dict[str, int]] = None,
-                 tiers=None):
+                 tiers=None,
+                 conform: Optional[Callable] = None):
         bs = int(block_size)
         if bs < 1 or cache_len % bs:
             raise ValueError(f"cache_len {cache_len} must be a positive "
@@ -342,7 +349,6 @@ class PagedDecodeEngine:
         self._jnp = jnp
         self._prefill_fn = prefill
         self._decode_fn = decode
-        self.params = params
         self.cache = cache
         self.batch = int(batch)
         self.cache_len = int(cache_len)
@@ -564,6 +570,12 @@ class PagedDecodeEngine:
                   "per-slot recurrent rows beside the pool (0 for a "
                   "model without recurrent layers)"
                   ).set(self.recurrent_state_bytes)
+        self._m_weight_bytes = reg.gauge(
+            "engine_weight_bytes", "bytes of the parameter tree(s) the "
+            "engine hands its step programs (what a decode step streams "
+            "is at most this)")
+        self._conform = conform
+        self.params = params
         self._m_kv_exported = reg.counter(
             "engine_kv_blocks_exported_total", "prefix-cache blocks "
             "serialized out over the P/D transfer wire "
@@ -593,6 +605,28 @@ class PagedDecodeEngine:
                           else _tiers.TieredStore(registry=reg,
                                                   **dict(tiers)))
             self.pool.on_evict = self._demote_block
+
+    draft_params = None     # a SpecDecodeEngine's second tree
+
+    @property
+    def params(self):
+        """The parameter tree handed to every step program. Whatever is
+        assigned (at construction or later: a benchmark's next seed,
+        training weights) passes ONCE through ``conform``, which
+        brings it to the form the programs take — for an artifact's
+        engine the dtypes of the decode program's own inputs
+        (``io/lm_serving._conform``), in-process
+        ``transformer.compute_dtype_params`` — so that no step casts a
+        float32 matrix; ``None`` (a caller freeing the weights) is
+        kept."""
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        if tree is not None and self._conform is not None:
+            tree = self._conform(tree)
+        self._params = tree
+        self._m_weight_bytes.set(_tree_nbytes((tree, self.draft_params)))
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -628,6 +662,12 @@ class PagedDecodeEngine:
                              f"multiple of block_size {block_size}")
         nb = int(num_blocks if num_blocks is not None
                  else batch * (cache_len // block_size))
+        # as an artifact stores them: the block matrices cast once,
+        # not by every step (here already: the flops below are those
+        # of the program the engine runs)
+        conform = functools.partial(transformer.compute_dtype_params,
+                                    cfg=cfg)
+        params = conform(params)
         prefill_fn, decode_fn = sampling.paged_step_fns(
             cfg, block_size, pallas=pallas)
         pool = transformer.init_block_pool(cfg, nb, block_size,
@@ -646,7 +686,8 @@ class PagedDecodeEngine:
                    chunk_tokens=chunk_tokens, chunk_buckets=chunk_buckets,
                    seed=seed, kv_dtype=kv_dtype,
                    pallas_mode=_pallas_policy.pallas_mode(pallas),
-                   kernel_paths=decode_fn.kernel_paths, **kw)
+                   kernel_paths=decode_fn.kernel_paths, conform=conform,
+                   **kw)
 
     # -- request-scoped observability --------------------------------------
     def configure_slo(self, slo: Optional[SloConfig]):
@@ -2193,6 +2234,7 @@ class SpecDecodeEngine(PagedDecodeEngine):
         if self.spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         self.draft_params = draft_params
+        self._m_weight_bytes.set(_tree_nbytes((self.params, draft_params)))
         self.draft_cache = draft_cache
         self._draft_prefill_fn = draft_prefill
         self._propose_fn = propose
@@ -2242,6 +2284,11 @@ class SpecDecodeEngine(PagedDecodeEngine):
                 f"{cfg.max_len}, draft {draft_cfg.max_len})")
         nb = int(num_blocks if num_blocks is not None
                  else batch * (cache_len // block_size))
+        conform = functools.partial(transformer.compute_dtype_params,
+                                    cfg=cfg)
+        params = conform(params)
+        draft_params = transformer.compute_dtype_params(draft_params,
+                                                        draft_cfg)
         prefill_fn, decode_fn = sampling.paged_step_fns(
             cfg, block_size, pallas=pallas)
         spec = sampling.paged_spec_fns(cfg, draft_cfg, block_size,
@@ -2276,7 +2323,8 @@ class SpecDecodeEngine(PagedDecodeEngine):
                    chunk_buckets=chunk_buckets, seed=seed,
                    kv_dtype=kv_dtype,
                    pallas_mode=_pallas_policy.pallas_mode(pallas),
-                   kernel_paths=decode_fn.kernel_paths, **kw)
+                   kernel_paths=decode_fn.kernel_paths, conform=conform,
+                   **kw)
 
     # -- scheduler ---------------------------------------------------------
     def _draft_chunk_hook(self, slot: int, padded, c: int, npages: int):

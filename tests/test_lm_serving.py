@@ -4,6 +4,7 @@ in-code generate() exactly (greedy) with zero model code at load time."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from paddle_tpu.io import lm_serving
 from paddle_tpu.models import transformer
@@ -538,3 +539,223 @@ def test_engine_pallas_resolves_from_the_export_target(tmp_path,
     # a mixed target list cannot carry Mosaic kernels: XLA path, stamped
     assert policy.pallas_mode(None, platform="mixed") == "off"
     assert policy.pallas_mode(None, platform="tpu") == "on"
+
+
+# -- the block matrices in the compute dtype (PR 29) -------------------------
+# A serving program is handed ``qkv`` / ``attn_out`` / ``mlp_in`` /
+# ``mlp_out`` in ``cfg.dtype``: cast once at export and once in
+# ``LMServer.engine()``, not by every decode step and prefill chunk.
+
+CFG_BF16 = transformer.TransformerConfig(
+    vocab=40, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=64,
+    max_len=32, dtype=jnp.bfloat16, use_rope=False)
+MATRICES = transformer.COMPUTE_DTYPE_LEAVES
+LEAVES = ("embed", "pos", "ln_f", "ln_f_b") + tuple(
+    "blocks/" + n for n in ("ln1", "ln1_b", "ln2", "ln2_b") + MATRICES)
+
+
+def _leaf(tree, path):
+    for p in path.split("/"):
+        tree = tree[p]
+    return tree
+
+
+def _export_paged(path, params, cfg, **kw):
+    lm_serving.save_lm_artifact(
+        path, params, cfg, batch=2, prompt_len=6, cache_len=32,
+        engine_buckets=(8, 16), engine_block_size=8, **kw)
+
+
+def _serve(eng, rng_seed=7, max_new=6):
+    rng = np.random.RandomState(rng_seed)
+    reqs = [eng.submit(rng.randint(0, 40, n).astype(np.int32),
+                       max_new=max_new) for n in (5, 9, 20)]
+    eng.run_until_idle()
+    return [list(r.tokens) for r in reqs]
+
+
+@pytest.fixture(scope="module")
+def bf16_artifact(tmp_path_factory):
+    """(float32 leaves, path of the artifact exported from them under
+    the bf16 config): exported once for the cases below."""
+    params = transformer.init_params(jax.random.PRNGKey(3), CFG_BF16)
+    path = str(tmp_path_factory.mktemp("bf16") / "lm.tar")
+    _export_paged(path, params, CFG_BF16)
+    return params, path
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_artifact_stores_matrices_in_compute_dtype(bf16_artifact, leaf):
+    """Saved from float32 leaves under a bf16 config: exactly the four
+    block matrices are stored as bf16, bit-equal to ``astype(bf16)``
+    of the leaf handed in; every other leaf is the float32 one."""
+    params, path = bf16_artifact
+    got = _leaf(lm_serving.load_lm_artifact(path).params, leaf)
+    want = _leaf(params, leaf)
+    assert want.dtype == jnp.float32
+    if leaf.split("/")[-1] in MATRICES:
+        want = want.astype(jnp.bfloat16)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_engine_conforms_handed_in_float32_weights(bf16_artifact):
+    """The benchmark's spelling: load, ``srv.params = <float32 tree>``,
+    ``srv.engine()``. The tree is cast once, to the dtypes of the
+    decode program's own inputs; the served greedy ids are those of
+    the engine over the artifact's own leaves; no float32 matrix stays
+    referenced by the server; the cast's seconds and the bytes the
+    engine hands its programs are on record."""
+    from paddle_tpu.utils.stat import global_stats
+    params, path = bf16_artifact
+    own = lm_serving.load_lm_artifact(path)
+    eng_own = own.engine(seed=0)
+    srv = lm_serving.load_lm_artifact(path)
+    srv.params = params
+    calls = global_stats.get("artifact/conform").count
+    eng = srv.engine(seed=0)
+    assert global_stats.get("artifact/conform").count == calls + 1
+    assert eng.params is srv.params
+    for leaf in LEAVES:
+        x, want = _leaf(eng.params, leaf), _leaf(own.params, leaf)
+        assert isinstance(x, jax.Array) and x.dtype == want.dtype, leaf
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(want))
+        if leaf.split("/")[-1] not in MATRICES:
+            # a device array in the right dtype is the one handed in
+            assert x is _leaf(params, leaf), leaf
+    assert _serve(eng) == _serve(eng_own)
+    # the gauge reads the tree the programs are handed
+    nbytes = sum(int(x.nbytes)
+                 for x in jax.tree_util.tree_leaves(eng.params))
+    assert nbytes < sum(int(x.nbytes)
+                        for x in jax.tree_util.tree_leaves(params))
+    assert eng.metrics.get("engine_weight_bytes").value() == nbytes
+    # the artifact's own (host) leaves leave engine() on the device
+    assert all(isinstance(x, jax.Array)
+               for x in jax.tree_util.tree_leaves(eng_own.params))
+    assert eng_own.metrics.get("engine_weight_bytes").value() == nbytes
+    # float32 matrices set on the ENGINE, past the server (a
+    # benchmark's next seed): conformed by the same one cast
+    eng.params = params
+    assert global_stats.get("artifact/conform").count == calls + 2
+    assert eng.params["blocks"]["qkv"].dtype == jnp.bfloat16
+    assert eng.params["embed"] is params["embed"]
+    assert eng.metrics.get("engine_weight_bytes").value() == nbytes
+    assert _serve(eng, rng_seed=8) == _serve(eng_own, rng_seed=8)
+    eng_own.params = None           # a caller freeing the weights
+    assert eng_own.params is None
+    assert eng_own.metrics.get("engine_weight_bytes").value() == 0
+
+
+def _identity_case(name, tmp_path, monkeypatch):
+    """(config, tree, path of an artifact exported from that tree) of a
+    case in which nothing is to cast."""
+    from paddle_tpu.models import gated_hybrid
+    path = str(tmp_path / "lm.tar")
+    if name == "float32_config":
+        params = transformer.init_params(jax.random.PRNGKey(0), CFG)
+        _export_paged(path, params, CFG)
+        return CFG, params, path
+    if name == "int8_tree":
+        params = lm_serving.quantize_lm_params(
+            transformer.init_params(jax.random.PRNGKey(0), CFG_BF16))
+        # an exported program typed from the tree stands in for the
+        # int8 artifact's (test_engine_artifact_v4_int8_roundtrip
+        # serves a real one)
+    elif name == "gated_hybrid":
+        cfg = transformer.TransformerConfig(
+            vocab=64, d_model=32, n_heads=2, n_kv_heads=1, n_layers=4,
+            d_ff=16, max_len=64, dtype=jnp.bfloat16, use_rope=True,
+            skeleton="gated_hybrid", attn_head_dim=16, rotary_dim=8,
+            full_attn_interval=4, rec_key_heads=2, rec_value_heads=2,
+            rec_key_dim=16, rec_value_dim=16, moe_experts=4, moe_top_k=2,
+            moe_shared_ff=16)
+        return cfg, gated_hybrid.init_params(
+            jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16), None
+    elif name == "float32_programs":
+        # what the parent commit exported: float32 leaves under a bf16
+        # config, programs typed for them
+        params = transformer.init_params(jax.random.PRNGKey(3), CFG_BF16)
+        with monkeypatch.context() as m:
+            m.setattr(transformer, "compute_dtype_params",
+                      lambda p, cfg: p)
+            _export_paged(path, params, CFG_BF16)
+        return CFG_BF16, params, path
+    return CFG_BF16, params, None
+
+
+@pytest.mark.parametrize("case", ["float32_config", "int8_tree",
+                                  "gated_hybrid", "float32_programs"])
+def test_nothing_to_cast_is_the_identity(case, tmp_path, monkeypatch,
+                                         bf16_artifact):
+    """A float32 config, an int8 tree, the gated_hybrid skeleton (bf16
+    leaves since it exists) and an artifact whose programs take float32
+    leaves (the parent commit's): the helper returns the tree it was
+    given, the conform step every leaf it was given, and the old
+    artifact serves the ids the new one serves."""
+    cfg, params, path = _identity_case(case, tmp_path, monkeypatch)
+    if case != "float32_programs":
+        assert transformer.compute_dtype_params(params, cfg) is params
+    leaves = jax.tree_util.tree_leaves(params)
+    if path is None:
+        exported = jax.export.export(jax.jit(lambda p, x: x))(
+            jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
+            jax.ShapeDtypeStruct((), jnp.int32))
+        got = lm_serving._conform(params, exported)
+        assert jax.tree_util.tree_structure(got) \
+            == jax.tree_util.tree_structure(params)
+    else:
+        srv = lm_serving.load_lm_artifact(path)
+        # host leaves of the artifact itself: placed, dtype kept
+        own = srv.engine(seed=0)
+        for a, b in zip(jax.tree_util.tree_leaves(own.params), leaves):
+            assert isinstance(a, jax.Array) and a.dtype == b.dtype
+        srv.params = params
+        eng = srv.engine(seed=0)
+        got = eng.params
+        if case == "float32_programs":
+            # float32 all through, cast by the program at every step:
+            # the same ids as the artifact that stores them cast
+            new = lm_serving.load_lm_artifact(bf16_artifact[1])
+            assert _serve(eng) == _serve(new.engine(seed=0))
+    for a, b in zip(jax.tree_util.tree_leaves(got), leaves):
+        assert a is b
+
+
+def test_spec_artifact_stores_the_draft_in_compute_dtype(tmp_path):
+    """The speculative members take their trees the same way: the
+    draft's matrices are stored in ITS compute dtype (the ``@bfloat16``
+    member spelling), a float32 draft handed in is conformed to the
+    propose program's inputs, and the engine serves the in-process
+    engine's ids."""
+    from paddle_tpu.serving import SpecDecodeEngine
+    import dataclasses
+    dcfg = dataclasses.replace(CFG_BF16, n_layers=1, d_ff=32)
+    params = transformer.init_params(jax.random.PRNGKey(3), CFG_BF16)
+    draft = transformer.init_params(jax.random.PRNGKey(4), dcfg)
+    path = str(tmp_path / "spec.tar")
+    lm_serving.save_lm_artifact(
+        path, params, CFG_BF16, batch=2, prompt_len=6, cache_len=32,
+        engine_buckets=(8,), engine_block_size=8,
+        engine_draft_params=draft, engine_draft_config=dcfg,
+        engine_spec_k=2)
+    srv = lm_serving.load_lm_artifact(path)
+    for tree in (srv.params, srv.draft_params):
+        for n, x in tree["blocks"].items():
+            assert x.dtype == (jnp.bfloat16 if n in MATRICES
+                               else jnp.float32), n
+        assert tree["embed"].dtype == jnp.float32
+    srv.params, srv.draft_params = params, draft
+    eng = srv.engine(seed=0)
+    assert isinstance(eng, SpecDecodeEngine)
+    assert eng.draft_params is srv.draft_params
+    assert eng.draft_params["blocks"]["qkv"].dtype == jnp.bfloat16
+    assert eng.metrics.get("engine_weight_bytes").value() == sum(
+        int(x.nbytes) for x in jax.tree_util.tree_leaves(
+            (eng.params, eng.draft_params)))
+    mine = SpecDecodeEngine.from_params(
+        params, CFG_BF16, draft, dcfg, spec_k=2, batch=2, cache_len=32,
+        block_size=8, chunk_tokens=8, chunk_buckets=(8,), seed=0)
+    assert mine.draft_params["blocks"]["mlp_out"].dtype == jnp.bfloat16
+    assert _serve(eng) == _serve(mine)

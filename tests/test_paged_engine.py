@@ -684,3 +684,119 @@ class TestPoolInPlace:
         assert list(v.tokens) == list(ref.tokens)
         assert int(eng.metrics.get("engine_resumes_total").value(
             mode=resume)) == 1
+
+
+# -- the block matrices in the compute dtype (PR 29) -------------------------
+
+def _step_programs(cfg, B=3, P=4, C=8):
+    """{name: (fn of (params, pool), its pool)}: the paged step
+    programs at fixed small inputs."""
+    rng = np.random.RandomState(5)
+    pool = _random_pool(cfg, B * P, None, rng)
+    pages = jnp.asarray(np.arange(B * P, dtype=np.int32).reshape(B, P))
+    tok = jnp.asarray(rng.randint(0, 40, B), jnp.int32)
+    pos = jnp.asarray([9, 4, 17], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    chunk = jnp.asarray(rng.randint(0, 40, (1, C)), jnp.int32)
+    window = jnp.asarray(rng.randint(0, 40, (B, 3)), jnp.int32)
+    valid = jnp.asarray([3, 1, 2], jnp.int32)
+    return {
+        "decode": lambda p, c: transformer.decode_step_paged(
+            p, c, tok, pos, active, pages, cfg, block_size=BS,
+            pallas="off"),
+        # the second chunk of a prompt: context pages + the chunk's own
+        "prefill": lambda p, c: transformer.prefill_into_blocks(
+            p, c, chunk, jnp.int32(C - 2), pages[1, :2], cfg,
+            block_size=BS, pallas="off"),
+        "verify": lambda p, c: transformer.verify_step_paged(
+            p, c, window, pos, valid, active, pages, cfg,
+            block_size=BS)}, pool
+
+
+def _matrix_converts(jaxpr, shapes):
+    """``convert_element_type`` equations, anywhere in ``jaxpr``, whose
+    operand has one of ``shapes``."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "convert_element_type" \
+                and eqn.invars[0].aval.shape in shapes:
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _matrix_converts(sub, shapes)
+    return n
+
+
+class TestMatricesInComputeDtype:
+    """``transformer.compute_dtype_params``: a serving program takes
+    the four block matrices in ``cfg.dtype``, so the block's
+    ``w[...].astype(h.dtype)`` is a no-op in the program."""
+
+    @pytest.mark.parametrize("tree", ["helper", "float32"])
+    @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+    def test_no_cast_of_a_block_matrix_in_the_program(self, program,
+                                                      tree):
+        """The test that fails if the cast creeps back: over the
+        helper's tree no ``convert_element_type`` has an operand of a
+        block matrix's shape (one layer's, or the stack's, were it
+        hoisted); over raw float32 leaves the layer body holds four."""
+        cfg = CFG_BF16
+        params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+        shapes = set()
+        for n in transformer.COMPUTE_DTYPE_LEAVES:
+            shapes |= {params["blocks"][n].shape,
+                       params["blocks"][n].shape[1:]}
+        if tree == "helper":
+            params = transformer.compute_dtype_params(params, cfg)
+        programs, pool = _step_programs(cfg)
+        jaxpr = jax.make_jaxpr(programs[program])(params, pool).jaxpr
+        assert _matrix_converts(jaxpr, shapes) \
+            == (0 if tree == "helper" else 4)
+
+    @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+    def test_bitwise_the_float32_tree(self, program):
+        """A bf16 value cast from the same float32 value once is the
+        value the step cast every time: logits and pool bit-equal."""
+        cfg = CFG_BF16
+        params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+        cast = transformer.compute_dtype_params(params, cfg)
+        assert cast["blocks"]["qkv"].dtype == jnp.bfloat16
+        assert cast["embed"] is params["embed"]
+        assert cast["blocks"]["ln1"] is params["blocks"]["ln1"]
+        programs, pool = _step_programs(cfg)
+        fn = jax.jit(programs[program])
+        want, got = fn(params, pool), fn(cast, pool)
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("cfg", [CFG, CFG_BF16], ids=["fp32", "bf16"])
+    def test_from_params_casts_once_and_counts_the_bytes(self, cfg, rng):
+        """The in-process engine is handed the helper's tree (under a
+        float32 config: the tree it was given), ``engine_weight_bytes``
+        reads that tree's bytes, and the served ids are those of an
+        engine handed the cast tree."""
+        params = transformer.init_params(jax.random.PRNGKey(1), cfg)
+        want = transformer.compute_dtype_params(params, cfg)
+        assert (want is params) == (cfg.dtype == jnp.float32)
+
+        def engine(p):
+            return PagedDecodeEngine.from_params(
+                p, cfg, batch=2, cache_len=32, block_size=BS,
+                chunk_tokens=8, seed=0, tracker=CompileTracker())
+
+        eng, eng_cast = engine(params), engine(want)
+        assert eng_cast.params is want
+        for a, b in zip(jax.tree_util.tree_leaves(eng.params),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.dtype == b.dtype
+        nbytes = sum(int(x.nbytes)
+                     for x in jax.tree_util.tree_leaves(want))
+        assert eng.metrics.get("engine_weight_bytes").value() == nbytes
+        prompts = [rng.randint(0, 40, n).astype(np.int32) for n in (5, 12)]
+        out = []
+        for e in (eng, eng_cast):
+            reqs = [e.submit(p, max_new=6) for p in prompts]
+            e.run_until_idle()
+            out.append([list(r.tokens) for r in reqs])
+        assert out[0] == out[1]
