@@ -205,9 +205,9 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
         raise ValueError(f"draft vocab {engine_draft_config.vocab} != "
                          f"target vocab {cfg.vocab}")
 
-    hybrid = cfg.skeleton == "gated_hybrid"
+    hybrid = cfg.skeleton != "gpt2"
     if hybrid:
-        # the skeleton runs through the paged engine's programs alone
+        # such a skeleton runs through the paged engine's programs alone
         # (bf16 weight leaves, the model's own KV width); the lockstep
         # pair is not exported for it
         for on, what in ((weights_int8, "int8 weights"),
@@ -337,7 +337,8 @@ def save_lm_artifact(path: str, params, cfg, *, batch: int,
                     p_shapes, pool_shapes,
                     jax.ShapeDtypeStruct((1, b), jnp.int32), i32,
                     jax.ShapeDtypeStruct((pv,), jnp.int32),
-                    *((i32,) if hybrid else ()),    # the slot
+                    *((i32,) if hybrid and transformer.skeleton_module(
+                        cfg).SLOT_STATE else ()),   # the slot
                     f32, i32, i32)
                 engine_members[
                     f"engine_prefill_paged_{b}_{pv}.bin"] = \
@@ -499,7 +500,8 @@ class LMServer:
         self.params = params
         # v5: the stamped speculative-decoding draft (None below v5)
         self.draft_params = draft_params
-        # the lockstep pair (absent from a gated_hybrid artifact, which
+        # the lockstep pair (absent from the artifact of a skeleton
+        # other than "gpt2", which
         # serves through engine() alone)
         self._prefill = prefill_bin and jax.export.deserialize(prefill_bin)
         self._decode = decode_bin and jax.export.deserialize(decode_bin)

@@ -55,6 +55,7 @@ from paddle_tpu.ops.pallas import policy as _pallas_policy
 
 HI = jax.lax.Precision.HIGHEST
 SUB = 64        # tokens per sub-chunk of the chunked delta rule
+SLOT_STATE = True   # the recurrent rows: per engine slot, beside the pages
 _EXPERT = ("w1", "w3", "w2")    # the routed experts' weight stacks
 
 
@@ -434,10 +435,10 @@ def _run_layers(params, x, cfg, carry, attn_fn, rec_fn, valid):
     return x, carry, stats
 
 
-def _head(params, x, cfg):
+def _head(params, x, cfg, centred=True):
     """Final norm and the untied head -> float32 logits; operands in
     the model dtype, accumulated in float32."""
-    x = _rms(x, params["ln_f"], cfg.norm_eps)
+    x = _rms(x, params["ln_f"], cfg.norm_eps, centred)
     return jnp.einsum("...d,vd->...v", x, params["head"].astype(x.dtype),
                       preferred_element_type=jnp.float32)
 

@@ -26,6 +26,23 @@ from paddle_tpu.ops.pallas import policy as _pallas_policy
 from paddle_tpu.parallel import ring
 
 
+# "gpt2" is the block of this module; each other skeleton is the module
+# of that name beside it (``skeleton_module``)
+SKELETONS = ("gpt2", "gated_hybrid", "latent_moe")
+
+
+def skeleton_module(cfg):
+    """The module that runs a skeleton other than "gpt2":
+    ``models/<skeleton>.py``. Each brings ``check_config``,
+    ``init_params``, ``init_block_pool``, the three programs
+    ``forward`` / ``prefill_chunk`` / ``decode_step``, ``refuse`` (what
+    it does not run) and ``SLOT_STATE`` (whether its pool keeps rows
+    per engine slot beside the pages, which a chunk then has to be
+    told the slot of)."""
+    import importlib
+    return importlib.import_module(f"paddle_tpu.models.{cfg.skeleton}")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int
@@ -85,21 +102,43 @@ class TransformerConfig:
                                        # chip holds of moe_experts (expert
                                        # parallelism's share); () = all
     moe_shared_ff: int = 0             # width of the shared expert
+    # -- the latent-attention skeleton (models/latent_moe.py):
+    # skeleton="latent_moe": plain RMSNorm, bias-free maps, untied head;
+    # latent attention in every layer; ``dense_layers`` leading layers
+    # with a dense SwiGLU of width ``dense_ff``, then dropless expert
+    # layers (d_ff the expert width, moe_held / moe_shared_ff as above)
+    # routed by sigmoid scores plus a selection bias
+    q_lora_rank: int = 0               # rank of the query's down-map
+    kv_lora_rank: int = 0              # width of the cached latent
+    qk_nope_dim: int = 0               # a head's unrotated key width,
+    qk_rope_dim: int = 0               # its rotary width (one rotary
+                                       # key serves all heads) and
+    v_head_dim: int = 0                # its value width
+    dense_layers: int = 0
+    dense_ff: int = 0
+    moe_route_scale: float = 1.0       # factor on the k renormalised
+                                       # expert weights
+    mtp_layers: int = 0                # depth of the multi-token-
+                                       # prediction module's block
+                                       # (``latent_moe.forward_mtp``; no
+                                       # served path runs it)
 
     def __post_init__(self):
         object.__setattr__(self, "moe_held",
                            tuple(int(i) for i in self.moe_held))
-        if self.skeleton not in ("gpt2", "gated_hybrid"):
-            raise ValueError(f"skeleton must be 'gpt2' or 'gated_hybrid',"
-                             f" got {self.skeleton!r}")
-        if self.skeleton == "gated_hybrid":
-            from paddle_tpu.models import gated_hybrid
-            gated_hybrid.check_config(self)
+        if self.skeleton not in SKELETONS:
+            raise ValueError(f"skeleton must be one of {SKELETONS}, "
+                             f"got {self.skeleton!r}")
+        if self.skeleton != "gpt2":
+            skeleton_module(self).check_config(self)
         elif self.attn_head_dim or self.rotary_dim or self.moe_held \
-                or self.moe_shared_ff:
+                or self.moe_shared_ff or self.kv_lora_rank \
+                or self.dense_layers or self.mtp_layers:
             raise ValueError("attn_head_dim, rotary_dim, moe_held and "
                              "moe_shared_ff belong to skeleton="
-                             "'gated_hybrid'")
+                             "'gated_hybrid' or 'latent_moe', "
+                             "kv_lora_rank, dense_layers and mtp_layers "
+                             "to 'latent_moe'")
         if self.cp_mode not in ("ring", "alltoall"):
             raise ValueError(
                 f"cp_mode must be 'ring' or 'alltoall', got "
@@ -135,9 +174,8 @@ class TransformerConfig:
 
 def init_params(key: jax.Array, cfg: TransformerConfig):
     """Parameter pytree; block weights stacked on axis 0 (scan layout)."""
-    if cfg.skeleton == "gated_hybrid":
-        from paddle_tpu.models import gated_hybrid
-        return gated_hybrid.init_params(key, cfg)
+    if cfg.skeleton != "gpt2":
+        return skeleton_module(cfg).init_params(key, cfg)
     k = jax.random.split(key, 8)
     D, F, L, V = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab
     kvd = cfg.kv_heads * cfg.head_dim     # == D for MHA; smaller for GQA
@@ -218,13 +256,12 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh):
 
 
 def require_gpt2(cfg, what: str):
-    """THE check of everything ``skeleton="gated_hybrid"`` does not
-    run (``models/gated_hybrid.refuse`` says what is supported): every
+    """THE check of everything a skeleton other than "gpt2" does not
+    run (its module's ``refuse`` says what is supported): every
     entry point below that computes on the GPT-2 block calls it first,
     so nothing computes silently on the wrong block."""
-    if cfg.skeleton == "gated_hybrid":
-        from paddle_tpu.models import gated_hybrid
-        gated_hybrid.refuse(what)
+    if cfg.skeleton != "gpt2":
+        skeleton_module(cfg).refuse(what)
 
 
 # the stacked matrices the GPT-2 block consumes ONLY as
@@ -244,8 +281,8 @@ def compute_dtype_params(params, cfg: TransformerConfig):
     leaf (embedding, positions, LayerNorm, a router's gate, an expert
     stack, ``{"q8", "scale"}`` nodes of an int8 tree) comes back as it
     is, and so does the whole tree (``is``) when nothing is to cast: a
-    float32 config, leaves already in the compute dtype, the
-    ``gated_hybrid`` skeleton (bf16 leaves since it exists). The
+    float32 config, leaves already in the compute dtype, the other
+    skeletons (bf16 leaves since they exist). The
     trainer keeps float32 master weights and runs the same block."""
     if cfg.skeleton != "gpt2":
         return params
@@ -388,13 +425,13 @@ def forward(params, tokens: jax.Array, cfg: TransformerConfig, *,
     ``return_aux=True`` additionally returns the summed MoE
     load-balance loss (zero for dense configs) — lm_loss adds it.
     """
-    if cfg.skeleton == "gated_hybrid":
-        from paddle_tpu.models import gated_hybrid
+    if cfg.skeleton != "gpt2":
         if mesh is not None or return_kv or return_aux \
                 or dropout_key is not None:
-            gated_hybrid.refuse("forward(mesh=, return_kv=, return_aux=, "
-                                "dropout_key=)")
-        return gated_hybrid.forward(params, tokens, cfg, lengths=lengths)
+            require_gpt2(cfg, "forward(mesh=, return_kv=, return_aux=, "
+                              "dropout_key=)")
+        return skeleton_module(cfg).forward(params, tokens, cfg,
+                                            lengths=lengths)
     return _forward_impl(params, tokens, cfg, mesh, lengths, return_kv,
                          head="all", dropout_key=dropout_key,
                          return_aux=return_aux)
@@ -605,17 +642,20 @@ def init_block_pool(cfg: TransformerConfig, num_blocks: int,
     Scales are per pool ROW (write-local): a decode step writing one
     token never rescales a block's resident neighbours, which is what
     keeps hit-replay bitwise and blocks relocatable."""
-    if cfg.skeleton == "gated_hybrid":
-        # pages for the full-attention layers only, and per slot the
-        # recurrent rows of the others (``slots``: the engine's batch)
-        from paddle_tpu.models import gated_hybrid
+    if cfg.skeleton != "gpt2":
+        # the skeleton's own page tables (``serving/transfer``'s
+        # description holds for them: gated_hybrid pages its
+        # full-attention layers only, latent_moe keeps one latent row a
+        # token) and, where it has any, per slot the rows beside them
+        # (``slots``: the engine's batch)
+        mod = skeleton_module(cfg)
         if kv_dtype not in (None, "none"):
-            gated_hybrid.refuse(f"an {kv_dtype} KV pool")
-        if slots is None:
-            raise ValueError("init_block_pool(slots=...): a gated_hybrid "
-                             "pool holds recurrent rows per engine slot")
-        return gated_hybrid.init_block_pool(cfg, num_blocks, block_size,
-                                            int(slots))
+            mod.refuse(f"an {kv_dtype} KV pool")
+        if mod.SLOT_STATE and slots is None:
+            raise ValueError(f"init_block_pool(slots=...): a "
+                             f"{cfg.skeleton} pool holds rows per engine "
+                             f"slot")
+        return mod.init_block_pool(cfg, num_blocks, block_size, slots)
     M = int(num_blocks) * int(block_size)
     if kv_dtype in (None, "none"):
         shape = (cfg.n_layers, cfg.kv_heads, M, cfg.head_dim)
@@ -653,20 +693,15 @@ def pool_kv_dtype(cache, cfg: TransformerConfig) -> str:
 def kv_pool_bytes_per_token(cfg: TransformerConfig,
                             kv_dtype: Optional[str] = None) -> int:
     """HBM bytes ONE resident token costs across all layers (k + v +
-    scale rows) — the ``engine_kv_bytes_per_token`` gauge and the
-    slots-at-equal-HBM arithmetic in ``serving_bench``."""
-    Hkv, Dh = cfg.kv_heads, cfg.head_dim
-    if kv_dtype in (None, "none"):
-        per = 2 * Hkv * Dh * jnp.dtype(cfg.dtype).itemsize
-    elif kv_dtype == "int8":
-        per = 2 * Hkv * Dh + 2 * Hkv * 4
-    elif kv_dtype == "int4":
-        per = 2 * Hkv * (Dh // 2) + 2 * Hkv * 4
-    else:
-        raise ValueError(f"kv_dtype {kv_dtype!r}")
-    if cfg.skeleton == "gated_hybrid":       # full-attention layers only
-        return cfg.n_layers // cfg.full_attn_interval * per
-    return cfg.n_layers * per
+    scale rows, or whatever page tables the skeleton keeps) — the
+    ``engine_kv_bytes_per_token`` gauge and the slots-at-equal-HBM
+    arithmetic in ``serving_bench``: read off the shapes of a pool of
+    ONE row by the description every pool satisfies
+    (``serving/transfer.bytes_per_token``), as the engine reads it off
+    the pool it is handed."""
+    from paddle_tpu.serving import transfer
+    return transfer.bytes_per_token(jax.eval_shape(
+        lambda: init_block_pool(cfg, 1, 1, kv_dtype, slots=1)))
 
 
 def kv_rel_l2_budget(cfg: TransformerConfig, kv_dtype: str) -> float:
@@ -707,20 +742,20 @@ def _gather_pages(tab, groups, pages, block_size: int, num_blocks: int):
                      + tab.shape[1:])
 
 
-def _hybrid_step(params, cache, cfg, pallas, program: str, *args, **kw):
-    """A paged step program of ``skeleton="gated_hybrid"``
-    (``models/gated_hybrid``): XLA path, weights and pool in the
-    model's dtype; ``(logits, pool)`` and with ``return_stats`` the
-    expert layer's counts as a third."""
-    from paddle_tpu.models import gated_hybrid
+def _skeleton_step(params, cache, cfg, pallas, program: str, *args, **kw):
+    """A paged step program of a skeleton other than "gpt2" (its
+    module's ``prefill_chunk`` / ``decode_step``): XLA path, weights
+    and pool in the model's dtype; ``(logits, pool)`` and with
+    ``return_stats`` the expert layer's counts as a third."""
+    mod = skeleton_module(cfg)
     if _pallas_policy.pallas_mode(pallas) != "off":
-        gated_hybrid.refuse("PADDLE_TPU_PALLAS other than 'off' (no "
-                            "kernel takes its head width)")
+        mod.refuse("PADDLE_TPU_PALLAS other than 'off' (no kernel takes "
+                   "its head widths)")
     if "k_scale" in cache:
-        gated_hybrid.refuse("an int8 / int4 KV pool")
+        mod.refuse("an int8 / int4 KV pool")
     if _blocks_quantized({"blocks": params}):
-        gated_hybrid.refuse("int8 weights")
-    return getattr(gated_hybrid, program)(params, cache, *args, cfg, **kw)
+        mod.refuse("int8 weights")
+    return getattr(mod, program)(params, cache, *args, cfg, **kw)
 
 
 def prefill(params, tokens: jax.Array, cfg: TransformerConfig,
@@ -883,11 +918,11 @@ def decode_step_paged(params, cache, tokens: jax.Array, pos: jax.Array,
     quantized path (tests/test_kv_quant.py)."""
     from paddle_tpu.ops import q8 as ops_q8
     from paddle_tpu.ops.pallas import decode as _pallas_decode
-    if cfg.skeleton == "gated_hybrid":
-        return _hybrid_step(params, cache, cfg, pallas, "decode_step",
-                            tokens, pos, active, pages,
-                            block_size=block_size,
-                            return_stats=return_stats)
+    if cfg.skeleton != "gpt2":
+        return _skeleton_step(params, cache, cfg, pallas, "decode_step",
+                              tokens, pos, active, pages,
+                              block_size=block_size,
+                              return_stats=return_stats)
     B = tokens.shape[0]
     P = pages.shape[1]
     bs = int(block_size)
@@ -1273,16 +1308,20 @@ def prefill_into_blocks(params, cache, tokens: jax.Array,
     writes run the ``paged_span_write`` kernel (block-mapped through
     the page vector via scalar prefetch). The XLA path above is what
     ``off`` selects, and the numerics reference."""
-    if cfg.skeleton == "gated_hybrid":
-        # ``slot``: whose recurrent rows the chunk reads and writes
-        if slot is None:
-            raise ValueError("prefill_into_blocks(slot=...): a "
-                             "gated_hybrid chunk updates its slot's "
-                             "recurrent rows")
-        return _hybrid_step(params, cache, cfg, pallas, "prefill_chunk",
-                            tokens, length, pages, slot,
-                            block_size=block_size,
-                            return_stats=return_stats)
+    if cfg.skeleton != "gpt2":
+        # ``slot``: whose rows beside the pages the chunk reads and
+        # writes, for a skeleton that keeps any
+        whose = ()
+        if skeleton_module(cfg).SLOT_STATE:
+            if slot is None:
+                raise ValueError(f"prefill_into_blocks(slot=...): a "
+                                 f"{cfg.skeleton} chunk updates its "
+                                 f"slot's recurrent rows")
+            whose = (slot,)
+        return _skeleton_step(params, cache, cfg, pallas, "prefill_chunk",
+                              tokens, length, pages, *whose,
+                              block_size=block_size,
+                              return_stats=return_stats)
     from paddle_tpu.ops import q8 as ops_q8
     if tokens.shape[0] != 1:
         raise ValueError(f"prefill_into_blocks takes one request "

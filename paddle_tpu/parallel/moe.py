@@ -225,10 +225,13 @@ _HI = jax.lax.Precision.HIGHEST
 
 def dropless_init_params(key: jax.Array, d_model: int, d_ff: int,
                          num_experts: int, held: int, shared_ff: int,
-                         dtype=jnp.float32):
+                         dtype=jnp.float32, route: str = "softmax"):
     """``router`` over ALL experts (float32), SwiGLU weights of the
-    ``held`` ones, a shared expert of width ``shared_ff`` under a
-    sigmoid gate."""
+    ``held`` ones, a shared expert of width ``shared_ff``: under a
+    sigmoid gate (``s_gate``) for ``route="softmax"``; ungated, with a
+    selection bias per expert (``router_bias``, float32, small and not
+    zero so that selection and weighting differ), for
+    ``route="sigmoid_bias"``."""
     k = jax.random.split(key, 8)
     D, F, Fs = d_model, d_ff, shared_ff
     s = 1.0 / math.sqrt(D)
@@ -237,25 +240,56 @@ def dropless_init_params(key: jax.Array, d_model: int, d_ff: int,
         return (jax.random.normal(kk, shape, jnp.float32)
                 * scale).astype(dtype)
 
-    return {
+    out = {
         "router": jax.random.normal(k[0], (D, num_experts),
                                     jnp.float32) * s,
         "w1": nrm(k[1], (held, D, F), s), "w3": nrm(k[2], (held, D, F), s),
         "w2": nrm(k[3], (held, F, D), 1.0 / math.sqrt(F)),
-        "s_gate": nrm(k[4], (D,), s),
         "s_w1": nrm(k[5], (D, Fs), s), "s_w3": nrm(k[6], (D, Fs), s),
         "s_w2": nrm(k[7], (Fs, D), 1.0 / math.sqrt(Fs)),
     }
+    if route == "sigmoid_bias":
+        out["router_bias"] = jax.random.normal(
+            jax.random.fold_in(key, 8), (num_experts,), jnp.float32) * 0.1
+    else:
+        out["s_gate"] = nrm(k[4], (D,), s)
+    return out
+
+
+def route_softmax(params, logits, k: int):
+    """softmax over ALL experts -> top-k -> the k weights renormalised
+    over the k."""
+    gate, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return gate / jnp.sum(gate, axis=-1, keepdims=True), expert
+
+
+def route_sigmoid_bias(scale: float):
+    """The rule of a router trained without an auxiliary loss: scores
+    ``s = sigmoid(logits)``; the k experts are chosen by ``s + b``
+    (``params["router_bias"]``, which steers the load and nothing
+    else); the weights are the UNBIASED scores of the chosen,
+    renormalised over the k and multiplied by ``scale``."""
+    def route(params, logits, k: int):
+        s = jax.nn.sigmoid(logits)
+        _, expert = jax.lax.top_k(
+            s + params["router_bias"].astype(jnp.float32), k)
+        gate = jnp.take_along_axis(s, expert, axis=-1)
+        return gate / jnp.sum(gate, axis=-1, keepdims=True) * scale, expert
+    return route
 
 
 def moe_dropless(params, x: jax.Array, *, top_k: int,
                  held: Tuple[int, int], valid: Optional[jax.Array] = None,
-                 layer=0) -> Tuple[jax.Array, jax.Array]:
+                 layer=0, route=route_softmax
+                 ) -> Tuple[jax.Array, jax.Array]:
     """Top-k SwiGLU experts without capacity: x [N, D] -> (out [N, D],
     stats int32 [2] = (assignments kept here, distinct held experts hit)).
 
     The router scores ALL experts in float32 (``params["router"]``
-    [D, E]) and the k chosen weights are renormalised over the k, as
+    [D, E]) and ``route(params, logits, k) -> (weights [N, k], experts
+    [N, k])`` turns the scores into the k assignments of a token
+    (:func:`route_softmax`: the weights renormalised over the k;
+    :func:`route_sigmoid_bias`), as
     expert parallelism has every chip do; of the N*k assignments this
     chip keeps those whose expert lies in ``held = (first, count)``,
     the span whose weights it holds (``w1``/``w3`` [count, D, F], ``w2``
@@ -265,7 +299,8 @@ def moe_dropless(params, x: jax.Array, *, top_k: int,
     its token under its weight. An assignment to an absent expert adds
     nothing here: its weight took part in the renormalisation, its
     product is the other chip's. The shared expert (``s_w1``/``s_w3``/
-    ``s_w2`` under ``sigmoid(x . s_gate)``) is added once, to every
+    ``s_w2``; under ``sigmoid(x . s_gate)`` where the tree has an
+    ``s_gate``, ungated where it has none) is added once, to every
     token. Rows where ``valid`` [N] is False (a chunk's padding) are
     routed nowhere and counted nowhere.
 
@@ -282,8 +317,7 @@ def moe_dropless(params, x: jax.Array, *, top_k: int,
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
                         params["router"].astype(jnp.float32),
                         precision=_HI)
-    gate, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    gate, expert = route(params, logits, k)
     local = expert - first
     kept = (local >= 0) & (local < count)
     if valid is not None:
@@ -313,10 +347,13 @@ def moe_dropless(params, x: jax.Array, *, top_k: int,
     sw1, sw3, sw2 = (params[n].astype(x.dtype)
                      for n in ("s_w1", "s_w3", "s_w2"))
     sh = (jax.nn.silu(x @ sw1) * (x @ sw3)) @ sw2
-    sg = jax.nn.sigmoid(jnp.einsum(
-        "nd,d->n", x.astype(jnp.float32),
-        params["s_gate"].astype(jnp.float32), precision=_HI))
-    out = out + sg[:, None] * sh.astype(jnp.float32)
+    if "s_gate" in params:
+        sg = jax.nn.sigmoid(jnp.einsum(
+            "nd,d->n", x.astype(jnp.float32),
+            params["s_gate"].astype(jnp.float32), precision=_HI))
+        out = out + sg[:, None] * sh.astype(jnp.float32)
+    else:
+        out = out + sh.astype(jnp.float32)
     stats = jnp.stack([jnp.sum(kept.astype(jnp.int32)),
                        jnp.sum((sizes > 0).astype(jnp.int32))])
     return out.astype(x.dtype), stats

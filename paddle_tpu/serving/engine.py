@@ -454,6 +454,16 @@ class PagedDecodeEngine:
             "engine_slow_steps_total", f"decode steps that completed "
             f"more than {_SLOW_STEP_S:g} s after the one before, with "
             f"decoders in flight throughout")
+        self._m_live_rows = reg.counter(
+            "engine_decode_live_rows_total", "live cache rows of the "
+            "active slots (positions 0 .. pos), summed over decode "
+            "steps: what a decode step's attention has to read")
+        self._m_read_rows = reg.counter(
+            "engine_decode_read_rows_total", "cache rows the decode "
+            "program's attention reads, summed over decode steps: "
+            "slots x cache_len where it attends over the gathered "
+            "view of every slot's pages (the XLA path), the active "
+            "slots' live pages where a kernel reads pages in place")
         self._step_end: Optional[float] = None   # last decode step's,
         #                                 while decoders stay in flight
         self._tag = {"step": 0, "active": 0}     # span args of this step
@@ -467,28 +477,24 @@ class PagedDecodeEngine:
         # KV storage width of the device pool ("none" = model dtype;
         # "int8"/"int4" pools carry per-(position, head) scale tables
         # the page table indexes alongside the values). Derived HBM
-        # arithmetic uses the pool SHAPES, so it needs no model config.
+        # arithmetic uses the pool SHAPES under the one description of
+        # a pool (``serving/transfer``: page tables, and whatever else
+        # is rows per slot), so it needs no model config.
+        from paddle_tpu.serving import transfer as _transfer
         self.kv_dtype = kv_dtype or "none"
-        kshape = cache["k"].shape          # [L, Hkv, M, Dh-stored]
-        L, Hkv, _, Dh_st = kshape
-        per_tok = 2 * Hkv * Dh_st * cache["k"].dtype.itemsize
-        if "k_scale" in cache:
-            per_tok += 2 * Hkv * 4         # fp32 scale rows (k + v)
-        self.kv_bytes_per_token = int(L) * per_tok
+        self.kv_bytes_per_token = _transfer.bytes_per_token(cache)
         self.pool_bytes = self.kv_bytes_per_token * self.num_blocks * bs
         # A model with RECURRENT layers (``models/gated_hybrid``): the
         # pool pytree also holds, per slot, fixed-size state rows, and
-        # ``k``/``v`` cover the attention layers only (so the arithmetic
-        # above counts those). Pages are then NOT all the state a
+        # the page tables cover the attention layers only. Pages are
+        # then NOT all the state a
         # position depends on: nothing stores the recurrent rows at a
         # block boundary, so a prefix hit could not be resumed from.
         # Prefix publishing, lookup, adoption, export/import and tier
         # demotion are off; preemption releases the pages unpublished
         # and resume replays from position 0.
-        self.recurrent = "rec_state" in cache
-        self.recurrent_state_bytes = sum(
-            int(cache[n].size) * cache[n].dtype.itemsize
-            for n in ("rec_state", "rec_tail") if n in cache)
+        self.recurrent_state_bytes = _transfer.slot_state_bytes(cache)
+        self.recurrent = self.recurrent_state_bytes > 0
         if self.recurrent and tiers is not None:
             raise ValueError("tiered spill (tiers=) is off for a model "
                              "with recurrent state: no prefix block is "
@@ -1877,7 +1883,7 @@ class PagedDecodeEngine:
     def moe_stats(self) -> bool:
         """Whether the step programs append the expert layer's three
         counts to the ids they return
-        (``sampling._hybrid_paged_step_fns``): read off their results,
+        (``sampling._skeleton_paged_step_fns``): read off their results,
         so known from ``precompile()`` or the first step on."""
         return self._m_moe is not None
 
@@ -1922,6 +1928,7 @@ class PagedDecodeEngine:
                     for m, n in zip(self._moe_counters(),
                                     nxt[self.batch:]):
                         m.inc(int(n))
+                self._count_decode_rows()
                 for slot in np.flatnonzero(self._active):
                     self._pos[slot] += 1
                     forced = self._slot_forced[slot]
@@ -1941,6 +1948,19 @@ class PagedDecodeEngine:
                         finished.append(req)
         self._close_step()
         return finished
+
+    def _count_decode_rows(self):
+        """The decode step just read back: rows its attention had to
+        read against rows it read, from the lengths the host holds."""
+        from paddle_tpu.ops.pallas.policy import PATH_XLA
+        live = self._pos[self._active].astype(np.int64) + 1
+        self._m_live_rows.inc(int(live.sum()))
+        if self.kernel_paths.get("decode", {}).get(
+                "attention", PATH_XLA) == PATH_XLA:
+            self._m_read_rows.inc(self.batch * self.cache_len)
+        else:
+            bs = self.block_size
+            self._m_read_rows.inc(int((-(-live // bs) * bs).sum()))
 
     def _open_step(self):
         self._tag = {"step": int(self._m_steps.value()),
@@ -2203,10 +2223,14 @@ class SpecDecodeEngine(PagedDecodeEngine):
                  verify: Callable, draft_verify: Callable, spec_k: int,
                  tracker: Optional[_ct.CompileTracker] = None,
                  **kw):
-        if "rec_state" in (cache or ()) or "rec_state" in (draft_cache
-                                                            or ()):
-            from paddle_tpu.models import gated_hybrid
-            gated_hybrid.refuse("speculative decoding (SpecDecodeEngine)")
+        # no verify program over recurrent rows or a latent pool
+        for leaf, skeleton in (("rec_state", "gated_hybrid"),
+                               ("latent", "latent_moe")):
+            if leaf in (cache or ()) or leaf in (draft_cache or ()):
+                import importlib
+                importlib.import_module(
+                    f"paddle_tpu.models.{skeleton}").refuse(
+                    "speculative decoding (SpecDecodeEngine)")
         if kw.get("tiers") is not None:
             # a spilled payload carries only TARGET pool rows; adopting
             # one would leave the draft pool's rows beside it stale —

@@ -205,10 +205,10 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
     from paddle_tpu.ops.pallas import policy as _pallas_policy
 
     mode = _pallas_policy.pallas_mode(pallas)
-    if cfg.skeleton == "gated_hybrid":
+    if cfg.skeleton != "gpt2":
         if dequant is not None:
             transformer.require_gpt2(cfg, "int8 weights")
-        return _hybrid_paged_step_fns(cfg, block_size, mode)
+        return _skeleton_paged_step_fns(cfg, block_size, mode)
     _live = _prefill_live(dequant)
     _live_d = _decode_live(dequant)
     tail = _epilogue(mode)
@@ -238,22 +238,24 @@ def paged_step_fns(cfg, block_size: int, dequant=None, pallas=None):
     return prefill_fn, decode_fn
 
 
-def _hybrid_paged_step_fns(cfg, block_size: int, mode: str):
-    """``paged_step_fns`` for ``skeleton="gated_hybrid"``: the chunk
-    program also takes the ``slot`` whose recurrent rows it updates
-    (after ``pages``), and both programs append the expert layer's
-    three counts (``gated_hybrid._run_layers``) to the ids they return
-    (``token [1 + 3]``, ``tokens [B + 3]``, int32), so the engine reads
-    them back in the transfer it already makes."""
+def _skeleton_paged_step_fns(cfg, block_size: int, mode: str):
+    """``paged_step_fns`` for a skeleton other than "gpt2"
+    (``transformer.skeleton_module``): where the skeleton keeps rows
+    per slot (``SLOT_STATE``: ``gated_hybrid``) the chunk program also
+    takes the ``slot`` whose rows it updates (after ``pages``), and
+    both programs append the expert layer's three counts (the
+    skeleton's ``_run_layers``) to the ids they return (``token [1 +
+    3]``, ``tokens [B + 3]``, int32), so the engine reads them back in
+    the transfer it already makes."""
     from paddle_tpu.models import transformer
     tail = _epilogue(mode)
 
-    def prefill_fn(params, pool, tokens, length, pages, slot,
-                   temperature, top_k, seed):
+    def prefill_fn(params, pool, tokens, length, pages, *rest):
+        *slot, temperature, top_k, seed = rest
         logits, pool, stats = transformer.prefill_into_blocks(
             params, pool, tokens, length, pages, cfg,
-            block_size=block_size, pallas=mode, slot=slot,
-            return_stats=True)
+            block_size=block_size, pallas=mode,
+            slot=slot[0] if slot else None, return_stats=True)
         tok = tail(logits, seed, jnp.reshape(temperature, (1,)),
                    jnp.reshape(top_k, (1,)))
         return jnp.concatenate([tok.astype(jnp.int32), stats]), pool

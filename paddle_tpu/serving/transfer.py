@@ -35,8 +35,32 @@ import numpy as np
 MAGIC = b"PTKV"
 VERSION = 1
 
-# per-block arrays ride in this order (when present in the pool)
-ARRAY_ORDER = ("k", "v", "k_scale", "v_scale")
+# THE description of the engine's pool pytree, which every model's pool
+# satisfies (per-head K/V, their int8 / int4 storage with scale tables,
+# a model that pages only some layers and keeps recurrent rows per
+# slot, a latent pool): a leaf named here is a PAGE TABLE
+# ``[layers, groups, M, ...]`` whose axis 2 is the flat position axis
+# (block i owns rows [i * block_size, (i + 1) * block_size)); a page is
+# that span of every table the pool has, which is what is copied to
+# export, adopt, spill or snapshot a block, in this order; what one
+# resident token costs is one row of every table
+# (``bytes_per_token``). Any OTHER leaf is state per engine slot
+# (``slot_state_bytes``): a pool that has some cannot resume from its
+# pages alone.
+ARRAY_ORDER = ("k", "v", "k_scale", "v_scale", "latent")
+
+
+def bytes_per_token(cache) -> int:
+    """HBM bytes one resident token costs: a row of every page table."""
+    return sum(int(np.prod(cache[n].shape)) // cache[n].shape[2]
+               * np.dtype(cache[n].dtype).itemsize
+               for n in ARRAY_ORDER if n in cache)
+
+
+def slot_state_bytes(cache) -> int:
+    """Bytes of the leaves that are no page table: rows per slot."""
+    return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+               for n, a in cache.items() if n not in ARRAY_ORDER)
 
 
 def _np_dtype(name: str) -> np.dtype:
