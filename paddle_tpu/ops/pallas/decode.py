@@ -56,13 +56,17 @@ devicelessly.
 (``serving/sampling.sample_tokens`` semantics, per-slot runtime vectors)
 as a Pallas kernel, one program per batch row, so the compiled decode
 step emits ``[B] int32`` token ids with no full-vocab sort: the runtime-k
-threshold is found by a 32-step radix binary search over the
-order-preserving integer image of the logits, and the categorical draw is
+threshold is ``ops/topk.kth_largest``, a 32-step binary search over the
+order-preserving integer image of the logits — the ONE definition that
+``sample_tokens`` reads too, written within Mosaic's limits because this
+kernel is the stricter of its two readers — and the categorical draw is
 a Gumbel-max over hashed counter-based uniforms (``pltpu.prng`` is
 TPU-only; the hash keeps the kernel interpretable on CPU). Greedy rows
-and the kept top-k SET match ``sample_tokens`` exactly; the categorical
-draw itself matches in distribution, not per-id (different RNG stream —
-the contract tests assert the distribution, greedy ties, and membership).
+and the kept top-k SET match ``sample_tokens`` exactly (the same
+threshold, compared as a float in both, so ``-0.0`` and ``+0.0`` tie
+here as they do there); the categorical draw itself matches in
+distribution, not per-id (different RNG stream — the contract tests
+assert the distribution, greedy ties, and membership).
 Counting/argmax reductions run over exact small-integer fp32 images
 (integer reductions have no Mosaic lowering; fp32 is exact below 2^24,
 far above any vocab).
@@ -81,6 +85,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops import topk as ops_topk
 from paddle_tpu.ops.pallas import policy as _policy
 
 NEG_INF = -1e30
@@ -355,39 +360,6 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _sortable_key(v: jax.Array) -> jax.Array:
-    """fp32 -> uint32 order-preserving image (the radix-sort key map):
-    positive floats get the sign bit set, negative floats flip every
-    bit, so unsigned comparisons order exactly like float compares."""
-    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
-    flip = ((u >> 31) * jnp.uint32(0x7FFFFFFF)) | jnp.uint32(0x80000000)
-    return u ^ flip
-
-
-def _kth_key(keys: jax.Array, k: jax.Array) -> jax.Array:
-    """The k-th largest of ``keys`` [1, V] uint32 (k >= 1, traced) by
-    32-step binary search on the integer threshold — count(keys >= t)
-    is monotone, so the invariant count(>= lo) >= k pins lo to the
-    exact k-th value after the interval collapses. O(32·V) compares, no
-    sort (lax.sort has no Mosaic lowering; this runs anywhere). The
-    count sums an fp32 0/1 image — exact below 2^24, far above any
-    vocab — because integer reductions have no Mosaic lowering
-    either."""
-    kf = k.astype(jnp.float32)
-
-    def body(_, lh):
-        lo, hi = lh
-        d = hi - lo
-        mid = lo + (d >> 1) + (d & jnp.uint32(1))   # ceil, overflow-safe
-        cnt = jnp.sum((keys >= mid).astype(jnp.float32))
-        take = cnt >= kf
-        return (jnp.where(take, mid, lo),
-                jnp.where(take, hi, mid - jnp.uint32(1)))
-    lo, _ = jax.lax.fori_loop(
-        0, 32, body, (jnp.uint32(0), jnp.uint32(0xFFFFFFFF)))
-    return lo
-
-
 def _hash_uniform(seed: jax.Array, row: jax.Array,
                   shape: Tuple[int, ...]) -> jax.Array:
     """Counter-based uniforms in (0, 1): a splitmix-style integer hash
@@ -420,7 +392,8 @@ def _first_argmax(x: jax.Array, iota: jax.Array) -> jax.Array:
 
 
 def _sample_kernel(seed_ref, temp_ref, topk_ref, logits_ref, o_ref):
-    """One batch row: greedy argmax, radix top-k threshold, temperature
+    """One batch row: greedy argmax, the top-k threshold by selection
+    (``ops/topk.kth_largest`` on the ``[1, V]`` block), temperature
     scale, Gumbel-max categorical — ``sample_tokens`` semantics with no
     full-vocab sort and no second dispatch. The per-row controls are
     scalar-prefetched (SMEM); logits ride as a ``(1, 1, V)`` block of
@@ -434,9 +407,8 @@ def _sample_kernel(seed_ref, temp_ref, topk_ref, logits_ref, o_ref):
         jnp.float32)
     greedy = _first_argmax(v, iota)
     k = jnp.clip(topk_ref[row], 0, V)
-    keys = _sortable_key(v)
-    kstar = _kth_key(keys, jnp.maximum(k, 1))
-    keep = (k <= 0) | (keys >= kstar)     # ties at the threshold survive
+    kth = ops_topk.kth_largest(v, jnp.maximum(k, 1))      # [1, 1]
+    keep = (k <= 0) | (v >= kth)          # ties at the threshold survive
     z = jnp.where(keep, v, -jnp.inf)
     temp = temp_ref[row]
     z = z / jnp.where(temp > 0, temp, 1.0)

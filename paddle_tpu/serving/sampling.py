@@ -13,13 +13,19 @@ Per-slot controls are runtime vectors (static shapes, one compile):
   row; the categorical draw still happens but is discarded by a
   ``where``, keeping the program shape-identical for any mix.
 - ``top_k`` [B] int32 — ``<= 0`` (or ``>= vocab``) disables filtering.
-  A runtime k can't use ``lax.top_k`` (static k), so the row is sorted
-  once and everything below the k-th value is masked to ``-inf``; ties
-  at the threshold survive, matching the usual top-k convention.
+  A runtime k can't use ``lax.top_k`` (static k), and a sort of the
+  row for that one number costs a quarter of a decode step at a
+  vocabulary's width: the k-th largest value is found by selection
+  (``ops/topk.kth_largest``: 32 compare-and-count passes, bitwise the
+  value a sort would give) and everything below it is masked to
+  ``-inf``; ties at the threshold survive, matching the usual top-k
+  convention.
 """
 
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu.ops import topk as ops_topk
 
 
 def sample_tokens(logits: jax.Array, key: jax.Array,
@@ -30,11 +36,9 @@ def sample_tokens(logits: jax.Array, key: jax.Array,
     V = logits.shape[-1]
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    k = jnp.clip(top_k.astype(jnp.int32), 0, V)
-    srt = jnp.sort(logits, axis=-1)[:, ::-1]          # descending
-    kth = jnp.take_along_axis(srt, jnp.maximum(k - 1, 0)[:, None],
-                              axis=-1)                # [B, 1]
-    keep = (k[:, None] <= 0) | (logits >= kth)
+    k = jnp.clip(top_k.astype(jnp.int32), 0, V)[:, None]
+    kth = ops_topk.kth_largest(logits, jnp.maximum(k, 1))    # [B, 1]
+    keep = (k <= 0) | (logits >= kth)
     z = jnp.where(keep, logits, -jnp.inf)
     t = jnp.where(temperature > 0, temperature, 1.0)  # div-safe for
     z = z / t[:, None].astype(jnp.float32)            # greedy rows
@@ -123,9 +127,13 @@ def _decode_live(dequant):
 def _epilogue(mode):
     """The sampling tail of a step program under the resolved
     ``PADDLE_TPU_PALLAS`` mode: ``sample_tokens`` for ``off``, the
-    Pallas ``fused_sample`` kernel otherwise (greedy/top-k set exact,
-    categorical matching in distribution). Nothing falls back: a
-    backend that cannot compile the kernel fails the compile."""
+    Pallas ``fused_sample`` kernel otherwise. Both find the top-k
+    threshold by the same selection (``ops/topk.kth_largest``) and
+    compare it as a float, so the greedy ids and the kept top-k set
+    are the same in both, ``-0.0`` / ``+0.0`` ties included; the
+    categorical draw matches in distribution (another stream). Nothing
+    falls back: a backend that cannot compile the kernel fails the
+    compile."""
     from paddle_tpu.ops.pallas import decode as _pallas_decode
     from paddle_tpu.ops.pallas import policy as _pallas_policy
     path = _pallas_policy.kernel_path(mode)

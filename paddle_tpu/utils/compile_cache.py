@@ -8,7 +8,7 @@ set in code; otherwise the cache is ``<checkout>/.jax_cache`` (listed in
 directory that moves never hits — so it is never built from a temporary
 name, a process id or the time. Child processes get the same directory:
 through the inherited variable, or by running the same rule from the
-same checkout.
+same checkout. Every program is kept, however fast it compiled.
 """
 
 import os
@@ -47,6 +47,12 @@ def configure() -> str:
         # (a bare ``python worker.py``) still lands in the same place
         os.environ[ENV] = path
         jax.config.update("jax_compilation_cache_dir", path)
+    # JAX keeps what compiled in under a second out of the cache, and
+    # a serving artifact holds many such programs (a chunk program
+    # whose sampler does not sort compiles in ~0.6 s): a warm start
+    # that compiles each again is seconds slower than one that reads
+    # them, so everything is kept
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     with _lock:
         if not _listening:
             jax.monitoring.register_event_listener(_on_event)

@@ -10,6 +10,7 @@ import pytest
 
 from paddle_tpu.models import transformer
 from paddle_tpu.observe.compile_tracker import CompileTracker
+from paddle_tpu.ops import topk as ops_topk
 from paddle_tpu.serving import (PagedDecodeEngine, SpecDecodeEngine,
                                 sample_tokens)
 
@@ -38,6 +39,51 @@ def _engine(kind="paged", batch=2, cache_len=32, seed=0):
     return PagedDecodeEngine.from_params(PARAMS, CFG, **kw)
 
 
+def _sample_tokens_by_sort(logits, key, temperature, top_k):
+    """``sample_tokens`` as it was up to PR 31: the k-th largest logit
+    read out of a full descending sort of the row. Kept here as the
+    reference the selection is held to, bitwise."""
+    V = logits.shape[-1]
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    k = jnp.clip(top_k.astype(jnp.int32), 0, V)
+    srt = jnp.sort(logits, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(srt, jnp.maximum(k - 1, 0)[:, None],
+                              axis=-1)
+    keep = (k[:, None] <= 0) | (logits >= kth)
+    z = jnp.where(keep, logits, -jnp.inf)
+    t = jnp.where(temperature > 0, temperature, 1.0)
+    z = z / t[:, None].astype(jnp.float32)
+    sampled = jax.random.categorical(key, z, axis=-1).astype(jnp.int32)
+    return jnp.where(temperature > 0, sampled, greedy)
+
+
+def _halves(rng, b, v):
+    """Logits rounded to halves: ties across every threshold."""
+    return (np.round(rng.randn(b, v) * 4) / 2).astype(np.float32)
+
+
+def _neg_inf_columns(rng, b, v):
+    x = _halves(rng, b, v)
+    x[:, rng.rand(v) < 0.3] = -np.inf
+    return x
+
+
+def _signed_zeros(rng, b, v):
+    x = _halves(rng, b, v)
+    x[np.abs(x) < 1.0] = 0.0
+    x[(x == 0) & (rng.rand(b, v) < 0.5)] = -0.0
+    return x
+
+
+def _all_equal(rng, b, v):
+    return np.full((b, v), rng.randn(), np.float32)
+
+
+_ROWS = {"halves": _halves, "neg_inf": _neg_inf_columns,
+         "signed_zeros": _signed_zeros, "all_equal": _all_equal}
+
+
 class TestOnDeviceSampling:
     def test_greedy_rows_argmax(self, rng):
         logits = jnp.asarray(rng.randn(4, 12), jnp.float32)
@@ -63,6 +109,49 @@ class TestOnDeviceSampling:
             logits, jax.random.PRNGKey(3),
             jnp.asarray([0.0, 5.0]), jnp.zeros(2, jnp.int32)))
         assert out[0] == np.asarray(logits[0]).argmax()
+
+    @pytest.mark.parametrize("batch", (1, 6))
+    @pytest.mark.parametrize("rows", sorted(_ROWS))
+    @pytest.mark.parametrize("vocab", (17, 128, 1000, 5003))
+    def test_ids_bitwise_those_of_the_sort_spelling(self, vocab, rows,
+                                                    batch):
+        """The contract of selection: whatever the ties, infinities and
+        signed zeros around the threshold, and whatever k and
+        temperature each row asks for, the ids are the ones the full
+        sort gave."""
+        rng = np.random.RandomState(vocab * 7 + batch)
+        new, old = jax.jit(sample_tokens), jax.jit(_sample_tokens_by_sort)
+        ks = (-3, 0, 1, 2, 40, vocab - 1, vocab, vocab + 9)
+        temps = (-1.0, 0.0, 0.8, 1.5)
+        for i in range(len(ks)):
+            logits = jnp.asarray(_ROWS[rows](rng, batch, vocab))
+            key = jax.random.PRNGKey(1000 * vocab + i)
+            top_k = jnp.asarray([ks[(i + r) % len(ks)]
+                                 for r in range(batch)], jnp.int32)
+            # row r of call i: every k meets a sampling temperature
+            temp = jnp.asarray([temps[(2 + i // 4 + r) % len(temps)]
+                                for r in range(batch)], jnp.float32)
+            np.testing.assert_array_equal(
+                np.asarray(new(logits, key, temp, top_k)),
+                np.asarray(old(logits, key, temp, top_k)))
+
+    def test_kth_largest_is_the_sorted_rows_kth(self, rng):
+        """Every k of a small row, ties, signed zeros and infinities in
+        it: the selected value is ``sort(x)[::-1][k - 1]`` (compared as
+        a float, which is how the sampler reads it: ``-0.0 == +0.0``),
+        and the key map is a bijection on the bits."""
+        x = np.round(rng.randn(3, 37) * 2) / 2
+        x[0, :5], x[1, 7:9], x[2, ::3] = -np.inf, np.inf, -0.0
+        x = x.astype(np.float32)
+        want = np.sort(x, axis=-1)[:, ::-1]
+        f = jax.jit(ops_topk.kth_largest)
+        for k in range(1, x.shape[1] + 1):
+            got = np.asarray(f(jnp.asarray(x),
+                               jnp.full((3, 1), k, jnp.int32)))
+            np.testing.assert_array_equal(got[:, 0], want[:, k - 1])
+        back = ops_topk.key_value(ops_topk.sortable_key(jnp.asarray(x)))
+        np.testing.assert_array_equal(
+            np.asarray(back).view(np.uint32), x.view(np.uint32))
 
 
 @pytest.mark.parametrize("kind", ENGINES)
