@@ -36,6 +36,15 @@ sampler runs inside the step); scheduling state (positions, active
 mask, page table, per-slot temperature/top_k) lives in numpy and is
 re-uploaded as tiny vectors per step.
 
+One decode step stays in flight: ``step()`` dispatches decode step n+1
+before it reads step n's ids back, so the device always has the next
+step queued behind the one the host waits on. Everything step n+1 needs
+is known to the host at dispatch except the ids step n samples; a row
+that was in step n takes its input from step n's output on the device
+(``_next_inputs``). A row whose request may end on EOS stays in step
+n+1; if it did end, that row is discarded at read-back, and its pool
+write lands in a page the request still held when n+1 was dispatched.
+
 Observability: each engine carries its own metrics ``Registry`` —
 queue-wait and time-to-first-token histograms, slot-occupancy and
 queue-depth gauges, token/step counters, per-request goodput — and
@@ -52,7 +61,7 @@ import functools
 import itertools
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -88,14 +97,15 @@ _PHASES = {
     "schedule": "admission, page allocation, adoption, tier promotion, "
                 "preemption, and the bookkeeping around a prefill chunk",
     "prefill_chunk": "one prefill (chunk) program dispatched and its "
-                     "sampled token read back",
+                     "sampled token read back (behind the decode step "
+                     "in flight)",
     "decode_stage": "slot state staged for the decode program: write "
                     "pages, the [B] vectors uploaded, the compile "
                     "tracker's signature",
     "decode_dispatch": "the call into the decode program, until it "
-                       "returns",
-    "decode_sync": "the sampled ids read back: the wait for the device "
-                   "and the ids' way to the host",
+                       "returns, and the cursors moved past it",
+    "decode_sync": "the sampled ids of the step before read back: the "
+                   "wait for the device and the ids' way to the host",
     "emit": "per-slot loop after the read-back: tokens emitted, "
             "requests finished and recorded",
     "reply": "replica loop: one finished request's result line written "
@@ -107,24 +117,58 @@ class _Phase:
     """One host phase: an ``observe.trace_scope("engine/<name>")`` whose
     seconds also land in the engine's own ``engine_<name>_seconds``.
     ``t0`` / ``end`` are the phase's edges on ``time.perf_counter``, for
-    the lifecycle stamps that used to take their own."""
+    the lifecycle stamps that used to take their own. A phase opened
+    inside another (the read-back ``_resolve`` makes under ``schedule``,
+    ``ingest`` or ``reply``) keeps its seconds, and the outer phase's
+    series leaves them out: no second is counted twice."""
 
-    __slots__ = ("_series", "_scope", "t0", "end")
+    __slots__ = ("_series", "_scope", "_open", "_inner", "t0", "end")
 
-    def __init__(self, series, name, args):
+    def __init__(self, series, name, args, open_):
         self._series = series
         self._scope = _trace.trace_scope("engine/" + name, args=args)
+        self._open = open_          # the engine's phases open now
+        self._inner = 0.0           # seconds of the phases inside this
 
     def __enter__(self):
         self.t0 = time.perf_counter()
         self._scope.__enter__()
+        self._open.append(self)
         return self
 
     def __exit__(self, *exc):
+        self._open.pop()
         self._scope.__exit__(*exc)
         self.end = time.perf_counter()
-        self._series.observe(self.end - self.t0)
+        took = self.end - self.t0
+        self._series.observe(took - self._inner)
+        if self._open:
+            self._open[-1]._inner += took
         return False
+
+
+# ``_last`` holds this where a row's next input is the id the decode
+# step in flight samples for it (token ids are never negative)
+_FROM_DEVICE = -1
+
+
+def _next_inputs(ids, last):
+    """The decode step's input ids: ``last`` where the host holds a
+    row's next input, the step before's sampled id where ``last`` reads
+    ``_FROM_DEVICE``. ``ids[:B]`` drops the expert layer's three counts
+    that a skeleton's step program appends."""
+    import jax.numpy as jnp
+    return jnp.where(last < 0, ids[:last.shape[0]], last)
+
+
+class _InFlight(NamedTuple):
+    """A decode step dispatched and not yet read back."""
+    ids: object             # its sampled ids (and counts), on the device
+    rows: list              # (slot, request, emits) per row it covered,
+    #                         as at dispatch; emits False on a replay row
+    live: np.ndarray        # live cache rows of each row (pos + 1)
+    phases: tuple           # its decode_stage and decode_dispatch
+    overlapped: bool        # dispatched with the step before unread
 
 
 # the two scheduling tiers: "latency" admits ahead of "batch" and may
@@ -345,10 +389,20 @@ class PagedDecodeEngine:
             spans = max(1, int(cache_len) // chunk_tokens)
             tracker = _ct.CompileTracker(
                 storm_threshold=spans * len(tuple(chunk_buckets)) + 2)
+        import jax
         import jax.numpy as jnp
         self._jnp = jnp
         self._prefill_fn = prefill
         self._decode_fn = decode
+        # a plain jit, not an exported program: the benchmark tells the
+        # decode program from the prefill chunks as the most frequent
+        # ``jit_call_exported`` module
+        self._next_inputs = jax.jit(_next_inputs)
+        self._inflight: Optional[_InFlight] = None
+        # requests finished by a read-back outside ``step()`` (a resolve
+        # for ``_preempt`` or ``export_prefix``): the next step returns
+        # them
+        self._landed: List[EngineRequest] = []
         self.cache = cache
         self.batch = int(batch)
         self.cache_len = int(cache_len)
@@ -426,8 +480,13 @@ class PagedDecodeEngine:
             "engine_prefill_seconds", "slot-prefill device latency",
             buckets=_LATENCY_BUCKETS)
         self._m_step_s = reg.histogram(
-            "engine_decode_step_seconds", "batched decode-step latency "
-            "(device call + [B]-ids host sync)", buckets=_LATENCY_BUCKETS)
+            "engine_decode_step_seconds", "batched decode-step latency: "
+            "its stage, dispatch and [B]-ids host sync phases",
+            buckets=_LATENCY_BUCKETS)
+        self._m_overlapped = reg.counter(
+            "engine_decode_overlapped_total", "decode steps dispatched "
+            "while the step before was still unread: the device had "
+            "this step queued behind the one the host waited on")
         self._m_goodput = reg.histogram(
             "engine_request_tokens_per_sec", "per-request goodput: "
             "tokens emitted / (finish - submit)",
@@ -467,6 +526,7 @@ class PagedDecodeEngine:
         self._step_end: Optional[float] = None   # last decode step's,
         #                                 while decoders stay in flight
         self._tag = {"step": 0, "active": 0}     # span args of this step
+        self._phases_open: List[_Phase] = []
         # -- block pool and page table -----------------------------------
         self.block_size = bs
         self.pages_per_slot = cache_len // bs
@@ -733,7 +793,8 @@ class PagedDecodeEngine:
         (``_PHASES``); ``args`` join the step's number and active-slot
         count on the recorded span."""
         return _Phase(self._m_phase[name], name,
-                      dict(self._tag, **args) if args else self._tag)
+                      dict(self._tag, **args) if args else self._tag,
+                      self._phases_open)
 
     def _reject(self, rid: int, reason: str, msg: str) -> ValueError:
         """Account + trace a rejected submission; returns (does not
@@ -910,9 +971,11 @@ class PagedDecodeEngine:
         with it (the merged fleet trace simply never sees the dead
         attempt), but an in-process fleet shares one buffer, so a kill
         simulation must close what the dead attempt opened or the
-        joined trace shows unbalanced slices. Trace-level only — block
-        /slot accounting is abandoned, not released, exactly like a
-        dead process; do not reuse the engine afterwards."""
+        joined trace shows unbalanced slices. The decode step in
+        flight is read back first (requests it finishes end normally
+        and the next ``step()`` returns them); every aborted slot then
+        gives back its blocks and reservation, so no block leaks."""
+        self._resolve()
         now = time.perf_counter()
         aborted: List[EngineRequest] = []
         # a preempted-to-blocks request closed its prefill/decode slices
@@ -929,8 +992,9 @@ class PagedDecodeEngine:
             if req.decode_open:
                 self._ev(req, "decode", "e", now)
                 req.decode_open = False
-            self._active[slot] = False
-            self._slot_req[slot] = None
+            if slot in self._prefilling:
+                self._prefilling.remove(slot)
+            self._release_slot(slot, req)
             aborted.append(req)
         for req in aborted:
             req.status, req.finish_reason = "aborted", reason
@@ -960,7 +1024,12 @@ class PagedDecodeEngine:
 
     @property
     def idle(self) -> bool:
-        return (not self._queue and not self._preempted
+        """Nothing queued, prefilling, decoding, in flight on the
+        device, or finished and not yet returned: a loop that steps
+        until idle (``run_until_idle``, ``EngineLoop``'s drain and EOF
+        exit) therefore reads the last decode step back."""
+        return (self._inflight is None and not self._landed
+                and not self._queue and not self._preempted
                 and not self._prefilling and not self._active.any())
 
     # -- P/D disaggregation (KV transfer over the fleet wire) -------------
@@ -999,6 +1068,7 @@ class PagedDecodeEngine:
         because admission stops at its first miss anyway. None only
         when the leading run is empty."""
         from paddle_tpu.serving import transfer as _transfer
+        self._resolve()
         digests = self.prefix_digests(prompt)
         if not digests:
             return None
@@ -1450,9 +1520,14 @@ class PagedDecodeEngine:
         (blocks survived in the LRU) or a cache-hit chunked prefill
         plus forced decode replay (blocks evicted). A victim still
         PREFILLING simply re-queues: its published chunks already sit
-        in the prefix cache, so re-admission hits them."""
+        in the prefix cache, so re-admission hits them. The decode step
+        in flight is read back first, for an exact cursor; a victim it
+        finishes has freed its slot already."""
         from paddle_tpu.serving import blocks as _blocks
+        self._resolve()
         req = self._slot_req[slot]
+        if req is None:
+            return
         now = time.perf_counter()
         bs = self.block_size
         blocks = list(self._slot_blocks[slot])
@@ -1465,7 +1540,6 @@ class PagedDecodeEngine:
                 req.decode_open = False
             req.snapshot = None
             published = 0
-            self._active[slot] = False
         elif req.status == "running":
             if req.decode_open:
                 self._ev(req, "decode", "e", now)
@@ -1491,7 +1565,6 @@ class PagedDecodeEngine:
                 "last": int(self._last[slot]),
                 "forced": list(self._slot_forced[slot])}
             published = nfull + (1 if tail_len else 0)
-            self._active[slot] = False
         else:                       # mid-prefill: published chunk
             published = 0           # blocks already carry their hashes
             self._prefilling.remove(slot)
@@ -1503,20 +1576,7 @@ class PagedDecodeEngine:
                 # tokens (replay restarts from the full emitted list —
                 # the prompt prefill re-derives the earlier part)
                 req.replay = list(req.tokens)
-        for b in blocks:
-            self.pool.release(b)
-        self.pool.unreserve(self._slot_reserved[slot])
-        self._slot_blocks[slot] = []
-        self._slot_hashes[slot] = []
-        self._slot_reserved[slot] = 0
-        self._nalloc[slot] = 0
-        self._slot_off[slot] = 0
-        self._slot_forced[slot] = deque()
-        self._pages[slot, :] = 0
-        self._pages_dev = None
-        self._slot_req[slot] = None
-        self._free.append(slot)
-        self._uncharge_tenant(req)
+        self._release_slot(slot, req)
         req.slot = -1
         req.preemptions += 1
         self._m_preempts.inc()
@@ -1595,11 +1655,7 @@ class PagedDecodeEngine:
             self._slot_forced[slot] = deque(snap.get("forced", ()))
             req.slot, req.status = slot, "running"
             self._slot_req[slot] = req
-            self._active[slot] = True
-            self._pos[slot] = snap["pos"]
-            self._last[slot] = snap["last"]
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
+            self._activate(slot, req, snap["pos"], snap["last"])
             self._charge_tenant(req)
             req.snapshot = None
             self._ev(req, "queued", "e", now)
@@ -1708,31 +1764,37 @@ class PagedDecodeEngine:
             stalled = bool(self._active.any())
         with self.phase("prefill_chunk", tokens=int(c),
                         bucket=bucket) as chunk:
-            tok = np.asarray(self._dispatch_chunk(
+            tok = self._dispatch_chunk(
                 slot, padded, c, npages, req.temperature, req.top_k,
-                self._seed()))
+                self._seed())
+            if self._inflight is not None:
+                # the decode step queued ahead of the chunk: its time
+                # is this prompt's stall, not its own prefill
+                self._inflight.ids.block_until_ready()
+            own_t0 = time.perf_counter()
+            tok = np.asarray(tok)
             if tok.ndim:                # [token, 3 counts]
                 self._moe_counters()[0].inc(int(tok[1]))
                 tok = tok[0]
             tok = int(tok)
         with self.phase("schedule"):
-            self._chunk_done(slot, req, off, c, tok, chunk, stalled,
-                             finished)
+            self._chunk_done(slot, req, off, c, tok, own_t0, chunk.end,
+                             stalled, finished)
 
     def _chunk_done(self, slot: int, req: EngineRequest, off: int, c: int,
-                    tok: int, chunk: _Phase, stalled: bool,
+                    tok: int, own_t0: float, now: float, stalled: bool,
                     finished: List[EngineRequest]):
-        """Bookkeeping after one chunk program: publish its blocks,
-        and on the prompt's final chunk hand the slot to decode."""
-        now = chunk.end
+        """Bookkeeping after one chunk program (its own device time
+        from ``own_t0`` to ``now``): publish its blocks, and on the
+        prompt's final chunk hand the slot to decode."""
         # accumulate per-chunk device time; the histogram observes one
         # per-request total at the final chunk (chunk-grain timing
         # lives in engine_prefill_chunk_seconds and the stall
         # histogram)
-        self._slot_prefill_s[slot] += now - chunk.t0
+        self._slot_prefill_s[slot] += now - own_t0
         self._m_chunks.inc()
         if stalled:
-            self._m_stall.observe(now - chunk.t0)
+            self._m_stall.observe(now - own_t0)
         # publish the chunk's fully-written prompt blocks NOW (not at
         # prompt completion): a concurrent same-prefix request adopts
         # them instead of re-prefilling — a burst of shared-prefix
@@ -1771,38 +1833,48 @@ class PagedDecodeEngine:
             if not req.decode_open:
                 self._ev(req, "decode", "b", now)
                 req.decode_open = True
-            self._active[slot] = True
-            self._pos[slot] = req.prompt.size
-            self._last[slot] = self._slot_forced[slot].popleft()
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
+            self._activate(slot, req, req.prompt.size,
+                           self._slot_forced[slot].popleft())
             return
         if self._emit(req, tok, now):
             finished.append(req)            # blocks released by _finish;
             return                          # published ones park in LRU
+        self._activate(slot, req, req.prompt.size, tok)
+
+    def _activate(self, slot: int, req: EngineRequest, pos: int,
+                  last: int):
+        """Hand ``slot`` to decode at ``pos``, its next input ``last``
+        known to the host."""
         self._active[slot] = True
-        self._pos[slot] = req.prompt.size
-        self._last[slot] = tok
+        self._pos[slot] = pos
+        self._last[slot] = last
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
+
+    def _release_slot(self, slot: int, req: EngineRequest):
+        """Give ``slot`` back with everything it holds: its blocks, the
+        unallocated rest of its reservation, its tenant charge."""
+        for b in self._slot_blocks[slot]:
+            self.pool.release(b)
+        self.pool.unreserve(self._slot_reserved[slot])
+        self._slot_blocks[slot] = []
+        self._slot_hashes[slot] = []
+        self._slot_reserved[slot] = 0
+        self._nalloc[slot] = 0
+        self._slot_off[slot] = 0
+        self._slot_forced[slot] = deque()
+        self._pages[slot, :] = 0
+        self._pages_dev = None
+        self._active[slot] = False
+        self._last[slot] = 0
+        self._slot_req[slot] = None
+        self._free.append(slot)
+        self._uncharge_tenant(req)
 
     def _finish(self, req: EngineRequest, reason: str, now: float):
         slot = req.slot
         if slot >= 0:
-            for b in self._slot_blocks[slot]:
-                self.pool.release(b)
-            self.pool.unreserve(self._slot_reserved[slot])
-            self._slot_blocks[slot] = []
-            self._slot_hashes[slot] = []
-            self._slot_reserved[slot] = 0
-            self._nalloc[slot] = 0
-            self._pages[slot, :] = 0
-            self._pages_dev = None
-            self._slot_forced[slot] = deque()
-            self._uncharge_tenant(req)
-            self._active[slot] = False
-            self._slot_req[slot] = None
-            self._free.append(slot)
+            self._release_slot(slot, req)
         req.status, req.finish_reason, req.finish_t = "done", reason, now
         self._m_completed.inc(reason=reason)
         if req.latency_s and req.latency_s > 0:
@@ -1876,7 +1948,7 @@ class PagedDecodeEngine:
         """The decode programs' page-table argument, uploaded when it
         changed."""
         if self._pages_dev is None:
-            self._pages_dev = self._jnp.asarray(self._pages)
+            self._pages_dev = self._upload(self._pages)
         return (self._pages_dev,)
 
     @property
@@ -1906,54 +1978,108 @@ class PagedDecodeEngine:
 
     def step(self) -> List[EngineRequest]:
         """One scheduler iteration: admit waiting requests, run the
-        prefill chunks due (one while anything decodes), then one
-        batched decode step for everything in flight. Returns the
-        requests that finished during this step."""
-        finished: List[EngineRequest] = []
+        prefill chunks due (one while anything decodes), dispatch one
+        batched decode step for everything in flight, then read back
+        the decode step the call before dispatched and emit its tokens.
+        Returns the requests that finished during this step."""
+        finished = self._landed
         self._open_step()
+        if not self._active.any():
+            # nothing to queue behind the step in flight: read it back
+            # first (admission then sees the slots it freed), and the
+            # time until the next decode step is not a step's
+            self._resolve()
+            self._step_end = None
         self._schedule(finished)
         if self._active.any():
+            prev, self._inflight = self._inflight, None
             with self.phase("decode_stage") as stage:
                 self._pre_decode()
-                staged = self._stage_decode(self._seed())
-            with self.phase("decode_dispatch"):
-                nxt = self._call_decode(*staged)
-            with self.phase("decode_sync") as sync:
-                # the only device->host transfer: [B] int32 ids (and,
-                # after them, an expert layer's three counts)
-                nxt = np.asarray(nxt)
-            now = self._close_decode(stage, sync)
-            with self.phase("emit"):
-                if nxt.size > self.batch:
-                    for m, n in zip(self._moe_counters(),
-                                    nxt[self.batch:]):
-                        m.inc(int(n))
-                self._count_decode_rows()
-                for slot in np.flatnonzero(self._active):
-                    self._pos[slot] += 1
-                    forced = self._slot_forced[slot]
-                    if forced:
-                        # replay after a preempt-to-blocks resume: the
-                        # step ran at the right (pos, last) and its
-                        # pool write is what matters; the sampled id
-                        # re-derives the known next token (bitwise
-                        # under greedy), which advances the cursor
-                        # WITHOUT re-emitting — the caller holds it
-                        self._last[slot] = forced.popleft()
-                        continue
-                    req = self._slot_req[slot]
-                    tok = int(nxt[slot])
-                    self._last[slot] = tok
-                    if self._emit(req, tok, now):
-                        finished.append(req)
+                staged = self._stage_decode(
+                    self._seed(), None if prev is None else prev.ids)
+            with self.phase("decode_dispatch") as dispatch:
+                ids = self._call_decode(*staged)
+                self._inflight = self._advance(ids, (stage, dispatch),
+                                               prev)
+            if prev is not None:
+                self._land(prev)
+        self._landed = []
         self._close_step()
         return finished
 
-    def _count_decode_rows(self):
-        """The decode step just read back: rows its attention had to
-        read against rows it read, from the lengths the host holds."""
-        from paddle_tpu.ops.pallas.policy import PATH_XLA
+    def _advance(self, ids, phases, prev: Optional[_InFlight]
+                 ) -> _InFlight:
+        """Move the cursors past the decode step just dispatched, before
+        its ids are known: each row's position advances; a replay row
+        takes its next forced token; any other row's next input is this
+        step's id (``_FROM_DEVICE``), and a row whose request reaches
+        ``max_new`` with it leaves the batch (the slot stays its
+        request's until the read-back finishes it)."""
+        ahead = {} if prev is None else \
+            {slot: req for slot, req, emits in prev.rows if emits}
         live = self._pos[self._active].astype(np.int64) + 1
+        rows = []
+        for slot in np.flatnonzero(self._active):
+            req = self._slot_req[slot]
+            self._pos[slot] += 1
+            forced = self._slot_forced[slot]
+            if forced:
+                # replay after a preempt-to-blocks resume: the step runs
+                # at the right (pos, last) and its pool write is what
+                # matters; its id re-derives the known next token
+                # (bitwise under greedy), which advances the cursor
+                # WITHOUT re-emitting — the caller holds it
+                self._last[slot] = forced.popleft()
+                rows.append((slot, req, False))
+                continue
+            self._last[slot] = _FROM_DEVICE
+            rows.append((slot, req, True))
+            owed = 2 if ahead.get(slot) is req else 1
+            if len(req.tokens) + owed >= req.max_new:
+                self._active[slot] = False
+        return _InFlight(ids, rows, live, phases, prev is not None)
+
+    def _land(self, rec: _InFlight) -> np.ndarray:
+        """Read one dispatched decode step back and emit its tokens; a
+        row whose request finished meanwhile (on EOS, at the read-back
+        before) is discarded. Returns the ids."""
+        with self.phase("decode_sync") as sync:
+            # the only device->host transfer: [B] int32 ids (and,
+            # after them, an expert layer's three counts)
+            ids = np.asarray(rec.ids)
+        now = self._close_decode(rec.phases + (sync,))
+        if rec.overlapped:
+            self._m_overlapped.inc()
+        with self.phase("emit"):
+            if ids.size > self.batch:
+                for m, n in zip(self._moe_counters(), ids[self.batch:]):
+                    m.inc(int(n))
+            self._count_decode_rows(rec.live)
+            for slot, req, emits in rec.rows:
+                if emits and self._slot_req[slot] is req \
+                        and self._emit(req, int(ids[slot]), now):
+                    self._landed.append(req)
+        return ids
+
+    def _resolve(self):
+        """Read back the decode step in flight, if any, so that the
+        host's state is exact: the cursors, each row's next input, and
+        the requests it finished (the next ``step()`` returns them).
+        Called wherever exact state is needed (``_preempt``,
+        ``export_prefix``, ``abort_requests``) and by ``step()`` when
+        no row is left to decode."""
+        rec, self._inflight = self._inflight, None
+        if rec is None:
+            return
+        ids = self._land(rec)[:self.batch]
+        held = self._last == _FROM_DEVICE
+        self._last[held] = ids[held]
+
+    def _count_decode_rows(self, live: np.ndarray):
+        """A decode step read back: rows its attention had to read
+        (``live``, from the positions at dispatch) against rows it
+        read."""
+        from paddle_tpu.ops.pallas.policy import PATH_XLA
         self._m_live_rows.inc(int(live.sum()))
         if self.kernel_paths.get("decode", {}).get(
                 "attention", PATH_XLA) == PATH_XLA:
@@ -1966,11 +2092,11 @@ class PagedDecodeEngine:
         self._tag = {"step": int(self._m_steps.value()),
                      "active": self.active_count}
 
-    def _close_decode(self, stage: _Phase, sync: _Phase) -> float:
-        """Account one completed decode step (stage + dispatch + sync);
-        returns its completion time."""
-        now = sync.end
-        self._m_step_s.observe(now - stage.t0)
+    def _close_decode(self, phases) -> float:
+        """Account one completed decode step from its stage, dispatch
+        and sync phases; returns its completion time."""
+        now = phases[-1].end
+        self._m_step_s.observe(sum(p.end - p.t0 for p in phases))
         self._m_steps.inc()
         if self._step_end is not None \
                 and now - self._step_end > _SLOW_STEP_S:
@@ -1979,7 +2105,7 @@ class PagedDecodeEngine:
         return now
 
     def _close_step(self):
-        if not self._active.any():
+        if not self._active.any() and self._inflight is None:
             self._step_end = None       # no decoder in flight: the next
             #                             interval is not a step's
         self._m_occupancy.set(self.active_count)
@@ -1991,15 +2117,25 @@ class PagedDecodeEngine:
             self._m_evictions.inc(pool.evictions - self._evictions_seen)
             self._evictions_seen = pool.evictions
 
-    def _stage_decode(self, seed):
+    def _upload(self, host: np.ndarray):
+        """A host vector put on the device through a copy of its own: a
+        device array may read its numpy source when the program runs
+        (the CPU aliases it), and the host moves these vectors on while
+        the step that read them is still queued."""
+        return self._jnp.asarray(host.copy())
+
+    def _stage_decode(self, seed, ids=None):
         """The decode program's arguments over the current slot state
-        (the [B] vectors and the page table uploaded) and their
+        (the [B] vectors and the page table uploaded; ``ids``, the step
+        in flight's, fill the inputs ``_FROM_DEVICE`` marks) and their
         compile-tracker signature."""
-        jnp = self._jnp
-        args = (self.params, self.cache, jnp.asarray(self._last),
-                jnp.asarray(self._pos), jnp.asarray(self._active),
+        last = self._upload(self._last)
+        if ids is not None:
+            last = self._next_inputs(ids, last)
+        args = (self.params, self.cache, last,
+                self._upload(self._pos), self._upload(self._active),
                 *self._decode_extra(),
-                jnp.asarray(self._temp), jnp.asarray(self._topk), seed)
+                self._upload(self._temp), self._upload(self._topk), seed)
         return args, _ct.arg_signature(args, {})
 
     def _call_decode(self, args, sig):
@@ -2035,9 +2171,11 @@ class PagedDecodeEngine:
         return self.compile_counts()
 
     def _precompile_decode(self):
-        # no active row: every cache write of the step is dropped
-        out = np.asarray(self._call_decode(*self._stage_decode(np.int32(0))))
-        if out.size > self.batch:   # counts after the ids, none counted
+        # no active row: every cache write of the step is dropped; the
+        # next step's input select compiles on its ids
+        ids = self._call_decode(*self._stage_decode(np.int32(0)))
+        np.asarray(self._next_inputs(ids, self._upload(self._last)))
+        if ids.size > self.batch:   # counts after the ids, none counted
             self._moe_counters()
 
     def run_until_idle(self, max_steps: int = 100_000
@@ -2399,7 +2537,9 @@ class SpecDecodeEngine(PagedDecodeEngine):
         ``decode_stage`` sizes the windows and allocates their pages,
         ``decode_dispatch`` runs propose (and reads its proposals),
         draft_verify and verify until the last returns, ``decode_sync``
-        reads the accepted tokens back."""
+        reads the accepted tokens back. Serial: propose reads back, so
+        no round is left in flight and ``_resolve`` has nothing to
+        do."""
         finished: List[EngineRequest] = []
         self._open_step()
         self._schedule(finished)
@@ -2425,7 +2565,7 @@ class SpecDecodeEngine(PagedDecodeEngine):
                 window = np.zeros((B, W), np.int32)
                 window[:, 0] = self._last
                 act_prop = self._active & ~forced
-            with self.phase("decode_dispatch"):
+            with self.phase("decode_dispatch") as dispatch:
                 if act_prop.any():
                     props, self.draft_cache = self._tracker.track_call(
                         "serving_engine.propose", self._propose_fn,
@@ -2459,7 +2599,7 @@ class SpecDecodeEngine(PagedDecodeEngine):
                     self._seed())
             with self.phase("decode_sync") as sync:
                 X, n = np.asarray(X), np.asarray(n)
-            now = self._close_decode(stage, sync)
+            now = self._close_decode((stage, dispatch, sync))
             self._m_spec_rounds.inc()
             with self.phase("emit"):
                 for slot in np.flatnonzero(self._active):

@@ -5,6 +5,9 @@ block pool must never leak or double-free, prefix-cache hits must serve
 bitwise the cold-prefill tokens, and the engine still compiles once per
 chunk bucket + once for decode."""
 
+import functools
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -800,3 +803,347 @@ class TestMatricesInComputeDtype:
             e.run_until_idle()
             out.append([list(r.tokens) for r in reqs])
         assert out[0] == out[1]
+
+
+# -- one decode step in flight -----------------------------------------------
+
+SKELETONS = ("gpt2", "gated_hybrid", "latent_moe")
+
+
+@functools.lru_cache(maxsize=None)
+def _skeleton(name):
+    """(cfg, params, engine keywords) of a tiny model of one skeleton;
+    the two skeletons run their XLA path."""
+    if name == "gpt2":
+        return CFG, PARAMS, dict(batch=3, cache_len=32, block_size=BS,
+                                 chunk_tokens=8)
+    common = dict(vocab=64, d_model=32, n_heads=2, d_ff=32, max_len=128,
+                  dtype=jnp.float32, use_rope=True, rope_theta=1e4,
+                  skeleton=name, moe_experts=8, moe_top_k=2,
+                  moe_shared_ff=32)
+    if name == "gated_hybrid":
+        from paddle_tpu.models import gated_hybrid as module
+        cfg = transformer.TransformerConfig(
+            n_kv_heads=1, n_layers=4, attn_head_dim=16, rotary_dim=8,
+            full_attn_interval=4, rec_key_heads=2, rec_value_heads=2,
+            rec_key_dim=16, rec_value_dim=16, rec_conv=4, moe_held=(0, 8),
+            **common)
+    else:
+        from paddle_tpu.models import latent_moe as module
+        cfg = transformer.TransformerConfig(
+            n_layers=2, norm_eps=1e-5, q_lora_rank=16, kv_lora_rank=32,
+            qk_nope_dim=8, qk_rope_dim=8, v_head_dim=8, dense_layers=1,
+            dense_ff=48, moe_route_scale=1.8, **common)
+    return cfg, module.init_params(jax.random.PRNGKey(0), cfg), dict(
+        batch=3, cache_len=64, block_size=16, chunk_tokens=32,
+        chunk_buckets=(32,), pallas="off")
+
+
+def _lookahead_engine(name, **kw):
+    cfg, params, ekw = _skeleton(name)
+    return PagedDecodeEngine.from_params(
+        params, cfg, seed=0, tracker=CompileTracker(), decode_flops=0.0,
+        **dict(ekw, **kw))
+
+
+def _prompts(name, lens, seed):
+    r = np.random.RandomState(seed)
+    return [r.randint(0, _skeleton(name)[0].vocab, n).astype(np.int32)
+            for n in lens]
+
+
+def _one_at_a_time(eng, prompts, max_new):
+    """Each request alone in the batch, in turn: its tokens."""
+    out = []
+    for p, m in zip(prompts, max_new):
+        r = eng.submit(p, m)
+        eng.run_until_idle()
+        out.append(list(r.tokens))
+    return out
+
+
+def _eos_inside(tokens):
+    """(index, id) of the first token after the first that did not
+    occur before it: an EOS id a request meets in mid-decode."""
+    return next((k, t) for k, t in enumerate(tokens)
+                if k >= 1 and t not in tokens[:k])
+
+
+def _overlapped(eng):
+    return eng.metrics.get("engine_decode_overlapped_total").value()
+
+
+@pytest.mark.parametrize("name", SKELETONS)
+def test_lookahead_mixed_batch_greedy_bitwise(name):
+    """Five requests on three slots with a decode step always in flight:
+    each request's tokens are bitwise those it gets alone in the batch,
+    the one that ends on an EOS id (taken from its solo run) included,
+    and the others ending on ``max_new``."""
+    prompts = _prompts(name, (5, 9, 3, 12, 7), seed=11)
+    max_new = [10, 7, 12, 6, 9]
+    alone = _one_at_a_time(_lookahead_engine(name), prompts, max_new)
+    if name == "gpt2":          # the solo runs against the lockstep model
+        for p, m, got in zip(prompts, max_new, alone):
+            want = np.asarray(transformer.generate(
+                PARAMS, jnp.asarray(p[None]), CFG, max_new=m))[0]
+            assert got == list(want[p.size:])
+    k, eos = _eos_inside(alone[0])
+    eng = _lookahead_engine(name)
+    reqs = [eng.submit(p, m, eos_id=eos if i == 0 else None)
+            for i, (p, m) in enumerate(zip(prompts, max_new))]
+    assert len(eng.run_until_idle()) == 5
+    assert reqs[0].finish_reason == "eos"
+    assert list(reqs[0].tokens) == alone[0][:k + 1]
+    for r, want in zip(reqs[1:], alone[1:]):
+        assert r.finish_reason == "max_tokens"
+        assert list(r.tokens) == want
+    assert _overlapped(eng) > 0
+    assert eng.pool.idle
+
+
+@pytest.mark.parametrize("name", SKELETONS)
+def test_lookahead_slot_and_pages_reused_after_eos(name):
+    """A request that ends on EOS while the step after it is already in
+    flight hands its slot and its pages to a successor: that step's row
+    of the finished request is discarded at read-back, and the
+    successor's tokens are bitwise those it gets first in an engine of
+    its own."""
+    bs = _skeleton(name)[2]["block_size"]
+    short = name == "gpt2"
+    pa, pb, pc = _prompts(name, (9, 5, 6) if short else (20, 10, 12),
+                          seed=11)
+    ma, mb, mc = (8, 20, 8) if short else (12, 30, 8)
+
+    def need(p, m):
+        return -(-(p.size + m) // bs)
+
+    # the pool holds the first two; the third can only have the first's
+    assert need(pc, mc) <= need(pa, ma)
+    ref = _lookahead_engine(name, batch=2)
+    want_c, want_a = _one_at_a_time(ref, (pc, pa), (mc, ma))
+    k, eos = _eos_inside(want_a)
+    assert k < ma - 1
+    eng = _lookahead_engine(name, batch=2,
+                            num_blocks=need(pa, ma) + need(pb, mb))
+    discarded = []
+    land = eng._land
+
+    def noting(rec):
+        discarded.extend(req for _, req, emits in rec.rows
+                         if emits and req.status == "done")
+        return land(rec)
+
+    eng._land = noting
+    a = eng.submit(pa, ma, eos_id=eos)
+    b = eng.submit(pb, mb)
+    c = eng.submit(pc, mc)
+    eng.run_until_idle()
+    assert a.finish_reason == "eos" and list(a.tokens) == want_a[:k + 1]
+    assert discarded == [a]
+    assert c.slot == a.slot and len(b.tokens) == mb
+    assert list(c.tokens) == want_c
+    assert eng.pool.idle
+
+
+@pytest.mark.parametrize("name", SKELETONS)
+def test_lookahead_preempt_with_a_step_in_flight_resumes_bitwise(name):
+    """``_preempt`` reads the step in flight back first: the victim's
+    cursor is exact and it resumes (by re-mapping its pages, or by
+    replay where the model keeps recurrent rows) to the tokens of an
+    undisturbed run."""
+    pv, pd = _prompts(name, (9, 4), seed=7)
+    want, = _one_at_a_time(_lookahead_engine(name), (pv,), (12,))
+    eng = _lookahead_engine(name)
+    d = eng.submit(pd, 20)
+    v = eng.submit(pv, 12)
+    while not (v.status == "running" and len(v.tokens) >= 2):
+        eng.step()
+    assert eng._inflight is not None
+    assert v in [req for _, req, _ in eng._inflight.rows]
+    eng._preempt(v.slot)
+    assert eng._inflight is None and v.status == "preempted"
+    eng.run_until_idle()
+    assert list(v.tokens) == want and len(d.tokens) == 20
+    assert eng.pool.idle
+
+
+def test_lookahead_last_step_read_back_before_admission():
+    """When the step in flight is the last (its one row reached
+    ``max_new`` at dispatch), the next ``step()`` reads it back before
+    admission: the request it finishes is returned, the request queued
+    behind it gets the slot in that same call, and the time until that
+    request's first decode step is not a step's period."""
+    eng = _lookahead_engine("gpt2", batch=1)
+    pa, pb = _prompts("gpt2", (5, 6), seed=3)
+    a = eng.submit(pa, 3)
+    b = eng.submit(pb, 3)
+    eng.step()
+    while eng._active.any():
+        eng.step()
+    assert eng._inflight is not None and a.status == "running"
+    assert b.status == "queued"
+    done = eng.step()
+    assert done == [a] and a.finish_reason == "max_tokens"
+    assert b.status == "running" and b.slot == 0
+    assert eng._inflight is not None and not eng._inflight.overlapped
+    assert eng._step_end is None
+    eng.run_until_idle()
+    assert len(b.tokens) == 3 and eng.pool.idle
+
+
+def test_lookahead_read_back_inside_another_phase_counts_once():
+    """A read-back made inside another phase (``_preempt`` under
+    ``schedule``, as admission calls it) lands in ``decode_sync`` and
+    ``emit``, and ``schedule`` leaves those seconds out: the phases'
+    series add up to no more than the wall time they cover."""
+    eng = _lookahead_engine("gpt2")
+    pv, pd = _prompts("gpt2", (9, 4), seed=7)
+    eng.submit(pd, 20)
+    v = eng.submit(pv, 12)
+    while not (v.status == "running" and eng._inflight is not None):
+        eng.step()
+    names = ("schedule", "decode_sync", "emit")
+
+    def seconds():
+        return {n: eng.metrics.get(f"engine_{n}_seconds").snapshot()["sum"]
+                for n in names}
+
+    before = seconds()
+    t0 = time.perf_counter()
+    with eng.phase("schedule"):
+        eng._preempt(v.slot)
+    wall = time.perf_counter() - t0
+    got = {n: s - before[n] for n, s in seconds().items()}
+    assert eng._inflight is None and v.status == "preempted"
+    assert got["decode_sync"] > 0 and got["emit"] > 0
+    assert sum(got.values()) <= wall
+    assert got["schedule"] < wall - got["decode_sync"] - got["emit"] \
+        + 1e-9
+
+
+@pytest.mark.parametrize("name", ["gpt2", "latent_moe"])
+def test_lookahead_export_prefix_with_a_step_in_flight(name):
+    """``export_prefix`` reads the step in flight back first and returns
+    the payload it returns once the engine has drained; what the read-
+    back finished, the next step returns."""
+    chunk = _skeleton(name)[2]["chunk_tokens"]
+    prompt, other = _prompts(name, (chunk + 4, 5), seed=9)
+    eng = _lookahead_engine(name)
+    a = eng.submit(prompt, 6)
+    b = eng.submit(other, 2)
+    while not (a.status == "running" and eng._inflight is not None):
+        eng.step()
+    early = eng.export_prefix(prompt)
+    assert early is not None and eng._inflight is None
+    done = eng.run_until_idle()
+    assert {r.rid for r in done} | {r.rid for r in (a, b)
+                                    if r.status == "done"} \
+        == {a.rid, b.rid}
+    assert a.status == b.status == "done"
+    assert eng.export_prefix(prompt) == early
+
+
+@pytest.mark.parametrize("name", SKELETONS)
+def test_lookahead_abort_with_a_step_in_flight_leaks_no_block(name):
+    """``abort_requests`` reads the step in flight back, aborts what is
+    still live and gives every slot's blocks back."""
+    eng = _lookahead_engine(name)
+    reqs = [eng.submit(p, 10) for p in _prompts(name, (5, 9, 3, 7),
+                                                seed=13)]
+    for _ in range(3):
+        eng.step()
+    assert eng._inflight is not None
+    aborted = eng.abort_requests("replica_killed")
+    assert aborted == sum(r.status == "aborted" for r in reqs) >= 3
+    assert all(r.status in ("aborted", "done") for r in reqs)
+    assert eng._inflight is None and eng.pool.idle
+    assert eng.free_slots == eng.batch and not eng._active.any()
+
+
+def test_lookahead_staged_arguments_are_the_host_state_at_dispatch(rng):
+    """The decode program's vectors and page table are the host's as
+    they were at staging: moving the host on (as ``_advance`` does while
+    the step is queued) leaves them as they were."""
+    eng = _lookahead_engine("gpt2")
+    for n in (5, 9):
+        eng.submit(rng.randint(0, 40, n).astype(np.int32), max_new=6)
+    eng.step()
+    eng.step()
+    assert eng._inflight is not None
+    eng._pages_dev = None
+    host = [eng._pos, eng._active, eng._temp, eng._topk, eng._pages]
+    want = [h.copy() for h in host]
+    args, _ = eng._stage_decode(np.int32(0), eng._inflight.ids)
+    for h in host:
+        h[...] = 1 - h
+    got = [args[3], args[4], args[6], args[7], args[5]]
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def test_lookahead_chunk_own_time_excludes_the_step_ahead(rng):
+    """A chunk dispatched behind a decode step still in flight waits for
+    it: that wait is the prompt's stall, not its own prefill time (a
+    slow decode program here: every step burns device time before its
+    ids and pool are ready)."""
+    eng = _lookahead_engine("gpt2")
+    decode = eng._decode_fn
+
+    @jax.jit
+    def burn(ids, pool):
+        x = jnp.ones((256, 256), jnp.float32) / 256.0
+        x = jax.lax.fori_loop(0, 400, lambda _, v: jnp.tanh(v @ v), x)
+        z = (x[0, 0] * 0.0).astype(ids.dtype)
+        return ids + z, jax.tree_util.tree_map(
+            lambda t: t + z.astype(t.dtype), pool)
+
+    ids, pool = burn(jnp.zeros(3, jnp.int32), eng.cache)
+    t0 = time.perf_counter()
+    np.asarray(burn(ids, pool)[0])
+    burn_s = time.perf_counter() - t0
+
+    def slow(*args):
+        return burn(*decode(*args))
+
+    eng._decode_fn = slow
+    eng.submit(rng.randint(0, 40, 4).astype(np.int32), max_new=12)
+    eng.step()
+    eng.step()
+    assert eng._inflight is not None
+    victim = eng.submit(rng.randint(0, 40, 5).astype(np.int32), max_new=1)
+    eng.run_until_idle()
+    assert victim.finish_reason == "max_tokens"
+    assert victim.prefill_own_s < burn_s / 3 < victim.prefill_stall_s
+
+
+@pytest.mark.parametrize("name", SKELETONS)
+def test_lookahead_counters_and_compiles(name):
+    """Every decode step dispatched with the step before still unread
+    counts in ``engine_decode_overlapped_total``; the engine is not idle
+    while a step is in flight; traffic compiles nothing beyond what
+    ``precompile()`` compiled, the input select included."""
+    eng = _lookahead_engine(name)
+    counts = eng.precompile()
+    selects = eng._next_inputs._cache_size()
+    fresh = []
+    advance = eng._advance
+
+    def noting(ids, phases, prev):
+        fresh.append(prev is None)
+        return advance(ids, phases, prev)
+
+    eng._advance = noting
+    prompts = _prompts(name, (5, 9, 3, 12, 7), seed=17)
+    eng.submit(prompts[0], 3)
+    eng.step()                  # its first decode step dispatched, unread
+    assert eng._inflight is not None and not eng.idle
+    assert eng._active.sum() == 1
+    for p in prompts[1:]:
+        eng.submit(p, 8)
+    eng.run_until_idle()
+    assert eng.idle and eng._inflight is None
+    steps = eng.metrics.get("engine_decode_steps_total").value()
+    assert steps == len(fresh)
+    assert _overlapped(eng) == steps - sum(fresh) > 0
+    assert eng.compile_counts() == counts
+    assert eng._next_inputs._cache_size() == selects
